@@ -25,11 +25,10 @@ from .pool import BlockPool
 
 BLOCKSYNC_CHANNEL = 0x40
 TRY_SYNC_INTERVAL = 0.01
-# blocks whose LastCommit sigs batch into one device dispatch
-# 48 from the r4b on-TPU depth sweep (ab_round4b_results.jsonl
-# prod3_blocksync at 10k validators): monotone through 48 (159.7 at
-# 24 vs 181.6 at 48 under the full kernel stack).  The pool keeps
-# MAX_PENDING_REQUESTS=64 blocks in flight so a full window can fill.
+# blocks whose LastCommit sigs batch into one device dispatch (an
+# on-chip depth sweep at 10k validators rose monotonically through
+# 48).  The pool keeps MAX_PENDING_REQUESTS=64 blocks in flight so a
+# full window can fill.
 VERIFY_WINDOW = 48
 STATUS_UPDATE_INTERVAL = 10.0
 SWITCH_TO_CONSENSUS_INTERVAL = 1.0

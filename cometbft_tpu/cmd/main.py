@@ -42,6 +42,10 @@ def cmd_init(args) -> int:
 def cmd_start(args) -> int:
     """commands/run_node.go NewRunNodeCmd."""
     from ..node import Node
+    from ..ops import compile_hook
+    # a restarted node must not recompile its verify programs for
+    # minutes: keep them in the persistent cache
+    compile_hook.ensure_compile_cache()
     cfg = _load_config(args.home)
     if args.proxy_app:
         cfg.base.abci = args.proxy_app

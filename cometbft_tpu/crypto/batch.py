@@ -316,13 +316,9 @@ DEVICE_THRESHOLD = int(os.environ.get("COMETBFT_TPU_BATCH_THRESHOLD", "8"))
 
 # secp256k1 has no RLC batch equation — its device kernel verifies
 # per-signature Straus chains, so the per-sig device advantage is far
-# smaller than ed25519's and the ~70 ms dispatch floor dominates small
-# batches.  Measured: host 889 sigs/s (1.12 ms/sig, recorded in
-# docs/PERF.md); device (r5 width sweep, ab_round5_results.jsonl
-# secp_batch_ab): 6613 sigs/s at batch 1024, 27583 at 4096, 27383 at
-# 16383 — marginal device cost ~36 us/sig once dispatch overhead
-# amortizes.  Fixed+marginal crossover ~= 70 sigs; 96 leaves margin
-# for relay jitter.
+# smaller than ed25519's and the fixed dispatch cost dominates small
+# batches: the crossover against the host loop sits well above the
+# ed25519 one.  Not measured on the current code (PERF.md).
 SECP_DEVICE_THRESHOLD = int(os.environ.get(
     "COMETBFT_TPU_SECP_THRESHOLD", "96"))
 

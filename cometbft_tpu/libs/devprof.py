@@ -176,6 +176,10 @@ class DevprofRecorder:
         self._compile_first_seconds = 0.0
         self._compile_count = 0
         self._compile_by_kind: dict[str, dict] = {}
+        # dispatches per labelled device program: (kind, shape) -> n.
+        # Unlike the compile ledger this fills on a warm compile cache
+        # too — it says which programs RAN, not which were built
+        self._programs: dict[tuple, int] = {}
 
     # -- device accounts ---------------------------------------------------
 
@@ -296,6 +300,13 @@ class DevprofRecorder:
             if backend:
                 dm.compile_count.labels(kind).inc()
 
+    def program_event(self, kind: str, shape) -> None:
+        """One dispatch of the device program labelled (kind, shape)
+        (ops/compile_hook.dispatch_scope)."""
+        with self._mtx:
+            key = (kind, shape)
+            self._programs[key] = self._programs.get(key, 0) + 1
+
     # -- reading -----------------------------------------------------------
 
     def snapshot(self) -> dict:
@@ -318,6 +329,12 @@ class DevprofRecorder:
                             sorted(self._compile_by_kind.items())},
                 "entries": entries,
             }
+            programs = [
+                {"kind": k, "shape": list(sh) if sh is not None else None,
+                 "dispatches": n}
+                for (k, sh), n in sorted(
+                    self._programs.items(),
+                    key=lambda kv: (kv[0][0], kv[0][1] or ()))]
             samples = {"recorded": self._sampled,
                        "dropped": self._sampled
                        - min(self._sampled, self.sample_capacity)}
@@ -329,7 +346,7 @@ class DevprofRecorder:
             d["idle_seconds"] = {k: round(v, 6)
                                  for k, v in d["idle_seconds"].items()}
         return {"devices": devices, "compile": compile_,
-                "samples": samples}
+                "programs": programs, "samples": samples}
 
     def dump(self) -> dict:
         return self.snapshot()
@@ -368,6 +385,7 @@ class DevprofRecorder:
             self._compile_first_seconds = 0.0
             self._compile_count = 0
             self._compile_by_kind = {}
+            self._programs = {}
 
 
 def occupancy_summary(snapshot: dict) -> dict:

@@ -34,6 +34,18 @@ _failed = False          # sticky: one bad load/build attempt ends it
 _lib_lock = lockrank.RankedLock("native_codec.lib")
 
 
+def _stale() -> bool:
+    """No .so, or a source newer than it (the check
+    crypto/bls12381.build makes): a library left by an earlier build
+    must not outlive a change to commit_codec.cc."""
+    if not os.path.exists(_LIB_PATH):
+        return True
+    lib_mtime = os.path.getmtime(_LIB_PATH)
+    return any(os.path.getmtime(os.path.join(_NATIVE_DIR, f)) > lib_mtime
+               for f in os.listdir(_NATIVE_DIR)
+               if f.endswith((".cc", ".h")))
+
+
 def build() -> bool:
     """Compile the native library (g++, <1 s).  Returns True when the
     .so exists afterwards — same contract as crypto/bls12381.build()
@@ -54,7 +66,7 @@ def _load():
             return _lib
         if _failed:
             return None
-        if not os.path.exists(_LIB_PATH) and not build():
+        if _stale() and not build():
             # no .so and no toolchain: don't retry per call — the
             # caller sits on the serialization hot path
             _failed = True

@@ -21,6 +21,7 @@ With no ledger installed dispatch_scope returns a shared null context
 
 from __future__ import annotations
 
+import os
 import threading
 
 from ..libs import lockrank
@@ -63,12 +64,23 @@ class _Scope:
         return False
 
 
-def dispatch_scope(kind: str, shape=None):
+def compile_scope(kind: str, shape=None):
     """Label any XLA compile triggered inside the with-block; free (a
     shared null context) when no ledger is installed."""
     if _ledger is None:
         return _NULL_SCOPE
     return _Scope((kind, tuple(shape) if shape is not None else None))
+
+
+def dispatch_scope(kind: str, shape=None):
+    """compile_scope around one DISPATCH of the labelled program, which
+    the ledger also counts (DevprofRecorder.program_event)."""
+    led = _ledger
+    if led is None:
+        return _NULL_SCOPE
+    label = (kind, tuple(shape) if shape is not None else None)
+    led.program_event(*label)
+    return _Scope(label)
 
 
 def _on_event_duration(event: str, duration: float, **kw) -> None:
@@ -108,3 +120,26 @@ def uninstall() -> None:
 
 def ledger():
     return _ledger
+
+
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+
+def ensure_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its
+    directory.  A whole RLC program costs minutes to compile for the
+    chip, so every entry point that dispatches (the node CLI,
+    chip_smoke.py, the test harness) calls this once before its first
+    jit.  JAX_COMPILATION_CACHE_DIR, when set, is read by JAX itself
+    and nothing here overrides it; otherwise the cache lives in
+    <checkout>/.jax_cache (git-ignored) — a fixed path, because the
+    path is part of the cache key."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+
+    path = os.path.join(_CHECKOUT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path

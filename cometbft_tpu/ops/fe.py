@@ -165,7 +165,7 @@ def mul(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
 
 
 # Dedicated squaring: ~210 int32 multiplies vs mul's 400.  Flag is for
-# on-hardware A/B attribution only (scripts/ab_round4b.py).
+# on-hardware A/B attribution only.
 FAST_SQR = os.environ.get("COMETBFT_TPU_FAST_SQR", "1") == "1"
 
 

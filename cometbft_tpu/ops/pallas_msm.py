@@ -35,7 +35,7 @@ from . import fe
 # blocks amortize the per-window shared doublings over more lanes
 # (doubling cost scales with OUT_PER_BLK * nblk = OUT_PER_BLK * W/BLK)
 # at the price of a bigger VMEM-resident table block (17*4*20*BLK*4 B:
-# 2.8 MB at 512, 5.6 MB at 1024) — A/B'd in scripts/ab_round4b.py.
+# 2.8 MB at 512, 5.6 MB at 1024).
 BLK = int(os.environ.get("COMETBFT_TPU_PALLAS_BLK", "512"))
 
 

@@ -180,7 +180,7 @@ class CommitSig:
         # inline fast path (byte parity with the Writer form pinned by
         # tests): a 6668-sig commit serializes on every save_block and
         # gossip send — per-sig Writer objects were the top residual
-        # of the blocksync stage profile (scripts/profile_blocksync.py)
+        # of the blocksync stage profile
         ts = self.timestamp.to_proto()
         uv = pw.encode_uvarint
         out = bytearray()

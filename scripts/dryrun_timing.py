@@ -23,7 +23,9 @@ import time
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 OUT = os.path.join(ROOT, "MULTICHIP_local_timing.json")
-CACHE = "/tmp/cometbft_tpu_jax_cache"
+# where ops/compile_hook.ensure_compile_cache puts it
+CACHE = os.environ.get("JAX_COMPILATION_CACHE_DIR") or \
+    os.path.join(ROOT, ".jax_cache")
 BUDGET_S = 900.0
 
 

@@ -57,7 +57,7 @@ LOWER_IS_BETTER = {"chaos_recovery_seconds",
 # cache landed: the cache removes device dispatches from the
 # proposal->commit critical path BY DESIGN, so the share falling is
 # the optimisation working, not a regression — and it rising again is
-# not an improvement either.  perf_report still prints its trajectory.
+# not an improvement either.
 SKIP = {"rlc_batch", "headline_passes", "vs_baseline",
         "critical_path_device_share",
         # devprof diagnostics (libs/devprof.py): compile seconds flap

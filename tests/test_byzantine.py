@@ -88,7 +88,7 @@ def _find_duplicate_vote_evidence(nodes, byz_addr):
 
 class TestByzantineEquivocation:
     def test_equivocation_evidence_lands_in_block(self):
-        # No retry (r4 VERDICT weak #6): the conflicting vote now goes
+        # No retry: the conflicting vote now goes
         # to EVERY peer each prevote, so evidence forms whenever any
         # honest peer is still inside the round — per-height detection
         # is near-certain instead of scheduler luck against a single

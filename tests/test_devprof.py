@@ -357,6 +357,49 @@ class TestCompileLedger:
                 compile_hook.uninstall()
         assert "other" in rec.snapshot()["compile"]["by_kind"]
 
+    def test_dispatch_scope_counts_programs_that_ran(self):
+        """The program account fills on every labelled dispatch, warm
+        compile cache or not: it says which programs RAN."""
+        prev = compile_hook.ledger()
+        rec = devprof.DevprofRecorder()
+        compile_hook.install(rec)
+        try:
+            for _ in range(3):
+                with compile_hook.dispatch_scope("devprof_test", (8, 16)):
+                    pass
+            with compile_hook.dispatch_scope("devprof_test", (8,)):
+                pass
+        finally:
+            if prev is not None:
+                compile_hook.install(prev)
+            else:
+                compile_hook.uninstall()
+        assert rec.snapshot()["programs"] == [
+            {"kind": "devprof_test", "shape": [8], "dispatches": 1},
+            {"kind": "devprof_test", "shape": [8, 16], "dispatches": 3}]
+
+    @pytest.mark.parametrize("env_dir", ["/somewhere/else", None])
+    def test_compile_cache_dir(self, env_dir, monkeypatch):
+        """JAX_COMPILATION_CACHE_DIR set: JAX reads it itself and the
+        helper touches nothing.  Unset: <checkout>/.jax_cache."""
+        import os
+
+        jax = pytest.importorskip("jax")
+        updates = []
+        monkeypatch.setattr(jax.config, "update",
+                            lambda k, v: updates.append((k, v)))
+        if env_dir:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+            assert compile_hook.ensure_compile_cache() == env_dir
+            assert updates == []
+        else:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            checkout = os.path.dirname(os.path.dirname(
+                os.path.abspath(__file__)))
+            want = os.path.join(checkout, ".jax_cache")
+            assert compile_hook.ensure_compile_cache() == want
+            assert updates == [("jax_compilation_cache_dir", want)]
+
 
 class TestMetricsSurface:
     def test_live_metrics_scrape_has_devprof_series(self):

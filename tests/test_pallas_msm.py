@@ -17,8 +17,8 @@ Two tiers, both CPU-safe:
    is replaced at trace time with a spy that records the call and
    returns the XLA-branch value, so the end-to-end verdicts (accept +
    tampered-reject) are checked without paying a giant interpret
-   compile.  Full-width semantic equality on real Mosaic is the
-   hardware A/B queue's job (scripts/ab_round3.py).
+   compile.  Full-width semantic equality on real Mosaic is
+   chip_smoke.py's job (reference verdicts on the chip).
 """
 
 import numpy as np
@@ -149,7 +149,7 @@ def _xla_epilogue_verdict(pa, pr):
 def test_fold_verify_matches_xla():
     """Fused fold/verify epilogue vs the XLA reference at tile 8 (the
     halving/butterfly argument is width-independent; real Mosaic at
-    tile 128 is covered by scripts/mosaic_smoke4b.py): the identity
+    tile 128 is covered by tests/test_tpu_compile.py): the identity
     case (R side = negated A side) must accept, the non-identity case
     must reject."""
     pa = _points(16, distinct=8)     # 2*tile: exercises the halving
@@ -529,9 +529,7 @@ def test_msm_window_major_grouped_matches_scan():
     """The grouped window-major kernel (G windows per table fetch, per-
     window VMEM scratch accumulators, fori_loop group-close doubling
     chain) equals the XLA shared-doubling scan.  Slow tier: each
-    interpret compile is ~3.5 min on one core (the kernel also has
-    real-Mosaic parity probes in scripts/mosaic_smoke5.py and A/B
-    coverage in scripts/ab_round5.py).  Combos cover multiblock wacc
+    interpret compile is ~3.5 min on one core.  Combos cover multiblock wacc
     accumulation (blk 8), divisor degradation (4 -> 3), the jg != 0
     later-group close, single-block grids, and group == nwin."""
     nwin = 6

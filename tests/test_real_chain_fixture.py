@@ -7,7 +7,7 @@ light-client commit verification over the canonical vote sign-bytes.
 
 Any drift in light/rpc_decode, types/canonical, merkle hashing, or
 commit verification breaks a FROZEN pin, not a value computed by the
-same code under test (VERDICT r4 item 7).  The fixture generator
+same code under test.  The fixture generator
 (scripts/gen_real_chain_fixture.py) documents the serializer
 correspondence; it is never run by tests.
 """

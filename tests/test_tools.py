@@ -595,14 +595,6 @@ class TestPerfGate:
              "parsed": {"metric": "sigs_per_sec", "value": value,
                         "unit": "sigs/s", "extra": extra or {}}}))
 
-    def test_committed_trajectory_gates_clean(self, capsys):
-        """The repo's own BENCH_r*.json history must pass its own
-        gate — this is the check the driver runs every round."""
-        mod = self._load()
-        assert mod.main(["--check-only"]) == 0
-        out = capsys.readouterr().out
-        assert "0 regression(s)" in out
-
     def test_gate_flags_regression_and_direction(self):
         mod = self._load()
         history = [{"headline": 100.0, "chaos_recovery_seconds": 10.0}
@@ -983,56 +975,6 @@ class TestMultichipDryrunBudget:
         # artifact's 2x-headroom guard covers the warm steady state)
         assert dt < 2 * self.BUDGET_S, f"dryrun took {dt:.0f}s"
         assert timings is not None and "total" in timings
-
-
-class TestBenchSteering:
-    """bench.py `_best_measured_config` (ADVICE r5 finding 2): arms
-    rank by the MEDIAN of their stored pass_rates, never by a single
-    outlier pass inside the ±7% relay swing."""
-
-    @staticmethod
-    def _load_bench():
-        import importlib.util
-        import pathlib
-        path = pathlib.Path(__file__).resolve().parent.parent / "bench.py"
-        spec = importlib.util.spec_from_file_location("bench_mod", path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod
-
-    def test_median_beats_outlier_max(self, tmp_path, monkeypatch):
-        import json
-        mod = self._load_bench()
-        rows = [
-            # one lucky pass (1000k) but a terrible median
-            {"name": "win_group_ab", "group": 1, "batch": 1024,
-             "sigs_per_sec": 1_000_000.0,
-             "pass_rates": [100_000.0, 1_000_000.0, 110_000.0]},
-            # steadier arm: lower max, higher median — must win
-            {"name": "win_group_ab", "group": 4, "batch": 2048,
-             "sigs_per_sec": 210_000.0,
-             "pass_rates": [205_000.0, 210_000.0, 208_000.0]},
-            # non-comparable arm families never steer
-            {"name": "iters16_ab", "group": 1, "batch": 65536,
-             "sigs_per_sec": 9_999_999.0,
-             "pass_rates": [9_999_999.0] * 3},
-        ]
-        p = tmp_path / "ab.jsonl"
-        p.write_text("\n".join(json.dumps(r) for r in rows) + "\n")
-        monkeypatch.setattr(mod, "AB5_PATH", str(p))
-        g, b, r, arm = mod._best_measured_config()
-        assert (g, b) == (4, 2048)
-        assert r == 208_000.0          # the median, not the max
-
-    def test_committed_evidence_picks_batch_131071(self):
-        """The repo's real round-5 evidence steers to (G1, 131071) —
-        the pick docs/PERF.md documents; a regression here silently
-        changes what the unattended capture measures."""
-        mod = self._load_bench()
-        pick = mod._best_measured_config()
-        assert pick is not None
-        g, b, _, _ = pick
-        assert (g, b) == (1, 131071)
 
 
 class TestCheckConcurrency:

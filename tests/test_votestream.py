@@ -114,8 +114,8 @@ class _StubPipeline:
 class TestPrewarm:
     def test_warmup_dispatches_dummy_batch(self):
         """warmup=True: start() compiles+dispatches one dummy device
-        batch (VERDICT item 8 — the 31.9 ms cold p99 outlier was the
-        first flush paying compile+dispatch); the warm batch must use
+        batch (the cold p99 outlier was the first flush paying
+        compile+dispatch); the warm batch must use
         DISTINCT keys so the A-side MSM width matches a real flood."""
         stub = _StubPipeline()
         sv = StreamingVerifier(device_threshold=16, pipeline=stub,
