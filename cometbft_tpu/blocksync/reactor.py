@@ -298,8 +298,10 @@ class BlocksyncReactor(Reactor):
                         vals = self.state.next_validators
                     else:
                         break
-                    parts = PartSet.from_data(block.to_proto())
-                    bid = BlockID(block.hash(), parts.header)
+                    with trace_span("blocksync", "partset",
+                                    height=collecting_h):
+                        parts = PartSet.from_data(block.to_proto())
+                        bid = BlockID(block.hash(), parts.header)
                     parts_ids.append((parts, bid))
                     vals.verify_commit_light(
                         self.state.chain_id, bid, block.header.height,
@@ -455,8 +457,10 @@ class BlocksyncReactor(Reactor):
                         vals = self.state.next_validators
                     else:
                         break
-                    parts = PartSet.from_data(block.to_proto())
-                    bid = BlockID(block.hash(), parts.header)
+                    with trace_span("blocksync", "partset",
+                                    height=collecting_h):
+                        parts = PartSet.from_data(block.to_proto())
+                        bid = BlockID(block.hash(), parts.header)
                     parts_ids.append((parts, bid))
                     vals.verify_commit_light(
                         self.state.chain_id, bid, block.header.height,

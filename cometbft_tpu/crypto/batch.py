@@ -20,6 +20,8 @@ from __future__ import annotations
 import os
 from typing import Protocol
 
+from ..libs import metrics as libmetrics
+from ..libs.trace import span as trace_span
 from . import ed25519 as ed
 from . import sigcache
 
@@ -93,14 +95,26 @@ class TpuEd25519BatchVerifier(_SigCollector):
             return False, []
         pks = [i[0] for i in self._items]
         # parse + hash ONCE; both device packings build from this
-        parsed = ed.parse_and_hash(pks, [i[1] for i in self._items],
-                                   [i[2] for i in self._items])
+        with trace_span("verify", "host_pack", batch=len(pks)):
+            parsed = ed.parse_and_hash(pks, [i[1] for i in self._items],
+                                       [i[2] for i in self._items])
         return _device_verify(pks, parsed)
 
 
 # sentinel: "no precomputed RLC packing" (None is a real pack_rlc
 # result meaning structural reject, so it cannot double as the default)
 _NO_PACK = object()
+
+
+def _count_verified(program: str, n: int) -> None:
+    """n signatures got their verdict from a device program: "rlc"
+    (the whole batch accepted in one equation) or "persig" (the
+    per-signature kernel, after a reject or for a batch of one).
+    Counted here, where windows and seam batches both pass, so the
+    count holds whatever plan made the dispatches."""
+    dm = libmetrics.device_metrics()
+    if dm is not None:
+        dm.signatures_verified.labels(program).add(n)
 
 
 def _device_verify(pubkeys: list[bytes], parsed, packed=_NO_PACK,
@@ -132,14 +146,15 @@ def _device_verify(pubkeys: list[bytes], parsed, packed=_NO_PACK,
             rlc_ok = mesh.maybe_split_verify(pubkeys, parsed)
         if rlc_ok is None:
             if packed is _NO_PACK:
-                packed = ed.pack_rlc(pubkeys, [b""] * n, [b""] * n,
-                                     parsed=parsed)
+                with trace_span("verify", "host_pack", batch=n):
+                    packed = ed.pack_rlc(pubkeys, [b""] * n, [b""] * n,
+                                         parsed=parsed)
             rlc_ok = packed is not None and \
                 ed.rlc_verify(packed, device=device)
         if rlc_ok:
+            _count_verified("rlc", n)
             return True, [True] * n
         from ..libs import flightrec
-        from ..libs import metrics as libmetrics
 
         dm = libmetrics.device_metrics()
         if dm is not None:
@@ -158,6 +173,7 @@ def _device_verify(pubkeys: list[bytes], parsed, packed=_NO_PACK,
         a, r, s, h, valid = ed.pack_batch(pubkeys, [b""] * n, [b""] * n,
                                           bucket, parsed=parsed)
         verdict = np.asarray(sharding.verify_batch_sharded(a, r, s, h))
+    _count_verified("persig", n)
     verdict = verdict & valid
     out = verdict[:n].tolist()
     return all(out) and bool(out), out
@@ -187,14 +203,15 @@ def _device_verify_hash(pubkeys: list[bytes], msgs: list[bytes], parsed,
             rlc_ok = mesh.maybe_split_verify_hash(pubkeys, msgs, parsed)
         if rlc_ok is None:
             if packed is _NO_PACK:
-                packed = ed.pack_rlc_device_hash(pubkeys, msgs,
-                                                 [b""] * n, parsed=parsed)
+                with trace_span("verify", "host_pack", batch=n):
+                    packed = ed.pack_rlc_device_hash(
+                        pubkeys, msgs, [b""] * n, parsed=parsed)
             rlc_ok = packed is not None and \
                 ed.rlc_verify_hash(packed, device=device)
         if rlc_ok:
+            _count_verified("rlc", n)
             return True, [True] * n
         from ..libs import flightrec
-        from ..libs import metrics as libmetrics
 
         dm = libmetrics.device_metrics()
         if dm is not None:
@@ -210,6 +227,7 @@ def _device_verify_hash(pubkeys: list[bytes], msgs: list[bytes], parsed,
                                for x in (a, r, s, bh, bl, nb))
     verdict = np.asarray(dev.verify_batch_hash_device(a, r, s, bh, bl,
                                                       nb))
+    _count_verified("persig", n)
     verdict = verdict & valid
     out = verdict[:n].tolist()
     return all(out) and bool(out), out

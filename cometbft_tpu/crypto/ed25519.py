@@ -19,6 +19,7 @@ from functools import lru_cache
 
 from . import ed25519_ref as ref
 from ..libs import lockrank
+from ..libs.trace import span as trace_span
 from .hash import sum_sha256
 
 KEY_TYPE = "ed25519"
@@ -590,16 +591,25 @@ def rlc_verify_hash_async(packed, device=None):
     fraction of its runtime than of the host-hash kernel's."""
     from ..ops import ed25519 as dev
 
-    if device is not None:
-        import jax
+    with trace_span("verify", "dispatch", k=packed[0].shape[-1],
+                    n=packed[1].shape[-1], cached=False):
+        if device is not None:
+            import jax
 
-        packed = tuple(jax.device_put(np.asarray(x), device)
-                       for x in packed)
-    return dev.rlc_verify_hash_device(*packed)
+            packed = tuple(jax.device_put(np.asarray(x), device)
+                           for x in packed)
+        return dev.rlc_verify_hash_device(*packed)
+
+
+def _read_verdict(out) -> bool:
+    """Block until the verdict bit of an rlc_verify*_async dispatch is
+    on the host: the wait for the device, and little else."""
+    with trace_span("verify", "readback"):
+        return bool(np.asarray(out))
 
 
 def rlc_verify_hash(packed, device=None) -> bool:
-    return bool(np.asarray(rlc_verify_hash_async(packed, device=device)))
+    return _read_verdict(rlc_verify_hash_async(packed, device=device))
 
 
 # one cached A-table slot: 17 rows x 4 coords x 20 int32 limbs
@@ -767,25 +777,30 @@ def rlc_verify_async(packed, use_cache: bool | None = None,
 
     a_words, r_words, a_mag, a_neg, r_mag, r_neg = packed
     a_np = np.asarray(a_words)
-    entry = None
-    if use_cache is True:
-        entry = _A_TABLE_CACHE.get(a_np, device=device)
-    elif use_cache is None and USE_A_CACHE:
-        entry = _A_TABLE_CACHE.get_if_worthwhile(a_np, device=device)
-    if device is not None:
-        import jax
+    # the host's share of one dispatch, up to the program's
+    # (asynchronous) return; k and n are the padded widths
+    with trace_span("verify", "dispatch", k=a_np.shape[-1],
+                    n=r_words.shape[-1]) as sp:
+        entry = None
+        if use_cache is True:
+            entry = _A_TABLE_CACHE.get(a_np, device=device)
+        elif use_cache is None and USE_A_CACHE:
+            entry = _A_TABLE_CACHE.get_if_worthwhile(a_np, device=device)
+        sp.note(cached=entry is not None)
+        if device is not None:
+            import jax
 
-        r_words, a_mag, a_neg, r_mag, r_neg = (
-            jax.device_put(np.asarray(x), device)
-            for x in (r_words, a_mag, a_neg, r_mag, r_neg))
-        if entry is None:
-            a_words = jax.device_put(a_np, device)
-    if entry is not None:
-        a_tab, a_ok = entry
-        return dev.rlc_verify_device_cached_a(
-            a_tab, a_ok, r_words, a_mag, a_neg, r_mag, r_neg)
-    return dev.rlc_verify_device(a_words, r_words,
-                                 a_mag, a_neg, r_mag, r_neg)
+            r_words, a_mag, a_neg, r_mag, r_neg = (
+                jax.device_put(np.asarray(x), device)
+                for x in (r_words, a_mag, a_neg, r_mag, r_neg))
+            if entry is None:
+                a_words = jax.device_put(a_np, device)
+        if entry is not None:
+            a_tab, a_ok = entry
+            return dev.rlc_verify_device_cached_a(
+                a_tab, a_ok, r_words, a_mag, a_neg, r_mag, r_neg)
+        return dev.rlc_verify_device(a_words, r_words,
+                                     a_mag, a_neg, r_mag, r_neg)
 
 
 def rlc_verify(packed, use_cache: bool | None = None,
@@ -796,5 +811,5 @@ def rlc_verify(packed, use_cache: bool | None = None,
     kernel, None (the default policy, COMETBFT_TPU_A_CACHE=0 disables)
     uses a cached table only for valsets seen before — one-shot
     batches keep the single fused dispatch.  Returns the verdict bit."""
-    return bool(np.asarray(rlc_verify_async(
-        packed, use_cache=use_cache, device=device)))
+    return _read_verdict(rlc_verify_async(
+        packed, use_cache=use_cache, device=device))

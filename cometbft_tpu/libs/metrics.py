@@ -558,6 +558,13 @@ class DeviceMetrics:
             "device", "rlc_fallbacks",
             "RLC whole-batch checks that failed and fell back to the "
             "per-signature verdict kernel.")
+        self.signatures_verified = reg.counter(
+            "device", "signatures_verified_total",
+            "Signatures whose verdict a device program produced, by "
+            "program (rlc = the whole batch accepted in one equation, "
+            "persig = the per-signature kernel).  Counted where "
+            "pipeline windows and synchronous seam batches both pass.",
+            labels=("program",))
         self.device_hash_fallbacks = reg.counter(
             "device", "device_hash_fallbacks",
             "Pipeline windows that left the fused device-hash path "

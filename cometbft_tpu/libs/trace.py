@@ -1,5 +1,7 @@
 """Stage-span tracing: per-stage wall-clock timers for the protocol
-hot paths (decode -> verify-dispatch -> device -> apply -> store).
+hot paths (decode -> verify-dispatch -> device -> apply -> store), and
+below them the inside of `apply` (state.*) and every RLC dispatch of the
+verify plane (verify.*).
 
 The host-residual breakdown that blocksync_profile_r5.jsonl measured
 with a one-off script becomes a first-class observable: reactors and
@@ -17,10 +19,25 @@ The seam mirrors libs/metrics.set_device_metrics: a module-level
 tracer the crypto/reactor layers reach without any node wiring.  With
 no tracer installed a span is a shared no-op object — the hot paths
 pay one global read and an `is None` test.
+
+Two clocks round the device, both the host's.  `<subsystem>.device` is
+opened by the verify pipeline round a whole WINDOW's dispatch and
+readback (crypto/dispatch.py); a batch that reaches the device through
+the synchronous seam (crypto/batch.py: the apply-time LastCommit
+remainder) never passes it.  `verify.dispatch` and `verify.readback`
+are the per-dispatch ones: they sit in crypto/ed25519's rlc_verify*
+funnel, which windows and seam batches both pass, and split a dispatch
+into the host's enqueue and the host's wait for the verdict bit.
+
+Spans nest.  A span opened inside another on the same thread carries
+the enclosing span's "subsystem.stage" as `parent` in its interval's
+fields, and the tracer keeps each span's children's seconds, so
+self_seconds() says what of a span no child names.
 """
 
 from __future__ import annotations
 
+import threading
 import time
 
 from . import lockrank
@@ -34,23 +51,39 @@ BLOCKSYNC_STAGES = ("decode", "verify_dispatch", "device", "apply",
 # the staging thread — concurrent with the previous window's device
 PIPELINE_STAGES = ("collect", "host_pack")
 LIGHT_STAGES = ("fetch", "verify_dispatch", "device", "store")
+# subsystem "state": the inside of BlockExecutor's validate + apply
+# (state/execution.py), one span a block each but `save` and `events`,
+# which the crash-safety order opens twice
+APPLY_STAGES = ("validate", "abci_finalize", "save", "update",
+                "abci_commit", "events")
+# subsystem "verify": one RLC batch through the device funnel.
+# host_pack only where the batch did not come packed from the
+# pipeline's staging thread (that one is <subsystem>.host_pack)
+VERIFY_STAGES = ("host_pack", "dispatch", "readback")
 
-# interval ring size per tracer: enough to prove overlap across a
-# bench run without unbounded growth on long-lived nodes
+# interval ring size per (subsystem, stage): enough to prove overlap
+# across a bench run without unbounded growth on long-lived nodes.
+# Per stage, not over all: a dozen state.*/verify.* spans a block
+# would otherwise push the few device/collect intervals a window
+# leaves out of a shared ring before overlap_seconds reads them
 MAX_INTERVALS = 1024
 
 
 class StageTracer:
     """Accumulates span durations per (subsystem, stage); optionally
     mirrors every observation into a metrics.TraceMetrics bundle.
-    Also keeps a bounded ring of (start, end) INTERVALS per span so
-    concurrency between stages — the overlapped pipeline's whole
-    claim — is provable from the record, not asserted."""
+    Also keeps a bounded ring of (start, end) INTERVALS per
+    (subsystem, stage) so concurrency between stages — the overlapped
+    pipeline's whole claim — is provable from the record, not
+    asserted."""
 
     def __init__(self, metrics=None):
         self._mtx = lockrank.RankedLock("trace.stage")
         self._totals: dict[tuple[str, str], list] = {}
-        self._intervals: list = []      # (sub, stage, t0, t1, fields)
+        # "subsystem.stage" of a parent -> seconds its children took
+        self._child_seconds: dict[str, float] = {}
+        # (sub, stage) -> [(t0, t1, fields)], oldest first
+        self._intervals: dict[tuple[str, str], list] = {}
         self.dropped_intervals = 0      # ring overflow, no longer silent
         self.metrics = metrics
 
@@ -58,15 +91,20 @@ class StageTracer:
                end: float | None = None, fields=None) -> None:
         t1 = end if end is not None else time.perf_counter()
         overflow = 0
+        key = (subsystem, stage)
+        parent = fields.get("parent") if fields else None
         with self._mtx:
-            t = self._totals.setdefault((subsystem, stage), [0, 0.0])
+            t = self._totals.setdefault(key, [0, 0.0])
             t[0] += 1
             t[1] += seconds
-            self._intervals.append(
-                (subsystem, stage, t1 - seconds, t1, fields))
-            if len(self._intervals) > MAX_INTERVALS:
-                overflow = len(self._intervals) - MAX_INTERVALS
-                del self._intervals[:overflow]
+            if parent is not None:
+                self._child_seconds[parent] = \
+                    self._child_seconds.get(parent, 0.0) + seconds
+            ring = self._intervals.setdefault(key, [])
+            ring.append((t1 - seconds, t1, fields))
+            if len(ring) > MAX_INTERVALS:
+                overflow = len(ring) - MAX_INTERVALS
+                del ring[:overflow]
                 self.dropped_intervals += overflow
         if self.metrics is not None:
             self.metrics.stage_duration_seconds.labels(
@@ -76,14 +114,17 @@ class StageTracer:
 
     def intervals(self, subsystem: str | None = None,
                   stage: str | None = None) -> list[dict]:
-        """Retained span intervals, oldest first."""
+        """Retained span intervals, in the order they ended."""
         with self._mtx:
-            raw = list(self._intervals)
+            raw = [(t1, t0, sub, st, f)
+                   for (sub, st), ring in self._intervals.items()
+                   if (subsystem is None or sub == subsystem)
+                   and (stage is None or st == stage)
+                   for (t0, t1, f) in ring]
+        raw.sort(key=lambda r: r[0])
         return [{"subsystem": sub, "stage": st, "start": t0, "end": t1,
                  **(dict(f) if f else {})}
-                for (sub, st, t0, t1, f) in raw
-                if (subsystem is None or sub == subsystem)
-                and (stage is None or st == stage)]
+                for (t1, t0, sub, st, f) in raw]
 
     def overlap_seconds(self, subsystem: str, stage_a: str,
                         stage_b: str) -> float:
@@ -101,6 +142,15 @@ class StageTracer:
                     total += hi - lo
         return total
 
+    def self_seconds(self, subsystem: str, stage: str) -> float:
+        """A stage's seconds less what the spans opened inside it, on
+        the same thread, cover: the part of it no child names.  From
+        the running totals, not the ring, so nothing ages out of it."""
+        with self._mtx:
+            t = self._totals.get((subsystem, stage))
+            return (t[1] if t else 0.0) - self._child_seconds.get(
+                f"{subsystem}.{stage}", 0.0)
+
     def snapshot(self) -> dict:
         """{"subsystem.stage": {"count": n, "seconds": s}} — the shape
         the simnet benches report alongside their e2e rates."""
@@ -112,6 +162,7 @@ class StageTracer:
     def reset(self) -> None:
         with self._mtx:
             self._totals.clear()
+            self._child_seconds.clear()
 
 
 class _NullSpan:
@@ -123,8 +174,15 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
+    def note(self, **fields) -> None:
+        pass
+
 
 _NULL_SPAN = _NullSpan()
+
+
+# the spans open on this thread, innermost last ("subsystem.stage")
+_open = threading.local()
 
 
 class _TimedSpan:
@@ -138,14 +196,30 @@ class _TimedSpan:
         self._fields = fields
 
     def __enter__(self):
+        try:
+            stack = _open.stack
+        except AttributeError:
+            stack = _open.stack = []
+        if stack:
+            self.note(parent=stack[-1])
+        stack.append(f"{self._subsystem}.{self._stage}")
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter()
+        _open.stack.pop()
         self._tracer.record(self._subsystem, self._stage,
                             t1 - self._t0, end=t1, fields=self._fields)
         return False
+
+    def note(self, **fields) -> None:
+        """Fields known only once the span is open (a cache hit, a
+        width) land on its interval like span()'s own."""
+        if self._fields is None:
+            self._fields = fields
+        else:
+            self._fields.update(fields)
 
 
 # process-wide tracer seam (same pattern as metrics.set_device_metrics)

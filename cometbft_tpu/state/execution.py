@@ -11,6 +11,7 @@ from __future__ import annotations
 
 from ..abci import types as at
 from ..crypto import encoding as key_encoding
+from ..libs.trace import span as trace_span
 from ..types import events as ev
 from ..types.block import (
     BLOCK_ID_FLAG_ABSENT, Block, BlockID, Commit, ExtendedCommit,
@@ -160,17 +161,20 @@ class BlockExecutor:
 
     # -- validation --------------------------------------------------------
     def validate_block(self, state: State, block: Block) -> None:
-        if self._last_validated_hash != block.hash():
-            validate_block(state, block)
-            self._last_validated_hash = block.hash()
+        self._validate_once(state, block)
         self.evpool.check_evidence(block.evidence)
+
+    def _validate_once(self, state: State, block: Block) -> None:
+        if self._last_validated_hash != block.hash():
+            with trace_span("state", "validate",
+                            height=block.header.height):
+                validate_block(state, block)
+            self._last_validated_hash = block.hash()
 
     # -- apply -------------------------------------------------------------
     def apply_block(self, state: State, block_id: BlockID, block: Block,
                     syncing_to_height: int | None = None) -> State:
-        if self._last_validated_hash != block.hash():
-            validate_block(state, block)
-            self._last_validated_hash = block.hash()
+        self._validate_once(state, block)
         return self._apply_block(state, block_id, block,
                                  syncing_to_height or block.header.height)
 
@@ -186,20 +190,24 @@ class BlockExecutor:
 
         from ..libs.fail import fail_point
 
+        # one span a step (libs/trace.APPLY_STAGES), all under the
+        # block's height: what blocksync.apply is made of
+        height = block.header.height
         t0 = _time.monotonic()
-        abci_response = self.proxy_app.finalize_block(
-            at.FinalizeBlockRequest(
-                hash=block.hash(),
-                next_validators_hash=block.header.next_validators_hash,
-                proposer_address=block.header.proposer_address,
-                height=block.header.height,
-                time=block.header.time,
-                decided_last_commit=self._build_last_commit_info(
-                    block, state),
-                misbehavior=_misbehavior(block.evidence),
-                txs=list(block.data.txs),
-                syncing_to_height=syncing_to_height,
-            ))
+        with trace_span("state", "abci_finalize", height=height):
+            abci_response = self.proxy_app.finalize_block(
+                at.FinalizeBlockRequest(
+                    hash=block.hash(),
+                    next_validators_hash=block.header.next_validators_hash,
+                    proposer_address=block.header.proposer_address,
+                    height=height,
+                    time=block.header.time,
+                    decided_last_commit=self._build_last_commit_info(
+                        block, state),
+                    misbehavior=_misbehavior(block.evidence),
+                    txs=list(block.data.txs),
+                    syncing_to_height=syncing_to_height,
+                ))
         if len(block.data.txs) != len(abci_response.tx_results):
             raise InvalidBlockError(
                 f"expected {len(block.data.txs)} tx results, got "
@@ -217,38 +225,47 @@ class BlockExecutor:
         fail_point("exec-after-finalize")
 
         # save results before commit (crash window covered by handshake)
-        self.store.save_finalize_block_response(
-            block.header.height, abci_response.to_proto())
+        with trace_span("state", "save", height=height):
+            self.store.save_finalize_block_response(
+                height, abci_response.to_proto())
 
         fail_point("exec-after-save-response")
 
-        validator_updates = validate_validator_updates(
-            abci_response.validator_updates,
-            state.consensus_params.validator)
+        with trace_span("state", "update", height=height):
+            validator_updates = validate_validator_updates(
+                abci_response.validator_updates,
+                state.consensus_params.validator)
 
-        new_state = update_state(state, block_id, block, abci_response,
-                                 validator_updates)
+            new_state = update_state(state, block_id, block,
+                                     abci_response, validator_updates)
 
         # lock mempool, commit app, update mempool (execution.go:405)
-        retain_height = self.commit(new_state, block, abci_response)
+        with trace_span("state", "abci_commit", height=height):
+            retain_height = self.commit(new_state, block, abci_response)
 
-        self.evpool.update(new_state, block.evidence)
+        # the evidence pool learns of the block before the state is
+        # saved, subscribers after: "events" opens on both sides
+        with trace_span("state", "events", height=height):
+            self.evpool.update(new_state, block.evidence)
 
         fail_point("exec-after-app-commit")
 
         new_state.app_hash = abci_response.app_hash
-        self.store.save(new_state)
+        with trace_span("state", "save", height=height):
+            self.store.save(new_state)
 
         fail_point("exec-after-state-save")
 
-        if retain_height > 0 and self.pruner is not None:
-            try:
-                self.pruner.set_application_block_retain_height(
-                    retain_height)
-            except Exception:
-                pass
+        with trace_span("state", "events", height=height):
+            if retain_height > 0 and self.pruner is not None:
+                try:
+                    self.pruner.set_application_block_retain_height(
+                        retain_height)
+                except Exception:
+                    pass
 
-        self._fire_events(block, block_id, abci_response, validator_updates)
+            self._fire_events(block, block_id, abci_response,
+                              validator_updates)
         return new_state
 
     def commit(self, state: State, block: Block,
