@@ -591,8 +591,9 @@ def rlc_verify_hash_async(packed, device=None):
     fraction of its runtime than of the host-hash kernel's."""
     from ..ops import ed25519 as dev
 
-    with trace_span("verify", "dispatch", k=packed[0].shape[-1],
-                    n=packed[1].shape[-1], cached=False):
+    k, n = packed[0].shape[-1], packed[1].shape[-1]
+    with trace_span("verify", "dispatch", k=k, n=n, cached=False,
+                    kernel=dev.rlc_kernel_name(k, n)):
         if device is not None:
             import jax
 
@@ -723,7 +724,10 @@ class ATableCache:
     # decompression/table work is proportional to K, while the split
     # into two dispatches (and, on cold caches, a fresh compile of the
     # cached-kernel shape) is constant.  Small-K batches — live
-    # consensus vote flushes — stay on the fused kernel.
+    # consensus vote flushes — stay on the fused kernel.  Compared with
+    # the PADDED K: on the chip pad_width returns no width under 128,
+    # so every batch passes this there and it is the second-sighting
+    # rule below that keeps one-shot flushes fused.
     MIN_K = int(os.environ.get("COMETBFT_TPU_A_CACHE_MIN_K", "64"))
 
     def get_if_worthwhile(self, a_words: np.ndarray, device=None):
@@ -778,9 +782,12 @@ def rlc_verify_async(packed, use_cache: bool | None = None,
     a_words, r_words, a_mag, a_neg, r_mag, r_neg = packed
     a_np = np.asarray(a_words)
     # the host's share of one dispatch, up to the program's
-    # (asynchronous) return; k and n are the padded widths
-    with trace_span("verify", "dispatch", k=a_np.shape[-1],
-                    n=r_words.shape[-1]) as sp:
+    # (asynchronous) return; k and n are the padded widths, kernel
+    # says whether the program at those widths is the Pallas kernels
+    # or has a stage on the XLA path
+    k, n = a_np.shape[-1], r_words.shape[-1]
+    with trace_span("verify", "dispatch", k=k, n=n,
+                    kernel=dev.rlc_kernel_name(k, n)) as sp:
         entry = None
         if use_cache is True:
             entry = _A_TABLE_CACHE.get(a_np, device=device)

@@ -346,21 +346,43 @@ _SMALL_WIDTHS = (8, 16, 32, 64, 96, 128, 160, 192)
 _BASE_WIDTHS = (128, 160, 192)
 
 
-def pad_width(n: int) -> int:
-    """Bucketed batch width for an MSM side: small widths verbatim,
-    larger ones base*2^L with base in a 3-element grid — bounds the
-    number of compiled shapes while keeping pad waste <= 25% (a plain
-    next-pow2 pad wastes up to 100%: K=4097 -> 8192)."""
-    if n <= _SMALL_WIDTHS[-1]:
-        for w in _SMALL_WIDTHS:
-            if n <= w:
-                return w
+def _grid_widths():
+    """The bucket grid, ascending: the small widths verbatim, then
+    base*2^L with base in a 3-element grid — bounds the number of
+    compiled shapes while keeping pad waste <= 25% (a plain next-pow2
+    pad wastes up to 100%: K=4097 -> 8192)."""
+    yield from _SMALL_WIDTHS
     lvl = 1
     while True:
         for base in _BASE_WIDTHS:
-            if n <= base << lvl:
-                return base << lvl
+            yield base << lvl
         lvl += 1
+
+
+def pad_width(n: int) -> int:
+    """Bucketed batch width for an MSM side: the first grid width that
+    holds n and — where the Pallas kernels lower for real
+    (_pallas_capable) — that a Pallas block divides
+    (pallas_msm.blk_for), so no RLC batch packed on the chip lowers to
+    the XLA Straus scan: n <= 128 -> 128, 129..256 -> 256,
+    257..320 -> 384 at the default block, the rest of the grid as it
+    is.  A 58-signature batch at width 64 kept the chip busy 22 ms on
+    the XLA path where 3,744 signatures at 4096 take 4 on the kernels
+    (PERF.md); pad slots contribute the identity (pack_rlc).  Off the
+    chip the XLA path is the product path and the grid is used as is."""
+    grid = (w for w in _grid_widths() if w >= n)
+    first = w = next(grid)
+    if not _pallas_capable():
+        return first
+    from . import pallas_msm
+    while pallas_msm.blk_for(w) is None:
+        if w >= 512:
+            # any block setting that is legal at all gives 512 a
+            # block: none is (a garbage COMETBFT_TPU_PALLAS_BLK), every
+            # width takes the XLA path, so keep the grid's own
+            return first
+        w = next(grid)
+    return w
 
 
 def _npart(w: int) -> int:
@@ -516,8 +538,9 @@ def _loop_partials(tab, mags, negs):
 def _pallas_blk(w: int, cap: int | None = None):
     """Lane block the Pallas kernels take at side width w, or None when
     that side runs the XLA path: off the chip, or at a width no
-    power-of-two block >= 128 divides (pallas_msm.blk_for) — a
-    175-validator A side pads to 192 lanes and lands here."""
+    power-of-two block >= 128 divides (pallas_msm.blk_for: 64, 192).
+    On the chip pad_width returns no such width, so only a caller that
+    packs at a width of its own lands here."""
     if not _pallas_capable():
         return None
     from . import pallas_msm
@@ -554,6 +577,24 @@ def rlc_kernel_plan(k: int, n: int) -> dict:
         else:
             s["msm"] = name(loops and s["blk"] is not None)
     return {"a": a, "r": r, "fold": "pallas" if fold else "xla"}
+
+
+@_functools.lru_cache(maxsize=None)
+def _rlc_kernel_name(k: int, n: int, capable: bool) -> str:
+    # capable is only part of the key: the plan asks _pallas_capable
+    plan = rlc_kernel_plan(k, n)
+    stages = {plan["fold"]} | {plan[side][stage] for side in ("a", "r")
+                               for stage in ("decompress", "tables", "msm")}
+    return "pallas" if stages == {"pallas"} else "xla"
+
+
+def rlc_kernel_name(k: int, n: int) -> str:
+    """'pallas' where every stage of rlc_kernel_plan(k, n) is a Pallas
+    kernel, 'xla' where any stage lowers to the XLA path: what a
+    verify.dispatch span says of the program it dispatched.  Memoised
+    per (k, n), and per backend capability so a test that patches it
+    reads its own answer: a dispatch pays a dictionary lookup."""
+    return _rlc_kernel_name(k, n, _pallas_capable())
 
 
 def _prefold(partials):

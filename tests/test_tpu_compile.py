@@ -32,6 +32,13 @@ from cometbft_tpu.ops import pallas_msm
 W = 8192          # one 10,000-validator commit: 6,667 signers pad here
 BLK = 512         # pallas_msm.BLK, the block the product takes at W
 
+# (width, block) each kernel is held at: the widest side of the main
+# path, and the narrowest width pad_width returns on the chip - one
+# block of 128 lanes, where a 175-validator commit's apply-time
+# remainder (58 signatures) and every small batch land
+SIDES = pytest.mark.parametrize("w, blk", [(W, BLK), (128, 128)],
+                                ids=["8192x512", "128x128"])
+
 
 @pytest.fixture(scope="module")
 def one_chip():
@@ -72,7 +79,11 @@ def compile_for_chip(one_chip, no_compile_cache, monkeypatch):
                 for s, d in shapes]
         return jax.jit(fn).lower(*args).compile().as_text()
 
-    return compile_
+    yield compile_
+    # a kernel's trace is a few hundred thousand equations, and every
+    # one kept alive makes the collector's passes over the next
+    # kernel's tracing slower: no test here reads another's trace
+    jax.clear_caches()
 
 
 def _kernels(text: str) -> int:
@@ -80,40 +91,55 @@ def _kernels(text: str) -> int:
 
 
 U32, I32, BOOL = jnp.uint32, jnp.int32, jnp.bool_
-POINT = ((4, 20, W), I32)
-TABLE = ((17, 4, 20, W), I32)
 
 
-def test_decompress(compile_for_chip):
-    text = compile_for_chip(lambda e: pd.decompress(e, blk=BLK),
-                            ((8, W), U32))
+def _point(w):
+    return ((4, 20, w), I32)
+
+
+def _table(w):
+    return ((17, 4, 20, w), I32)
+
+
+@SIDES
+def test_decompress(compile_for_chip, w, blk):
+    assert dev._pallas_blk(w, cap=pd.BLK) == blk
+    text = compile_for_chip(lambda e: pd.decompress(e, blk=blk),
+                            ((8, w), U32))
     assert _kernels(text) == 1
 
 
-def test_table17_neg(compile_for_chip):
-    text = compile_for_chip(lambda p: pallas_msm.table17_neg(p, blk=BLK),
-                            POINT)
+@SIDES
+def test_table17_neg(compile_for_chip, w, blk):
+    assert dev._pallas_blk(w) == blk
+    text = compile_for_chip(lambda p: pallas_msm.table17_neg(p, blk=blk),
+                            _point(w))
     assert _kernels(text) == 1
 
 
+@SIDES
 @pytest.mark.parametrize("nwin", [52, 26])   # A side (256-bit), R side
-def test_msm_window_major(compile_for_chip, nwin):
+def test_msm_window_major(compile_for_chip, nwin, w, blk):
     text = compile_for_chip(
-        lambda t, m, n: pallas_msm.msm_window_major(t, m, n, blk=BLK),
-        TABLE, ((nwin, W), I32), ((nwin, W), BOOL))
+        lambda t, m, n: pallas_msm.msm_window_major(t, m, n, blk=blk),
+        _table(w), ((nwin, w), I32), ((nwin, w), BOOL))
     assert _kernels(text) == 1
 
 
-def test_fold_verify(compile_for_chip):
-    # the partials msm_window_major hands the fold at this width
-    part = jax.eval_shape(
-        lambda t, m, n: pallas_msm.msm_window_major(t, m, n, blk=BLK),
-        *(jax.ShapeDtypeStruct(s, d) for s, d in
-          (TABLE, ((52, W), I32), ((52, W), BOOL))))
-    text = compile_for_chip(pallas_msm.fold_verify,
-                            (part.shape, part.dtype),
-                            (part.shape, part.dtype))
-    assert _kernels(text) == 1
+_FOLDS: dict = {}         # partial shapes -> the fold's compiled text
+
+
+@SIDES
+def test_fold_verify(compile_for_chip, w, blk):
+    # the partials msm_window_major hands the fold: one global
+    # accumulator of _out_lanes(blk) lanes whatever the width, so one
+    # block a side gives the fold the 128 lanes the widest side does,
+    # and one compile serves every width that agrees
+    part = ((4, 20, pallas_msm._out_lanes(blk)), I32)
+    assert part[0][-1] == 128
+    if part not in _FOLDS:
+        _FOLDS[part] = compile_for_chip(pallas_msm.fold_verify, part, part)
+    assert _kernels(_FOLDS[part]) == 1
 
 
 def _rlc(k, n):
@@ -136,8 +162,10 @@ def _persig(n):
 
 @pytest.mark.slow
 @pytest.mark.parametrize("program, kernels", [
-    # a 48-block window of 175-validator commits: 176 keys pad to 192,
-    # which no block divides, so the A side is the XLA Straus scan
+    # an A side of 192 lanes, which no block divides: the XLA Straus
+    # scan.  On the chip pad_width returns no such width any more (176
+    # keys pad to 256); a caller that packs at a width of its own still
+    # gets a program, with the kernels of the R side alone
     (_rlc(192, 6144), 3),
     # one 10,000-validator commit, Pallas on both sides
     (_rlc(8192, 8192), 7),
@@ -145,8 +173,13 @@ def _persig(n):
     (_rlc_cached(8192, 327680), 5),
     # per-signature localisation at its largest bucket
     (_persig(16384), 1),
+    # the narrowest programs pad_width gives the chip, one block a
+    # side (a 175-validator commit's 58-signature apply-time remainder):
+    # the kernel counts of the wide programs, no drop to the XLA path
+    (_rlc(128, 128), 7),
+    (_rlc_cached(128, 128), 5),
 ], ids=["rlc-192x6144", "rlc-8192x8192", "rlc_cached-8192x327680",
-        "persig-16384"])
+        "persig-16384", "rlc-128x128", "rlc_cached-128x128"])
 def test_whole_program(compile_for_chip, program, kernels):
     fn, *shapes = program
     assert _kernels(compile_for_chip(fn, *shapes)) == kernels

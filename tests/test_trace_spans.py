@@ -158,8 +158,10 @@ def test_seam_spans_in_order_and_apart(seam):
         assert a["end"] <= b["start"]
 
 
-@pytest.mark.parametrize("field,want", [("k", 8), ("n", 8),
-                                        ("cached", False)])
+@pytest.mark.parametrize("field,want", [
+    ("k", 8), ("n", 8), ("cached", False),
+    # which kernels the program at (k, n) is: off the chip the XLA path
+    ("kernel", "xla")])
 def test_dispatch_span_carries_the_padded_widths(seam, field, want):
     (iv,) = seam["good"][0].intervals("verify", "dispatch")
     assert iv[field] == want
