@@ -654,11 +654,24 @@ class ATableCache:
         self._lock = lockrank.RankedLock("ed25519.atable")
         self.hits = 0
         self.misses = 0
+        self.first_sightings = 0
         self.evictions = 0
 
     @property
     def bytes_resident(self) -> int:
         return self._bytes
+
+    def clear(self) -> None:
+        """Forget every table and every sighting, as a process that has
+        just started: the next batch of any key is a first sighting
+        again.  The hit, miss and sighting counts run on."""
+        from ..libs import metrics as libmetrics
+
+        with self._lock:
+            self._entries.clear()
+            self._seen.clear()
+            self._bytes = 0
+            self._gauge_bytes(libmetrics.device_metrics())
 
     @staticmethod
     def _entry_bytes(entry) -> int:
@@ -756,6 +769,12 @@ class ATableCache:
                     self._seen[digest] = True
                     while len(self._seen) > 64:
                         self._seen.popitem(last=False)
+                    self.first_sightings += 1
+                    from ..libs import metrics as libmetrics
+
+                    dm = libmetrics.device_metrics()
+                    if dm is not None:
+                        dm.a_table_cache_first_sightings.inc()
                     return None            # first sighting: stay fused
         return self.get(a_words, device=device)
 

@@ -576,6 +576,11 @@ class DeviceMetrics:
         self.a_table_cache_misses = reg.counter(
             "device", "a_table_cache_misses",
             "Device A-side table cache misses (table built on device).")
+        self.a_table_cache_first_sightings = reg.counter(
+            "device", "a_table_cache_first_sightings",
+            "RLC batches whose A side the cache had not seen before and "
+            "left on the fused kernel (a second sighting builds the "
+            "table).")
         self.a_table_cache_bytes = reg.gauge(
             "device", "a_table_cache_bytes",
             "HBM bytes resident in the A-side table cache.")

@@ -50,7 +50,12 @@ BLOCKSYNC_STAGES = ("decode", "verify_dispatch", "device", "apply",
 # (crypto/dispatch.py): collect runs in the submitter, host_pack in
 # the staging thread — concurrent with the previous window's device
 PIPELINE_STAGES = ("collect", "host_pack")
-LIGHT_STAGES = ("fetch", "verify_dispatch", "device", "store")
+# validate (light/verifier.verify_adjacent: every check of a header
+# that is not a signature) runs inside collect; verdict_wait is the
+# client blocked on a window's verdict; divergence the witness
+# cross-check
+LIGHT_STAGES = ("fetch", "verify_dispatch", "validate", "device",
+                "verdict_wait", "divergence", "store")
 # subsystem "state": the inside of BlockExecutor's validate + apply
 # (state/execution.py), one span a block each but `save` and `events`,
 # which the crash-safety order opens twice

@@ -271,7 +271,8 @@ class Client:
         else:
             trace = self._verify_skipping(self.primary, anchor, new_block,
                                           now)
-        self._detect_divergence(trace, now)
+        with trace_span("light", "divergence"):
+            self._detect_divergence(trace, now)
         with trace_span("light", "store"):
             for lb in trace[1:]:
                 self.store.save_light_block(lb)
@@ -405,7 +406,8 @@ class Client:
                     wend = min(h + bs - 1, target.height)
                 else:
                     window, verdict = inflight.popleft()
-                    verdict.wait()
+                    with trace_span("light", "verdict_wait"):
+                        verdict.wait()
                     trace.extend(window)
         return trace
 

@@ -9,6 +9,7 @@ types/validation.py. Durations are nanoseconds (ints).
 from __future__ import annotations
 
 from ..crypto import sigcache
+from ..libs.trace import span as trace_span
 from ..types.timestamp import Timestamp
 from ..types.validation import (
     ErrNotEnoughVotingPowerSigned, Fraction, verify_commit_light,
@@ -91,17 +92,23 @@ def verify_adjacent(trusted: SignedHeader, untrusted: SignedHeader,
     collects the commit's signature checks for a later cross-header
     device batch; every header/valset structural check still runs
     immediately."""
-    if untrusted.height != trusted.height + 1:
-        raise ErrHeaderHeightNotAdjacent()
-    if header_expired(trusted, trusting_period_ns, now):
-        raise ErrOldHeaderExpired()
-    _verify_new_header_and_vals(untrusted, untrusted_vals, trusted, now,
-                                max_clock_drift_ns)
-    if untrusted.header.validators_hash != trusted.header.next_validators_hash:
-        raise ErrInvalidHeader(
-            f"expected old header next validators "
-            f"({trusted.header.next_validators_hash.hex()}) to match those "
-            f"from new header ({untrusted.header.validators_hash.hex()})")
+    # everything that is not a signature: validate_basic with the
+    # header's hash, the validator set's Merkle hash, the link to the
+    # trusted header's next_validators_hash
+    with trace_span("light", "validate"):
+        if untrusted.height != trusted.height + 1:
+            raise ErrHeaderHeightNotAdjacent()
+        if header_expired(trusted, trusting_period_ns, now):
+            raise ErrOldHeaderExpired()
+        _verify_new_header_and_vals(untrusted, untrusted_vals, trusted,
+                                    now, max_clock_drift_ns)
+        if untrusted.header.validators_hash != \
+                trusted.header.next_validators_hash:
+            raise ErrInvalidHeader(
+                f"expected old header next validators "
+                f"({trusted.header.next_validators_hash.hex()}) to match "
+                f"those from new header "
+                f"({untrusted.header.validators_hash.hex()})")
     try:
         # commits the full node already verified (consensus/blocksync)
         # are verdict-cache hits here — attributed to the "light"
