@@ -134,8 +134,7 @@ class DeviceFaultController:
             return win.verifier.verify()
         from ..crypto.batch import safe_verify
 
-        out = [p is not None and safe_verify(pk, m, s)
-               for p, (pk, m, s) in zip(win.parsed, win.items)]
+        out = [safe_verify(pk, m, s) for pk, m, s in win.items]
         return all(out) and bool(out), out
 
 

@@ -93,12 +93,9 @@ class TpuEd25519BatchVerifier(_SigCollector):
     def _verify_items(self) -> tuple[bool, list[bool]]:
         if not self._items:
             return False, []
-        pks = [i[0] for i in self._items]
-        # parse + hash ONCE; both device packings build from this
-        with trace_span("verify", "host_pack", batch=len(pks)):
-            parsed = ed.parse_and_hash(pks, [i[1] for i in self._items],
-                                       [i[2] for i in self._items])
-        return _device_verify(pks, parsed)
+        return _device_verify([i[0] for i in self._items],
+                              msgs=[i[1] for i in self._items],
+                              sigs=[i[2] for i in self._items])
 
 
 # sentinel: "no precomputed RLC packing" (None is a real pack_rlc
@@ -117,14 +114,21 @@ def _count_verified(program: str, n: int) -> None:
         dm.signatures_verified.labels(program).add(n)
 
 
-def _device_verify(pubkeys: list[bytes], parsed, packed=_NO_PACK,
-                   device=None) -> tuple[bool, list[bool]]:
+def _device_verify(pubkeys: list[bytes], parsed=None, packed=_NO_PACK,
+                   device=None, *, msgs=None,
+                   sigs=None) -> tuple[bool, list[bool]]:
     """Shared device dispatch for any Edwards-domain batch: RLC fast
     path first, per-signature kernel for verdict localization on
     failure — the reference's verifyCommitBatch -> verifyCommitSingle
     pattern (/root/reference/types/validation.go:115).  `packed`
     accepts a pack_rlc result computed ahead of time (the overlapped
     pipeline packs window N+1 while window N is on device).
+
+    An ed25519 batch comes as `msgs` and `sigs`: pack_rlc hashes for
+    itself, and parse_and_hash's rows, which only the per-signature
+    kernel needs, are computed at the reject - as rare as a forged
+    signature.  A caller whose h is no SHA-512 (the sr25519 bridge)
+    brings `parsed` instead.
 
     `device` commits the dispatch to one specific mesh device (the
     pipeline's round-robin placement, crypto/dispatch.py); with
@@ -143,12 +147,14 @@ def _device_verify(pubkeys: list[bytes], parsed, packed=_NO_PACK,
         if packed is _NO_PACK and device is None:
             from . import mesh
 
-            rlc_ok = mesh.maybe_split_verify(pubkeys, parsed)
+            rlc_ok = mesh.maybe_split_verify(pubkeys, parsed,
+                                             msgs=msgs, sigs=sigs)
         if rlc_ok is None:
             if packed is _NO_PACK:
-                with trace_span("verify", "host_pack", batch=n):
-                    packed = ed.pack_rlc(pubkeys, [b""] * n, [b""] * n,
-                                         parsed=parsed)
+                with trace_span("verify", "host_pack", batch=n) as sp:
+                    packed, packer = ed.pack_rlc_named(
+                        pubkeys, msgs, sigs, parsed=parsed)
+                    sp.note(packer=packer)
             rlc_ok = packed is not None and \
                 ed.rlc_verify(packed, device=device)
         if rlc_ok:
@@ -160,6 +166,12 @@ def _device_verify(pubkeys: list[bytes], parsed, packed=_NO_PACK,
         if dm is not None:
             dm.rlc_fallbacks.inc()
         flightrec.record(flightrec.EV_RLC_FALLBACK, batch=n)
+    if parsed is None:
+        # an RLC reject or a batch of one: the per-signature kernel
+        # wants every h, hashed in Python under the same span name, so
+        # the packing metrics see a seam made slow by a forged signature
+        with trace_span("verify", "host_pack", batch=n, packer="python"):
+            parsed = ed.parse_and_hash(pubkeys, msgs, sigs)
     if device is not None:
         import jax
 

@@ -11,11 +11,10 @@ verification stages CONCURRENT, not from faster primitives — this
 module is that reformulation for the TPU seam:
 
 - submit(items) returns immediately with a WindowHandle future;
-- a STAGING thread runs the host work (SHA-512 sign-bytes hashing via
-  parse_and_hash, signed-digit recode via pack_rlc) for window N+1
-  while window N's RLC dispatch is in flight — hashlib and numpy
-  release the GIL, so a small worker pool genuinely parallelizes the
-  per-window parse+hash across cores (parse_and_hash_parallel);
+- a STAGING thread runs the host work (SHA-512 sign-bytes hashing,
+  the per-key sums and the signed-digit recode: ed25519.pack_rlc, one
+  native call a window that runs outside the interpreter lock) for
+  window N+1 while window N's RLC dispatch is in flight;
 - a DEVICE thread dispatches packed windows in QoS order (crypto/
   sched.py): priority lanes keyed by consumer label, deadline
   promotion, and deficit round-robin between equal-priority lanes.
@@ -49,7 +48,7 @@ import os
 import threading
 import time
 from ..libs import lockrank
-from concurrent.futures import Future, ThreadPoolExecutor
+from concurrent.futures import Future
 
 from ..libs.service import BaseService
 from . import sched as qos_sched
@@ -57,21 +56,6 @@ from . import sched as qos_sched
 # depth 2 = classic double buffering (pack N+1 while N is on device);
 # deeper helps only when device time >> host time per window
 DEFAULT_DEPTH = int(os.environ.get("COMETBFT_TPU_PIPELINE_DEPTH", "2"))
-# the host pool parallelizes WITHIN a window (parse_and_hash chunks);
-# hashlib releases the GIL so this scales to real cores.  Sized from
-# the machine (one core stays free for the device thread) instead of
-# the old static min(4, cpu_count) cap, which left a 16-core host
-# hashing on 4 threads; COMETBFT_TPU_PIPELINE_WORKERS pins it exactly.
-DEFAULT_HOST_WORKERS = int(
-    os.environ.get("COMETBFT_TPU_PIPELINE_WORKERS", "0")) or \
-    max(1, (os.cpu_count() or 2) - 1)
-_MIN_PARALLEL_CHUNK = 256
-# below this many signatures the hash runs INLINE on the staging
-# thread: the pool handoff (submit + futures + result gather) costs
-# more than hashlib saves on a tiny votestream flush
-PARSE_INLINE_THRESHOLD = int(os.environ.get(
-    "COMETBFT_TPU_PARSE_INLINE_THRESHOLD",
-    str(2 * _MIN_PARALLEL_CHUNK)))
 # hung-dispatch watchdog: a device call in flight past this deadline
 # marks the device hung — the window (and everything staged behind it)
 # resolves on the host, the wedged thread is abandoned + replaced, and
@@ -94,31 +78,6 @@ BROWNOUT_MAX_WINDOW = int(os.environ.get(
 # every pipeline in the process to the plain global-FIFO queue (the
 # bench A/B arms toggle the constructor flag instead).
 DEFAULT_QOS = os.environ.get("COMETBFT_TPU_SCHED", "1") != "0"
-
-
-def parse_and_hash_parallel(pubkeys, msgs, sigs, pool=None,
-                            workers: int | None = None):
-    """ed25519.parse_and_hash fanned across a thread pool in chunks.
-
-    Byte-identical to the serial function (pinned by
-    tests/test_dispatch.py): chunking only partitions the index space.
-    Small batches (under PARSE_INLINE_THRESHOLD, or pool=None) stay
-    serial — the fan-out overhead beats the hashing there.
-    """
-    from . import ed25519 as ed
-
-    n = len(pubkeys)
-    nworkers = workers if workers is not None else DEFAULT_HOST_WORKERS
-    if pool is None or nworkers <= 1 or n < PARSE_INLINE_THRESHOLD:
-        return ed.parse_and_hash(pubkeys, msgs, sigs)
-    chunk = max(_MIN_PARALLEL_CHUNK, -(-n // nworkers))
-    spans = [(i, min(i + chunk, n)) for i in range(0, n, chunk)]
-    futs = [pool.submit(ed.parse_and_hash, pubkeys[a:b], msgs[a:b],
-                        sigs[a:b]) for a, b in spans]
-    out = []
-    for f in futs:
-        out.extend(f.result())
-    return out
 
 
 def _pk_bytes(pk) -> bytes:
@@ -219,7 +178,8 @@ class WindowHandle:
 
 class _Window:
     __slots__ = ("items", "handle", "threshold", "mode", "pks",
-                 "msgs", "parsed", "packed", "verifier", "staged",
+                 "msgs", "sigs", "parsed", "packed", "packer",
+                 "verifier", "staged",
                  "device_s", "device_index", "dispatching", "result",
                  "all_items", "cached", "dispatch_started",
                  "abandoned", "lane", "prio", "seq", "enqueued_at",
@@ -237,9 +197,15 @@ class _Window:
         self.cached = None
         self.mode = None          # "ed" | "ed_hash" | "mixed" | "host"
         self.pks = None
-        self.msgs = None          # kept for ed_hash reject localization
+        # msgs and sigs are kept for the reject: parse_and_hash (mode
+        # "ed") and the device-hash localization (mode "ed_hash") run
+        # only then.  parsed is parse_batch's rows in mode "ed_hash"
+        # and None in mode "ed", whose packer hashes for itself.
+        self.msgs = None
+        self.sigs = None
         self.parsed = None
         self.packed = None
+        self.packer = None        # "native" | "python": who packed
         self.verifier = None
         self.staged = False
         self.device_s = 0.0
@@ -269,7 +235,6 @@ class VerifyPipeline(BaseService):
     """Depth-K overlapped verify dispatch engine (module docstring)."""
 
     def __init__(self, depth: int = DEFAULT_DEPTH,
-                 host_workers: int | None = None,
                  dispatch_fn=None, name: str = "VerifyPipeline",
                  devices=None, health=None,
                  dispatch_deadline_s: float | None = None,
@@ -280,8 +245,6 @@ class VerifyPipeline(BaseService):
         self.qos = DEFAULT_QOS if qos is None else bool(qos)
         self._sched = qos_sched.QosScheduler(enabled=self.qos)
         self.depth = max(1, depth)
-        self.host_workers = (host_workers if host_workers is not None
-                             else DEFAULT_HOST_WORKERS)
         # test/profiling seam: replaces the device-verify call; takes
         # the _Window, returns (ok, verdicts) or raises (exercising the
         # drain path exactly as a real device failure would)
@@ -315,7 +278,6 @@ class VerifyPipeline(BaseService):
         self._cv = lockrank.RankedCondition(name="dispatch.cv")
         self._windows: list[_Window] = []
         self._slots = threading.BoundedSemaphore(self.depth)
-        self._pool: ThreadPoolExecutor | None = None
         self._staging: threading.Thread | None = None
         self._device: threading.Thread | None = None
         self._dev_threads: list[threading.Thread] = []
@@ -357,9 +319,6 @@ class VerifyPipeline(BaseService):
         self._probe_inflight = {}
         self._wd_wake = threading.Event()
         self._brownout = self.in_brownout()
-        self._pool = ThreadPoolExecutor(
-            max_workers=max(1, self.host_workers),
-            thread_name_prefix=f"{self._name}-host")
         self._staging = threading.Thread(
             target=self._staging_loop, name=f"{self._name}-staging",
             daemon=True)
@@ -392,8 +351,6 @@ class VerifyPipeline(BaseService):
                    *self._dev_threads):
             if th is not None:
                 th.join(timeout=5)
-        if self._pool is not None:
-            self._pool.shutdown(wait=False)
         # a submit that raced stop() may have left windows behind the
         # exited threads: answer them on the host, free their slots
         with self._cv:
@@ -730,11 +687,13 @@ class VerifyPipeline(BaseService):
             _lat_stamp(win.handle, "stage_start")
             try:
                 with libtrace.span(win.handle.subsystem, stage_span,
-                                   inflight=len(self._windows)), \
+                                   inflight=len(self._windows)) as sp, \
                         tracetl.span_for(
                             self, win.handle.subsystem, stage_span,
                             **tracetl.ctx_fields(win.handle.ctx)):
                     self._stage(win)
+                    if win.packer is not None:
+                        sp.note(packer=win.packer)
             except Exception:
                 # a staging failure must not wedge the queue: route the
                 # window to the host path for verdicts
@@ -747,8 +706,8 @@ class VerifyPipeline(BaseService):
             self._gauge()
 
     def _stage(self, win: _Window) -> None:
-        """Host work for one window: key-type split, parallel SHA-512
-        parse+hash, RLC packing (signed-digit recode) — everything the
+        """Host work for one window: key-type split and RLC packing
+        (SHA-512, per-key sums, signed-digit recode) — everything the
         device dispatch needs, done while the PREVIOUS window is on
         device."""
         items = win.items
@@ -780,6 +739,8 @@ class VerifyPipeline(BaseService):
         msgs = [m for _, m, _ in items]
         sigs = [s for _, _, s in items]
         win.pks = pks
+        win.msgs = msgs
+        win.sigs = sigs
         n = len(pks)
         if ed.device_hash_enabled() and n >= 2:
             # fused hash-to-scalar staging: structural parse + splice
@@ -793,20 +754,15 @@ class VerifyPipeline(BaseService):
                     win.packed = ed.pack_rlc_device_hash(
                         pks, msgs, sigs, parsed=parsed)
                     win.parsed = parsed
-                    win.msgs = msgs
                     win.mode = "ed_hash"
                     return
                 except ValueError:
                     self._record_hash_fallback(n)
-        win.parsed = parse_and_hash_parallel(
-            pks, msgs, sigs, pool=self._pool,
-            workers=self.host_workers)
         if n >= 2:
-            # pack (aggregation + recode) here so the device thread
-            # only dispatches; None = structural reject, the device
-            # stage localizes with the per-signature kernel
-            win.packed = ed.pack_rlc(pks, [b""] * n, [b""] * n,
-                                     parsed=win.parsed)
+            # pack (hash + aggregation + recode) here so the device
+            # thread only dispatches; None = structural reject, the
+            # device stage localizes with the per-signature kernel
+            win.packed, win.packer = ed.pack_rlc_named(pks, msgs, sigs)
         win.mode = "ed"
 
     def _record_hash_fallback(self, n: int) -> None:
@@ -1192,7 +1148,7 @@ class VerifyPipeline(BaseService):
                                           win.parsed,
                                           packed=win.packed,
                                           device=device)
-        return cb._device_verify(win.pks, win.parsed,
+        return cb._device_verify(win.pks, msgs=win.msgs, sigs=win.sigs,
                                  packed=win.packed, device=device)
 
     def _host_fallback(self, win: _Window):
@@ -1467,9 +1423,9 @@ class VerifyPipeline(BaseService):
         msgs = [m for _, m, _ in items]
         sigs = [s for _, _, s in items]
         win.pks = pks
-        win.parsed = ed.parse_and_hash(pks, msgs, sigs)
-        win.packed = ed.pack_rlc(pks, [b""] * len(pks),
-                                 [b""] * len(pks), parsed=win.parsed)
+        win.msgs = msgs
+        win.sigs = sigs
+        win.packed = ed.pack_rlc(pks, msgs, sigs)
         win.mode = "ed"
         win.staged = True
         win.device_index = int(dev) if self.devices is not None else 0

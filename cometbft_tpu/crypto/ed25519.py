@@ -304,8 +304,7 @@ def pack_rlc(pubkeys: list[bytes], msgs: list[bytes], sigs: list[bytes],
              parsed=None):
     """Pack a batch for the device RLC kernel (ops/ed25519.rlc_verify_kernel).
 
-    Host work per signature: h = SHA512(R||A||M) mod L (via
-    parse_and_hash, shared with the per-signature packing), a random
+    Host work per signature: h = SHA512(R||A||M) mod L, a random
     128-bit z, zh = z*h mod L.  Two preprocessing steps shrink the
     device program (v4 kernel, split A/R MSMs):
 
@@ -318,25 +317,61 @@ def pack_rlc(pubkeys: list[bytes], msgs: list[bytes], sigs: list[bytes],
 
     Both batches pad to bucketed widths (ops/ed25519.pad_width); pad
     slots hold the base point with zero scalar and contribute the
-    identity.  Scalars are recoded host-side into signed 5-bit window
-    digits (_recode_w5).
+    identity.  Scalars are recoded into signed 5-bit window digits.
+
+    One native call does all of it for the batch (crypto/rlcpack.py),
+    outside the interpreter lock; _pack_rlc_python is the same
+    algorithm in Python, the oracle the library is pinned against and
+    what serves a host without a toolchain or a caller that brings
+    `parsed` (parse_and_hash's rows: the sr25519 bridge, whose h is no
+    SHA-512).  Which one packed is in
+    cometbft_device_host_pack_signatures_total{packer}.
 
     Returns (a_words (8,K), r_words (8,N), a_mag (52,K), a_neg (52,K),
     r_mag (26,N), r_neg (26,N)) limbs-first/MSB-first, or None if any
     entry fails structural checks (caller falls back to the
     per-signature kernel for verdicts).
     """
+    return pack_rlc_named(pubkeys, msgs, sigs, parsed=parsed)[0]
+
+
+def pack_rlc_named(pubkeys: list[bytes], msgs: list[bytes],
+                   sigs: list[bytes], parsed=None):
+    """pack_rlc's result and the packer that made it ("native" or
+    "python"), for the host_pack spans' `packer` field."""
     import secrets
 
+    from ..libs import metrics as libmetrics
+    from . import rlcpack
+
+    n = len(pubkeys)
+    if n == 0:
+        return None, "python"
+    # the batch's whole draw, up front: 128 bits a signature from the
+    # same source as ever, handed to whichever packer serves
+    zblock = secrets.token_bytes(16 * n)
+    packer = "native"
+    packed = rlcpack.pack(pubkeys, msgs, sigs, zblock) \
+        if parsed is None else rlcpack.UNAVAILABLE
+    if packed is rlcpack.UNAVAILABLE:
+        packer = "python"
+        if parsed is None:
+            parsed = parse_and_hash(pubkeys, msgs, sigs)
+        packed = _pack_rlc_python(pubkeys, parsed, zblock)
+    dm = libmetrics.device_metrics()
+    if dm is not None and packed is not None:
+        dm.host_pack_signatures.labels(packer).add(n)
+    return packed, packer
+
+
+def _pack_rlc_python(pubkeys: list[bytes], parsed, zblock: bytes):
+    """pack_rlc in Python from parse_and_hash's rows: z_i is the i-th
+    16 bytes of `zblock`, little-endian, top bit set."""
     global _NEG_B_ENC
     if _NEG_B_ENC is None:
         _NEG_B_ENC = _neg_b_encoding()
 
     n = len(pubkeys)
-    if n == 0:
-        return None
-    if parsed is None:
-        parsed = parse_and_hash(pubkeys, msgs, sigs)
     agg: dict[bytes, int] = {}
     c = 0
     r_encs = []
@@ -345,7 +380,8 @@ def pack_rlc(pubkeys: list[bytes], msgs: list[bytes], sigs: list[bytes],
         if parsed[i] is None:
             return None
         r_enc, s, h = parsed[i]
-        z = secrets.randbits(128) | (1 << 127)
+        z = int.from_bytes(zblock[16 * i:16 * i + 16],
+                           "little") | (1 << 127)
         pk = pubkeys[i]
         agg[pk] = (agg.get(pk, 0) + z * h) % L
         c = (c + z * s) % L

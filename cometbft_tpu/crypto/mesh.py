@@ -82,22 +82,27 @@ def _count_dispatch(i: int, n: int = 0) -> None:
 
 
 def split_rlc_verify(pubkeys: list[bytes], parsed, devices,
-                     use_cache: bool | None = None):
+                     use_cache: bool | None = None, *, msgs=None,
+                     sigs=None):
     """One multi-commit window split ACROSS the mesh: chunk i packs on
     the host, commits to devices[i], and dispatches its own RLC
     program; every chip's program is in flight before any verdict is
-    read back.  Returns the per-chunk bool list (len == number of
-    spans), or None when any chunk fails structural packing — the
-    caller localizes per signature either way."""
+    read back.  The batch comes as `msgs` and `sigs` (parsed=None:
+    pack_rlc hashes for itself) or as parse_and_hash's rows.  Returns
+    the per-chunk bool list (len == number of spans), or None when any
+    chunk fails structural packing — the caller localizes per
+    signature either way."""
     from . import ed25519 as ed
 
     n = len(pubkeys)
     spans = split_spans(n, len(devices))
     packs = []
     for a, b in spans:
-        m = b - a
-        packed = ed.pack_rlc(pubkeys[a:b], [b""] * m, [b""] * m,
-                             parsed=parsed[a:b])
+        if parsed is not None:
+            packed = ed.pack_rlc(pubkeys[a:b], None, None,
+                                 parsed=parsed[a:b])
+        else:
+            packed = ed.pack_rlc(pubkeys[a:b], msgs[a:b], sigs[a:b])
         if packed is None:
             return None
         packs.append(packed)
@@ -110,7 +115,8 @@ def split_rlc_verify(pubkeys: list[bytes], parsed, devices,
 
 
 def maybe_split_verify(pubkeys: list[bytes], parsed,
-                       min_split: int | None = None):
+                       min_split: int | None = None, *, msgs=None,
+                       sigs=None):
     """The crypto/batch._device_verify hook: None when the mesh split
     does not apply (mesh off, too few devices, window under
     MIN_SPLIT); otherwise the whole-window RLC verdict (True = every
@@ -126,7 +132,8 @@ def maybe_split_verify(pubkeys: list[bytes], parsed,
     devices = _healthy_devices(devices)
     if len(devices) < 2:
         return None
-    verdicts = split_rlc_verify(pubkeys, parsed, devices)
+    verdicts = split_rlc_verify(pubkeys, parsed, devices, msgs=msgs,
+                                sigs=sigs)
     if verdicts is None:
         return False
     return all(verdicts)

@@ -162,6 +162,7 @@ LOCK_RANKS: dict[str, int] = {
     # pure leaves
     "service.lifecycle": 550,
     "native_codec.lib": 560,
+    "rlcpack.lib": 565,
     "bls12381.lib": 570,
     "msm.coeff": 580,
     "compile_hook": 590,

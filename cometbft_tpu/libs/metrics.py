@@ -565,6 +565,15 @@ class DeviceMetrics:
             "persig = the per-signature kernel).  Counted where "
             "pipeline windows and synchronous seam batches both pass.",
             labels=("program",))
+        self.host_pack_signatures = reg.counter(
+            "device", "host_pack_signatures_total",
+            "Signatures packed for the RLC equation, by packer (native "
+            "= the library of native/rlcpack, one call a batch outside "
+            "the interpreter lock; python = the interpreter's loop: a "
+            "host without the library, or a caller that brought its "
+            "own hashes).  Counted where crypto/ed25519.pack_rlc "
+            "returns.",
+            labels=("packer",))
         self.device_hash_fallbacks = reg.counter(
             "device", "device_hash_fallbacks",
             "Pipeline windows that left the fused device-hash path "

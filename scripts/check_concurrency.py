@@ -91,8 +91,6 @@ JOINED_THREADS: set[str] = {
 KNOBS = {
     # crypto/dispatch.py — verify pipeline shape
     "COMETBFT_TPU_PIPELINE_DEPTH",
-    "COMETBFT_TPU_PIPELINE_WORKERS",
-    "COMETBFT_TPU_PARSE_INLINE_THRESHOLD",
     "COMETBFT_TPU_DISPATCH_DEADLINE_S",
     "COMETBFT_TPU_BROWNOUT_DEPTH",
     "COMETBFT_TPU_BROWNOUT_MAX_WINDOW",

@@ -41,54 +41,25 @@ def serial_verdicts(items):
             for pk, m, s in items]
 
 
-class TestParseAndHashParallel:
-    def test_byte_parity_with_serial(self):
-        from concurrent.futures import ThreadPoolExecutor
-
-        from cometbft_tpu.crypto import ed25519 as ed
-
-        items = make_items(700, seed=3, bad=(5, 611))
-        # a structurally-bad sig (s >= L) and a short pubkey exercise
-        # the None lanes across chunk boundaries
-        items[17] = (items[17][0], items[17][1], b"\xff" * 64)
-        items[300] = (b"\x01" * 5, items[300][1], items[300][2])
-        pks = [i[0] for i in items]
-        msgs = [i[1] for i in items]
-        sigs = [i[2] for i in items]
-        with ThreadPoolExecutor(max_workers=4) as pool:
-            par = vd.parse_and_hash_parallel(pks, msgs, sigs,
-                                             pool=pool, workers=4)
-        assert par == ed.parse_and_hash(pks, msgs, sigs)
-
-    def test_small_batch_stays_serial(self):
-        from cometbft_tpu.crypto import ed25519 as ed
-
-        items = make_items(8, seed=1)
-        pks = [i[0] for i in items]
-        msgs = [i[1] for i in items]
-        sigs = [i[2] for i in items]
-        assert vd.parse_and_hash_parallel(pks, msgs, sigs, pool=None) \
-            == ed.parse_and_hash(pks, msgs, sigs)
-
-
 class TestPipelineVerdicts:
     def test_verdict_parity_good_and_bad(self):
         """Host lane and (stubbed-dispatch) device lane must both
         match the serial oracle on the identical fixture.  The stub
         seam replaces ONLY the final device call — staging still runs
-        the real parallel parse+hash and RLC pack, and the stub judges
-        from the STAGED parse results, so a staging bug shows up as a
-        parity break here.  (The real XLA dispatch costs minutes of
-        cold compile on the CPU tier; the slow tier pins it.)"""
+        the real RLC pack, and the stub judges what staging kept for
+        the device stage (keys, messages, signatures), so a staging
+        bug shows up as a parity break here.  (The real XLA dispatch
+        costs minutes of cold compile on the CPU tier; the slow tier
+        pins it.)"""
         items = make_items(24, seed=7, bad=(3, 20))
         want = serial_verdicts(items)
         assert want.count(False) == 2
 
         def judge_from_staging(win):
-            # verdict from the staged parse: structural rejects are
-            # None; judge the rest with the host oracle
-            out = [p is not None and cb.safe_verify(PubKey(pk), m, s)
-                   for p, (pk, m, s) in zip(win.parsed, win.items)]
+            # the batch is structurally sound, so staging packed it
+            assert win.mode == "ed" and win.packed is not None
+            out = [cb.safe_verify(PubKey(pk), m, s)
+                   for pk, m, s in zip(win.pks, win.msgs, win.sigs)]
             return all(out), out
 
         # the oracle and each pipeline arm share triples; flush the
@@ -111,7 +82,7 @@ class TestPipelineVerdicts:
 
     @pytest.mark.slow
     def test_verdict_parity_real_device_dispatch(self):
-        """The real dispatch chain (parallel parse+hash -> pack_rlc ->
+        """The real dispatch chain (pack_rlc ->
         rlc_verify -> per-signature kernel fallback) against the
         serial oracle; cold-compiles the XLA kernels, so slow tier."""
         items = make_items(24, seed=7, bad=(3, 20))
@@ -305,6 +276,36 @@ class TestPipelineMetricsAndSpans:
         assert snap["blocksync.host_pack"]["count"] >= 1
         assert snap["blocksync.device"]["count"] >= 1
 
+    @pytest.mark.parametrize("library", [True, False])
+    def test_window_host_pack_span_names_its_packer(self, library,
+                                                    monkeypatch):
+        """A window the staging thread packs says who packed it; a
+        window below the threshold is packed by no one and says
+        nothing."""
+        from cometbft_tpu.crypto import rlcpack
+        from cometbft_tpu.libs import trace as libtrace
+
+        if not library:
+            monkeypatch.setattr(rlcpack, "_lib", None)
+            monkeypatch.setattr(rlcpack, "_failed", True)
+        want = "native" if rlcpack.enabled() else "python"
+        sigcache.reset()
+        tr = libtrace.StageTracer()
+        prev = libtrace.tracer()
+        libtrace.set_tracer(tr)
+        try:
+            with vd.VerifyPipeline(depth=2,
+                                   dispatch_fn=judge_staged) as pipe:
+                pipe.submit(make_items(6, seed=15), subsystem="light",
+                            device_threshold=1).result(timeout=30)
+                pipe.submit(make_items(3, seed=16), subsystem="light",
+                            device_threshold=1 << 30).result(timeout=30)
+        finally:
+            libtrace.set_tracer(prev)
+        packed, unpacked = tr.intervals("light", "host_pack")
+        assert packed["packer"] == want
+        assert "packer" not in unpacked
+
 
 class TestTraceIntervals:
     def test_overlap_seconds_detects_concurrency(self):
@@ -423,14 +424,12 @@ class TestDeferredVerifyAsync:
 
 
 def judge_staged(win):
-    """Honest stub dispatch: judge from the staged parse results with
-    the host oracle.  Handles both raw-bytes pubkeys (real windows)
-    and PubKey objects (devhealth probe windows)."""
+    """Honest stub dispatch: judge the window's items with the host
+    oracle (which rejects a structurally bad entry itself).  Handles
+    both raw-bytes pubkeys (real windows) and PubKey objects
+    (devhealth probe windows)."""
     out = []
-    for p, (pk, m, s) in zip(win.parsed, win.items):
-        if p is None:
-            out.append(False)
-            continue
+    for pk, m, s in win.items:
         pub = PubKey(pk) if isinstance(pk, (bytes, bytearray)) else pk
         out.append(cb.safe_verify(pub, m, s))
     return all(out) and bool(out), out

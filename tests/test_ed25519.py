@@ -331,3 +331,90 @@ def test_a_table_cache_byte_bound():
     out = devk.rlc_verify_device_cached_a(
         tab, ok, packed[1], packed[2], packed[3], packed[4], packed[5])
     assert bool(np.asarray(out))
+
+
+# -- the packer on the two lanes every RLC batch takes -------------------------
+
+@pytest.fixture(params=["native", "python"])
+def packer(request, monkeypatch):
+    """The library where it builds, or the loader forced to fail."""
+    from cometbft_tpu.crypto import rlcpack
+
+    if request.param == "python":
+        monkeypatch.setattr(rlcpack, "_lib", None)
+        monkeypatch.setattr(rlcpack, "_failed", True)
+    elif not rlcpack.build():
+        pytest.skip("no g++ to build librlcpack.so")
+    return request.param
+
+
+def _through_the_seam(items):
+    bv = cb.create_batch_verifier("ed25519", provider="tpu")
+    for pk, m, s in items:
+        bv.add(pk, m, s)
+    return bv.verify()
+
+
+def _through_a_window(items):
+    from cometbft_tpu.crypto import dispatch as vd
+
+    with vd.VerifyPipeline(depth=2) as pipe:
+        handle = pipe.submit(list(items), subsystem="light",
+                             device_threshold=1)
+        out = handle.result(timeout=1200)
+    assert handle.path == "device"
+    return out
+
+
+@pytest.mark.parametrize("altered", [None, 3])
+@pytest.mark.parametrize("lane", [_through_the_seam, _through_a_window])
+def test_both_lanes_pack_once_and_parse_only_at_a_reject(
+        packer, lane, altered, monkeypatch):
+    """The seam and a pipeline window on the CPU backend, the real
+    kernels at the 8 x 8 shape: a sound batch is accepted in one
+    equation with nothing parsed ahead of it; with one signature
+    altered the equation rejects, parse_and_hash runs then, and the
+    per-signature kernel names the altered one."""
+    from cometbft_tpu.crypto import sigcache
+    from cometbft_tpu.libs import metrics as libmetrics
+
+    privs = [ed.PrivKey.generate(bytes([0x61, i + 1]) * 16)
+             for i in range(5)]
+    items = [(p.pub_key(), b"lane vote %d" % i, p.sign(b"lane vote %d" % i))
+             for i, p in enumerate(privs)]
+    if altered is not None:
+        pk, m, s = items[altered]
+        items[altered] = (pk, m, s[:9] + bytes([s[9] ^ 0x04]) + s[10:])
+    parses = []
+    real = ed.parse_and_hash
+    monkeypatch.setattr(
+        ed, "parse_and_hash",
+        lambda *a: parses.append(len(a[0])) or real(*a))
+    dm = libmetrics.DeviceMetrics(libmetrics.Registry())
+    prev = libmetrics.device_metrics()
+    libmetrics.set_device_metrics(dm)
+    sigcache.set_enabled(False)     # a hit would resolve before the lane
+    try:
+        ok, verdicts = lane(items)
+    finally:
+        sigcache.set_enabled(None)
+        libmetrics.set_device_metrics(prev)
+
+    def read(metric):
+        with metric._mtx:
+            return {k[0] if k else "": v for k, v in metric._values.items()}
+
+    assert read(dm.host_pack_signatures) == {packer: 5.0}
+    if altered is None:
+        assert ok and verdicts == [True] * 5
+        assert read(dm.signatures_verified) == {"rlc": 5.0}
+        assert read(dm.rlc_fallbacks) == {}
+    else:
+        assert not ok
+        assert verdicts == [i != altered for i in range(5)]
+        assert read(dm.signatures_verified) == {"persig": 5.0}
+        assert read(dm.rlc_fallbacks) == {"": 1.0}
+    # the library hashes for itself: nothing is parsed but at the
+    # reject (the Python packer parses once more, to pack)
+    rejects = [5] if altered is not None else []
+    assert parses == ([5] if packer == "python" else []) + rejects

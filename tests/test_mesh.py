@@ -218,15 +218,15 @@ class TestPipelineRoundRobin:
         assert pipe2.devices is not None and len(pipe2.devices) == 8
 
     def test_verdict_parity_mesh_mode(self):
-        """Same fixture through the mesh pipeline (stub judging from
-        the STAGED parse, as in test_dispatch) equals the serial
-        oracle — staging bugs in mesh mode break parity here."""
+        """Same fixture through the mesh pipeline (stub judging what
+        staging kept, as in test_dispatch) equals the serial oracle —
+        staging bugs in mesh mode break parity here."""
         items = make_items(24, seed=7, bad=(3, 20))
         want = serial_verdicts(items)
 
         def judge_from_staging(win):
-            out = [p is not None and cb.safe_verify(PubKey(pk), m, s)
-                   for p, (pk, m, s) in zip(win.parsed, win.items)]
+            out = [cb.safe_verify(PubKey(pk), m, s)
+                   for pk, m, s in zip(win.pks, win.msgs, win.sigs)]
             return all(out), out
 
         with vd.VerifyPipeline(depth=4, dispatch_fn=judge_from_staging,

@@ -313,11 +313,9 @@ class TestPipelinedBlocksync:
             calls["n"] += 1
             if calls["n"] <= 2:
                 raise RuntimeError("injected mid-pipeline device fault")
-            # judge from the STAGED parse results: the real staging
-            # (parallel parse+hash + RLC pack) already ran
+            # the real staging (the RLC pack) already ran
             from cometbft_tpu.crypto.batch import safe_verify
-            out = [p is not None and safe_verify(pk, m, s)
-                   for p, (pk, m, s) in zip(win.parsed, win.items)]
+            out = [safe_verify(pk, m, s) for pk, m, s in win.items]
             return all(out), out
 
         net = SimNetwork(seed=41)

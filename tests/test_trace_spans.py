@@ -150,12 +150,27 @@ def test_seam_spans_in_order_and_apart(seam):
     tracer, _, (ok, verdicts) = seam["good"]
     assert ok and verdicts == [True] * 5
     got = tracer.intervals("verify")
-    # parse_and_hash, pack_rlc, the dispatch, the wait for its verdict
+    # pack_rlc (which hashes for itself), the dispatch, the wait for
+    # its verdict
     assert [iv["stage"] for iv in got] == [
-        "host_pack", "host_pack", "dispatch", "readback"]
+        "host_pack", "dispatch", "readback"]
     assert set(libtrace.VERIFY_STAGES) == {iv["stage"] for iv in got}
     for a, b in zip(got, got[1:]):
         assert a["end"] <= b["start"]
+
+
+@pytest.mark.parametrize("which", ["good", "bad"])
+def test_host_pack_span_names_its_packer(seam, which):
+    from cometbft_tpu.crypto import rlcpack
+
+    # one span for the pack; the reject's parse_and_hash opens a second
+    # of the same name, so the packing metrics see its Python hashing
+    packed, *at_reject = seam[which][0].intervals("verify", "host_pack")
+    assert packed["batch"] == 5
+    assert packed["packer"] == (
+        "native" if rlcpack.enabled() else "python")
+    assert [(iv["batch"], iv["packer"]) for iv in at_reject] == (
+        [(5, "python")] if which == "bad" else [])
 
 
 @pytest.mark.parametrize("field,want", [
