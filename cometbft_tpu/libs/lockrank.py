@@ -164,7 +164,6 @@ LOCK_RANKS: dict[str, int] = {
     "native_codec.lib": 560,
     "rlcpack.lib": 565,
     "bls12381.lib": 570,
-    "msm.coeff": 580,
     "compile_hook": 590,
 }
 

@@ -28,8 +28,6 @@ accepted, cofactored equation, s < L enforced host-side.
 
 from __future__ import annotations
 
-import os
-
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -127,19 +125,21 @@ def point_is_identity(p):
 # decompression (ZIP-215: no canonical-y check)
 # ---------------------------------------------------------------------------
 
-# Fused Pallas decompress (ops/pallas_decompress.py).  ON by default:
-# it won its on-chip A/B against the XLA decompression
-# (ab_round4_results.jsonl pallas_decompress_ab).
-USE_PALLAS_DECOMPRESS = os.environ.get(
-    "COMETBFT_TPU_PALLAS_DECOMPRESS", "1") == "1"
-
 def decompress(enc_words: jnp.ndarray):
-    """(8, ...) uint32 LE words of a 32-byte encoding -> (point, ok)."""
-    if USE_PALLAS_DECOMPRESS and enc_words.ndim == 2:
+    """(8, ...) uint32 LE words of a 32-byte encoding -> (point, ok):
+    the fused kernel (ops/pallas_decompress.py) where _pallas_blk gives
+    the width a block, the XLA code everywhere else."""
+    if enc_words.ndim == 2:
         from . import pallas_decompress as pd
         blk = _pallas_blk(enc_words.shape[-1], cap=pd.BLK)
         if blk is not None:
             return pd.decompress(enc_words, blk=blk)
+    return _decompress_xla(enc_words)
+
+
+def _decompress_xla(enc_words: jnp.ndarray):
+    """decompress on the XLA path: the CPU product path, any batch
+    shape, and the reference the kernel is tested against."""
     y = fe.words32_to_limbs(enc_words)
     sign = ((enc_words[7] >> 31) & jnp.uint32(1)).astype(jnp.int32)
     y2 = fe.sqr(y)
@@ -302,46 +302,6 @@ def verify_kernel(a_words, r_words, s_limbs, h_limbs):
 
 NPART_MAX = 192      # max lane-resident partial accumulators
 
-# Fused Pallas select+tree kernel for MSM windows (ops/pallas_msm.py);
-# opt-in until validated on every deployment target
-USE_PALLAS_TREE = os.environ.get("COMETBFT_TPU_PALLAS_TREE", "0") == "1"
-
-# Whole-window-loop Pallas kernel (ops/pallas_msm.msm_window_loop):
-# the entire Straus scan — select, negate, tree, 5 shared doublings —
-# in ONE program with per-block accumulators.  Strictly supersedes
-# USE_PALLAS_TREE when on.  ON by default: the per-window XLA
-# dispatch overhead this kernel removes was several times the useful
-# work in its on-chip A/B (ab_round4_results.jsonl pallas_msm_loop_ab).
-USE_PALLAS_MSM_LOOP = os.environ.get(
-    "COMETBFT_TPU_PALLAS_MSM_LOOP", "1") == "1"
-
-# Fused 17-row table build (ops/pallas_msm.table17_neg): negation +
-# cached conversion + 15 sequential cached adds in one program.  ON by
-# default: it won its on-chip A/B with the other kernels already on
-# (ab_round4_results.jsonl pallas_table_ab).
-USE_PALLAS_TABLE = os.environ.get(
-    "COMETBFT_TPU_PALLAS_TABLE", "1") == "1"
-
-# Fused fold/verify epilogue (ops/pallas_msm.fold_verify): the
-# partial-tensor tree reduction + combine + cofactor + identity check
-# in one program.  ON by default: the ~24 narrow XLA point_add levels
-# it replaces were the largest dispatch-overhead tax left after the
-# window loop; accept/reject parity on real Mosaic in
-# mosaic_smoke4b.jsonl.
-USE_PALLAS_FOLD = os.environ.get(
-    "COMETBFT_TPU_PALLAS_FOLD", "1") == "1"
-
-# Window-major whole-MSM kernel (ops/pallas_msm.msm_window_major):
-# blocks iterate INSIDE each window so the 5 shared doublings run once
-# per window on one global accumulator instead of once per block —
-# the largest line item of the r4 latency decomposition.  Supersedes
-# USE_PALLAS_MSM_LOOP when on.  ON by default: it won its on-chip A/B
-# against the window-loop kernel; parity on real Mosaic at blk
-# 512/1024 (mosaic_smoke4b.jsonl).
-USE_PALLAS_MSM_MAJOR = os.environ.get(
-    "COMETBFT_TPU_PALLAS_MSM_MAJOR", "1") == "1"
-
-
 _SMALL_WIDTHS = (8, 16, 32, 64, 96, 128, 160, 192)
 _BASE_WIDTHS = (128, 160, 192)
 
@@ -377,9 +337,9 @@ def pad_width(n: int) -> int:
     from . import pallas_msm
     while pallas_msm.blk_for(w) is None:
         if w >= 512:
-            # any block setting that is legal at all gives 512 a
-            # block: none is (a garbage COMETBFT_TPU_PALLAS_BLK), every
-            # width takes the XLA path, so keep the grid's own
+            # any block that is legal at all gives 512 a block: none
+            # is (pallas_msm.BLK <= 0), every width takes the XLA
+            # path, so keep the grid's own
             return first
         w = next(grid)
     return w
@@ -439,59 +399,49 @@ def _cond_neg_point(p, neg):
                jnp.where(n, -p[_T], p[_T]))
 
 
+def _pallas_blk(w: int, cap: int | None = None):
+    """Lane block the Pallas kernels take at side width w, or None when
+    that side runs the XLA path: off the chip, or at a width no
+    power-of-two block >= 128 divides (pallas_msm.blk_for: 64, 192).
+    On the chip pad_width returns no such width, so only a caller that
+    packs at a width of its own lands here.
+
+    This is the one choice of kernel: decompress, _msm_tables,
+    _msm_side and _rlc_verdict ask nothing else, and nothing can be
+    set.  Pallas against the XLA scan is the fork the ledger measured
+    (PERF_LEDGER.jsonl, PR 26: the 58-signature remainder 22.35 ms ->
+    0.595 ms on the chip)."""
+    if not _pallas_capable():
+        return None
+    from . import pallas_msm
+    return pallas_msm.blk_for(w, cap=cap)
+
+
 def _msm_tables(enc_words):
     """Decompress one MSM side and build its negated 17-row window
     tables: (8, W) encodings -> ((17, 4, 20, W) table, all-ok bool).
-    Split out of _msm so a repeated side (the distinct-pubkey A side of
-    a validator set verifying many commits) can be built ONCE and
-    cached on device — the reference caches expanded pubkeys for the
-    same reason (/root/reference/crypto/ed25519/ed25519.go:64)."""
+    A function of its own so a repeated side (the distinct-pubkey A
+    side of a validator set verifying many commits) can be built ONCE
+    and cached on device — the reference caches expanded pubkeys for
+    the same reason (/root/reference/crypto/ed25519/ed25519.go:64)."""
     pt, ok = decompress(enc_words)
-    blk = _pallas_blk(pt.shape[-1]) if USE_PALLAS_TABLE else None
+    blk = _pallas_blk(pt.shape[-1])
     if blk is not None:
         from . import pallas_msm
         return pallas_msm.table17_neg(pt, blk=blk), jnp.all(ok)
     return _table17(point_neg(pt)), jnp.all(ok)
 
 
-def _msm_scan(tab, mags, negs):
-    """Shared-doubling Straus scan over pre-built window tables.
+def _msm_scan_xla(tab, mags, negs):
+    """Shared-doubling Straus scan over pre-built window tables, on the
+    XLA path: sum_i e_i * (-P_i) with SIGNED 5-bit windows.
 
     tab: (17, 4, 20, W); mags: (nwin, W) int32 digit magnitudes 0..16,
-    MSB-first; negs: (nwin, W) bool signs.  5 doublings/window act on
-    <= NPART_MAX lane-resident partials.  Returns a (4, 20, 1) point.
-
-    The bucket (Pippenger) arm swaps the per-window select cascade for
-    the generic engine's bucket accumulate+fold when the auto-tuned
-    crossover favors it (ops/msm.choose_engine; force with
-    COMETBFT_TPU_MSM_ENGINE=bucket).  tab[1] is -P (the table is built
-    on the negated point), which is exactly the base-point plane the
-    digits are aimed at — both arms consume the same tables and digit
-    streams, so the choice is invisible above this function.
-    """
-    w = tab.shape[-1]
-    from . import msm as msm_engine
-    if msm_engine.choose_engine(w, 5) == "bucket":
-        spec = msm_engine.ed25519_spec()
-        acc, _ = msm_engine.bucket_msm(spec, (tab[1], None),
-                                       mags, negs, 5)
-        return acc
-    partials = _loop_partials(tab, mags, negs)
-    if partials is not None:
-        return _tree_reduce(partials, 1)
-    tree_blk = _pallas_blk(w) if USE_PALLAS_TREE else None
-    if tree_blk is not None:
-        from . import pallas_msm
-        npart = (w // tree_blk) * pallas_msm._out_lanes(tree_blk)
-
-        def window_contrib(mag, neg):
-            return pallas_msm.select_tree(tab, mag, neg, blk=tree_blk)
-    else:
-        npart = _npart(w)
-
-        def window_contrib(mag, neg):
-            contrib = _cond_neg_point(_select17(tab, mag), neg)
-            return _tree_reduce(contrib, npart)
+    MSB-first; negs: (nwin, W) bool signs (host recoding,
+    crypto/ed25519._recode_w5: 26 windows for the 128-bit z_i, 52 for
+    the 256-bit aggregated zh).  5 doublings/window act on <= NPART_MAX
+    lane-resident partials.  Returns a (4, 20, 1) point."""
+    npart = _npart(tab.shape[-1])
 
     def step(acc, xs):
         mag, neg = xs
@@ -500,59 +450,31 @@ def _msm_scan(tab, mags, negs):
         acc = point_double(acc, with_t=False)
         acc = point_double(acc, with_t=False)
         acc = point_double(acc, with_t=True)
-        return point_add(acc, window_contrib(mag, neg)), None
+        contrib = _cond_neg_point(_select17(tab, mag), neg)
+        return point_add(acc, _tree_reduce(contrib, npart)), None
 
     acc = identity_point((npart,))
     acc, _ = jax.lax.scan(step, acc, (mags, negs))
     return _tree_reduce(acc, 1)
 
 
-def _msm(enc_words, mags, negs):
-    """Straus MSM sum_i e_i * (-P_i) over one batch with SIGNED 5-bit
-    windows: decompress, 17-row per-point tables, shared-doubling scan
-    (5 doublings/window) with per-window lane-parallel tree reduction.
-
-    Host recoding (crypto/ed25519._recode_w5) gives digits in
-    [-16, 16]: 128-bit z_i take 26 windows, 256-bit aggregated zh take
-    52 — vs 32/64 with unsigned 4-bit windows for one extra table row.
-    Returns ((4,20,1) point, all-decompressed-ok bool).
-    """
-    tab, ok = _msm_tables(enc_words)
-    return _msm_scan(tab, mags, negs), ok
-
-
-def _loop_partials(tab, mags, negs):
-    """Window-loop/window-major partial tensor for one MSM side if a
-    Pallas path applies (width divisible by a legal block), else None."""
-    if not (USE_PALLAS_MSM_LOOP or USE_PALLAS_MSM_MAJOR):
-        return None
+def _msm_side(tab, mags, negs):
+    """One MSM side as points whose lane-sum is the MSM: the
+    window-major kernel's (4, 20, out_lanes) accumulator where
+    _pallas_blk gives the width a block, the XLA scan's (4, 20, 1)
+    point where it gives none."""
     blk = _pallas_blk(tab.shape[-1])
     if blk is None:
-        return None
+        return _msm_scan_xla(tab, mags, negs)
     from . import pallas_msm
-    if USE_PALLAS_MSM_MAJOR:
-        return pallas_msm.msm_window_major(tab, mags, negs, blk=blk)
-    return pallas_msm.msm_window_loop(tab, mags, negs, blk=blk)
-
-
-def _pallas_blk(w: int, cap: int | None = None):
-    """Lane block the Pallas kernels take at side width w, or None when
-    that side runs the XLA path: off the chip, or at a width no
-    power-of-two block >= 128 divides (pallas_msm.blk_for: 64, 192).
-    On the chip pad_width returns no such width, so only a caller that
-    packs at a width of its own lands here."""
-    if not _pallas_capable():
-        return None
-    from . import pallas_msm
-    return pallas_msm.blk_for(w, cap=cap)
+    return pallas_msm.msm_window_major(tab, mags, negs, blk=blk)
 
 
 def rlc_kernel_plan(k: int, n: int) -> dict:
     """Which kernel each stage of the RLC program at A width k, R width
-    n lowers to — the same predicates rlc_verify_kernel traces through,
-    so an operator (and chip_smoke.py) can read 'pallas' or 'xla' per
-    MSM side instead of inferring it from a width."""
-    from . import msm as msm_engine
+    n lowers to — the predicate rlc_verify_kernel traces through
+    (_pallas_blk), so an operator (and chip_smoke.py) can read 'pallas'
+    or 'xla' per MSM side instead of inferring it from a width."""
     from . import pallas_decompress as pd
 
     def name(pallas):
@@ -561,22 +483,13 @@ def rlc_kernel_plan(k: int, n: int) -> dict:
     def side(w):
         blk = _pallas_blk(w)
         return {"width": w, "blk": blk,
-                "decompress": name(
-                    USE_PALLAS_DECOMPRESS
-                    and _pallas_blk(w, cap=pd.BLK) is not None),
-                "tables": name(USE_PALLAS_TABLE and blk is not None)}
+                "decompress": name(_pallas_blk(w, cap=pd.BLK) is not None),
+                "tables": name(blk is not None),
+                "msm": name(blk is not None)}
 
-    loops = USE_PALLAS_MSM_LOOP or USE_PALLAS_MSM_MAJOR
     a, r = side(k), side(n)
-    fold = USE_PALLAS_FOLD and loops \
-        and a["blk"] is not None and r["blk"] is not None
-    for s in (a, r):
-        if not fold and \
-                msm_engine.choose_engine(s["width"], 5) == "bucket":
-            s["msm"] = "xla-bucket"
-        else:
-            s["msm"] = name(loops and s["blk"] is not None)
-    return {"a": a, "r": r, "fold": "pallas" if fold else "xla"}
+    return {"a": a, "r": r,
+            "fold": name(a["blk"] is not None and r["blk"] is not None)}
 
 
 @_functools.lru_cache(maxsize=None)
@@ -599,11 +512,12 @@ def rlc_kernel_name(k: int, n: int) -> str:
 
 def _prefold(partials):
     """XLA reduction of a partial tensor down to the fold kernel's VMEM
-    bound — only the wide (efficient) levels run here.  Widths are
-    m*128; when m is odd (window-loop partials with odd nblk > 64,
-    e.g. W=65*512) halving would break 128-alignment, so those widths
-    chunk-sum the tail into the MAX_FOLD_LANES-wide head instead of
-    asserting (r4 advisor)."""
+    bound — only the wide (efficient) levels run here.  The
+    window-major kernel hands over one accumulator of at most 128
+    lanes, so on the product path nothing is left to do; a wider
+    tensor of m*128 lanes is halved while the half stays 128-aligned,
+    and an odd m chunk-sums its tail into the MAX_FOLD_LANES-wide
+    head."""
     from . import pallas_msm
     bound = pallas_msm.MAX_FOLD_LANES
     while partials.shape[-1] > bound:
@@ -624,9 +538,23 @@ def _prefold(partials):
     return partials
 
 
-def _fold_verdict(pa, pr):
-    from . import pallas_msm
-    return pallas_msm.fold_verify(_prefold(pa), _prefold(pr))
+def _rlc_verdict(ok_a, ok_r, tab_a, tab_r, a_mag, a_neg, r_mag, r_neg):
+    """Both MSMs over built tables and the cofactored identity check
+    [8](A + R) == 0, as the batch's one bool: the fused fold
+    (pallas_msm.fold_verify) where both sides ran the window-major
+    kernel, else add, three doublings, identity check on the XLA
+    path."""
+    pa = _msm_side(tab_a, a_mag, a_neg)         # 52 windows, width K
+    pr = _msm_side(tab_r, r_mag, r_neg)         # 26 windows, width N
+    if _pallas_blk(tab_a.shape[-1]) is not None \
+            and _pallas_blk(tab_r.shape[-1]) is not None:
+        from . import pallas_msm
+        return ok_a & ok_r & pallas_msm.fold_verify(_prefold(pa),
+                                                    _prefold(pr))
+    total = point_add(_tree_reduce(pa, 1), _tree_reduce(pr, 1))
+    for _ in range(3):               # cofactor 8
+        total = point_double(total, with_t=False)
+    return ok_a & ok_r & point_is_identity(total)[0]
 
 
 def rlc_verify_kernel(a_words, r_words, a_mag, a_neg, r_mag, r_neg):
@@ -640,17 +568,8 @@ def rlc_verify_kernel(a_words, r_words, a_mag, a_neg, r_mag, r_neg):
     """
     tab_a, ok_a = _msm_tables(a_words)
     tab_r, ok_r = _msm_tables(r_words)
-    if USE_PALLAS_FOLD:
-        pa = _loop_partials(tab_a, a_mag, a_neg)
-        pr = _loop_partials(tab_r, r_mag, r_neg)
-        if pa is not None and pr is not None:
-            return ok_a & ok_r & _fold_verdict(pa, pr)
-    acc_a = _msm_scan(tab_a, a_mag, a_neg)      # 52 windows, width K
-    acc_r = _msm_scan(tab_r, r_mag, r_neg)      # 26 windows, width N
-    total = point_add(acc_a, acc_r)
-    for _ in range(3):               # cofactor 8
-        total = point_double(total, with_t=False)
-    return ok_a & ok_r & point_is_identity(total)[0]
+    return _rlc_verdict(ok_a, ok_r, tab_a, tab_r,
+                        a_mag, a_neg, r_mag, r_neg)
 
 
 _rlc_jitted = jax.jit(rlc_verify_kernel)
@@ -671,17 +590,8 @@ def rlc_verify_kernel_cached_a(a_tab, a_ok, r_words,
     adds, the dominant A-side cost when the same validator set verifies
     a stream of commits (light-client sync, blocksync replay)."""
     r_tab, ok_r = _msm_tables(r_words)
-    if USE_PALLAS_FOLD:
-        pa = _loop_partials(a_tab, a_mag, a_neg)
-        pr = _loop_partials(r_tab, r_mag, r_neg)
-        if pa is not None and pr is not None:
-            return a_ok & ok_r & _fold_verdict(pa, pr)
-    acc_a = _msm_scan(a_tab, a_mag, a_neg)
-    acc_r = _msm_scan(r_tab, r_mag, r_neg)
-    total = point_add(acc_a, acc_r)
-    for _ in range(3):               # cofactor 8
-        total = point_double(total, with_t=False)
-    return a_ok & ok_r & point_is_identity(total)[0]
+    return _rlc_verdict(a_ok, ok_r, a_tab, r_tab,
+                        a_mag, a_neg, r_mag, r_neg)
 
 
 _a_tables_jitted = jax.jit(_msm_tables)

@@ -27,8 +27,6 @@ an original TPU design, not a translation.
 
 from __future__ import annotations
 
-import os
-
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -164,11 +162,6 @@ def mul(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     return _prod_tail(acc, batch)
 
 
-# Dedicated squaring: ~210 int32 multiplies vs mul's 400.  Flag is for
-# on-hardware A/B attribution only.
-FAST_SQR = os.environ.get("COMETBFT_TPU_FAST_SQR", "1") == "1"
-
-
 def sqr(a: jnp.ndarray) -> jnp.ndarray:
     """a**2 with the doubled-cross-terms schoolbook: the i<j products
     appear once against 2*a_i, the diagonal once — 190 + 20 = 210
@@ -178,8 +171,6 @@ def sqr(a: jnp.ndarray) -> jnp.ndarray:
 
     Dominates the decompression sqrt chains (~253 squarings each,
     docs/PERF.md) and point_double (4S of 4M+4S)."""
-    if not FAST_SQR:
-        return mul(a, a)
     batch = a.shape[1:]
     a2 = a + a
     acc = jnp.zeros((2 * NLIMBS - 1,) + batch, dtype=jnp.int32)

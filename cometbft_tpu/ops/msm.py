@@ -37,13 +37,10 @@ group order):
    aggregate work can reuse.
 
 Crossover: on this architecture the masked-selection bucket form
-costs ~``B·W`` point-lane-ops per window (B = bucket count) against
-Straus' ~``W``, so the bucket arm only wins where a backend makes the
-bucket-major tree cheaper than the select cascade — the decision is
-an op-count model with measured per-op coefficients
-(:func:`choose_engine` / :func:`calibrate`), overridable with
-``COMETBFT_TPU_MSM_ENGINE=straus|bucket|auto``.  The honest default
-on XLA keeps Straus for the ed25519 RLC shapes; the engine's product
+costs ~``B·W`` point-lane-ops per window (B = bucket count, 16 at
+width 5) against Straus' ~``W`` to ``6W``, so the ed25519 RLC path
+keeps Straus (ops/ed25519.py) and the bucket arm serves the goldens
+and ops/msm_shard.sharded_bucket_msm; the engine's product
 win is the secp256k1 shared-table path (ops/secp256k1.py
 ``msm_verify_kernel``), which replaces ~4224 field-muls/sig of ladder
 with ~1250 and drops the 256 per-window exact-zero freezes.
@@ -62,8 +59,6 @@ false *reject*, never a false accept.
 
 from __future__ import annotations
 
-import os
-from ..libs import lockrank
 from dataclasses import dataclass
 from typing import Callable
 
@@ -353,7 +348,7 @@ def bucket_msm(spec: CurveSpec, pts_state, mags, negs, width: int):
     """Full bucket (Pippenger) MSM: ``sum_i e_i P_i`` over
     (coords, nlimbs, W) points with (nwin, W) MSB-first signed-window
     digit magnitudes/signs of the e_i (the same digit layout
-    ops/ed25519._msm_scan consumes).  Returns a width-1 state.
+    ops/ed25519._msm_side consumes).  Returns a width-1 state.
 
     Window combination is MSB-first Horner: ``acc = 2^w acc + W_j``,
     so the doublings are shared across all buckets exactly like the
@@ -405,59 +400,3 @@ def multiprod_shared_tables(acc, sides):
             return add_entry(a, gather(tab_j, row), neg), None
         acc, _ = jax.lax.scan(step, acc, (tables, rows, negs))
     return acc
-
-
-# ---------------------------------------------------------------------------
-# engine choice: op-count model with measurable coefficients
-# ---------------------------------------------------------------------------
-#
-# Lane-op model per window over W lanes with B = 2^(w-1) buckets:
-#   straus: select cascade is elementwise (cheap, coefficient c_sel)
-#           + tree reduce W -> npart (~W lane-adds) + w doublings on
-#           npart lanes;
-#   bucket: masked bucket-major tree (~B*W lane-adds) + running-sum
-#           fold (2(B-1) adds) + w doublings on 1 lane.
-# On XLA both arms' lane-adds cost the same per lane, so bucket wins
-# only when a backend's measured add coefficient for the bucket-major
-# layout undercuts the cascade (a Pallas bucket kernel could; the XLA
-# product path does not).  calibrate() lets a bench measure the two
-# coefficients; absent measurements the static model applies.
-
-_COEFF_LOCK = lockrank.RankedLock("msm.coeff")
-_COEFFS: dict[str, float] = {}     # "straus"/"bucket" -> ns per lane-op
-
-
-def straus_window_cost(w_lanes: int, width: int,
-                       npart_max: int = 192) -> float:
-    npart = w_lanes
-    while npart > npart_max:
-        npart //= 2
-    return w_lanes + width * npart
-
-
-def bucket_window_cost(w_lanes: int, width: int) -> float:
-    nbuckets = 1 << (width - 1)
-    return nbuckets * w_lanes + 2 * (nbuckets - 1) + width
-
-
-def calibrate(straus_ns_per_op: float, bucket_ns_per_op: float) -> None:
-    """Install measured per-lane-op coefficients (bench-driven
-    auto-tune; see bench.py --secp arms).  Thread-safe, process-wide."""
-    with _COEFF_LOCK:
-        _COEFFS["straus"] = float(straus_ns_per_op)
-        _COEFFS["bucket"] = float(bucket_ns_per_op)
-
-
-def choose_engine(w_lanes: int, width: int = 5) -> str:
-    """'straus' | 'bucket' for one MSM side of ``w_lanes`` lanes.
-    Evaluated at trace time (shapes are static), honoring
-    COMETBFT_TPU_MSM_ENGINE=straus|bucket|auto."""
-    forced = os.environ.get("COMETBFT_TPU_MSM_ENGINE", "auto")
-    if forced in ("straus", "bucket"):
-        return forced
-    with _COEFF_LOCK:
-        cs = _COEFFS.get("straus", 1.0)
-        cb = _COEFFS.get("bucket", 1.0)
-    s = cs * straus_window_cost(w_lanes, width)
-    b = cb * bucket_window_cost(w_lanes, width)
-    return "bucket" if b < s else "straus"

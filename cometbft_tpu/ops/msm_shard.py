@@ -40,8 +40,7 @@ def _gather_lanes(part, axis: str):
 
 
 def sharded_msm(tab, mags, negs, *, mesh, axis: str = "sig",
-                interpret=False, blk=None, group=None,
-                use_pallas: bool = True):
+                interpret=False, blk=None, use_pallas: bool = True):
     """One lane-sharded MSM: per-device window-major Straus kernel on
     the local table/digit shard, all_gather of the accumulator points,
     local tree fold — returns the replicated (4, 20, 1) MSM point.
@@ -49,18 +48,16 @@ def sharded_msm(tab, mags, negs, *, mesh, axis: str = "sig",
     The interpret-mode validation surface for the CPU mesh: interpret
     compile cost scales with grid steps (windows x blocks unrolled),
     so callers validate with SYNTHETIC few-window digit tensors — the
-    kernel's correctness argument is window-count-independent, and the
-    full 52/26-window program shape was proven on hardware by a
-    mesh-of-1 smoke (mosaic_smoke5.jsonl shard1_rlc).
+    kernel's correctness argument is window-count-independent.
 
     use_pallas=False swaps the per-shard Straus scan to the XLA path
-    (ops/ed25519._msm_scan) while keeping the sharding layout, the
+    (ops/ed25519._msm_scan_xla) while keeping the sharding layout, the
     accumulator-point all_gather, and the group-addition fold — the
     multi-chip-specific machinery — identical.  That is the budget
     surface for the driver dryrun: one interpret-mode Pallas compile
     costs minutes on a single core (the MULTICHIP_r05 rc=124 lesson),
-    and the Pallas kernel body is already proven by the slow-tier
-    interpret parity test and the hardware smoke."""
+    and the Pallas kernel body is proven by the tier-1 interpret
+    parity test (tests/test_pallas_msm.py) and on the chip."""
     from jax import shard_map
 
     from . import ed25519 as dev
@@ -78,10 +75,9 @@ def sharded_msm(tab, mags, negs, *, mesh, axis: str = "sig",
         if use_pallas:
             b = blk or pm.blk_for(tab_l.shape[-1])
             part = pm.msm_window_major(tab_l, mags_l, negs_l,
-                                       interpret=interpret, blk=b,
-                                       group=group)
+                                       interpret=interpret, blk=b)
         else:
-            part = dev._msm_scan(tab_l, mags_l, negs_l)
+            part = dev._msm_scan_xla(tab_l, mags_l, negs_l)
         return dev._tree_reduce(_gather_lanes(part, axis), 1)
 
     return run(tab, mags, negs)
@@ -121,7 +117,7 @@ def sharded_bucket_msm(tab, mags, negs, *, mesh, axis: str = "sig",
 
 def rlc_verify_sharded(a_words, r_words, a_mag, a_neg, r_mag, r_neg,
                        *, mesh, axis: str = "sig", interpret=False,
-                       blk=None, group=None):
+                       blk=None):
     """Whole-batch RLC verify with BOTH MSM sides lane-sharded over
     `mesh`: the multi-chip form of ops/ed25519.rlc_verify_kernel.
 
@@ -130,8 +126,7 @@ def rlc_verify_sharded(a_words, r_words, a_mag, a_neg, r_mag, r_neg,
     path (_msm_tables: Pallas on TPU, XLA elsewhere); the Straus scan
     runs pallas_msm.msm_window_major explicitly so interpret-mode
     validation on a CPU mesh exercises the REAL kernel, not the XLA
-    fallback.  blk must divide the per-device lane
-    width; group degrades per side as usual.
+    fallback.  blk must divide the per-device lane width.
     """
     from jax import shard_map
 
@@ -150,8 +145,7 @@ def rlc_verify_sharded(a_words, r_words, a_mag, a_neg, r_mag, r_neg,
         assert b is not None and tab.shape[-1] % b == 0, \
             (tab.shape, b, "per-device width must admit a block")
         part = pm.msm_window_major(tab, mags, negs,
-                                   interpret=interpret, blk=b,
-                                   group=group)
+                                   interpret=interpret, blk=b)
         return part, ok
 
     @functools.partial(

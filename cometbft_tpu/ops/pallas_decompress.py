@@ -9,9 +9,8 @@ resident Pallas program removes: one program per BLK-lane slice runs
 words->limbs, y^2, the (p-5)/8 power chain (fori_loop of fused
 squarings), the sqrt checks, sign fix, and T=X*Y without leaving VMEM.
 
-Opt-in via COMETBFT_TPU_PALLAS_DECOMPRESS=1 (ops/ed25519.decompress)
-until A/B-validated on hardware, mirroring the select+tree kernel's
-rollout (ops/pallas_msm.py).
+ops/ed25519.decompress takes it wherever _pallas_blk gives the width a
+block.
 
 Reference behavior matched: ZIP-215 decompression
 (/root/reference/crypto/ed25519/ed25519.go:181 via curve25519-voi),

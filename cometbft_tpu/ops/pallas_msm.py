@@ -1,18 +1,15 @@
-"""Pallas TPU kernel for the MSM window hot loop: fused
-table-select + conditional-negate + tree-reduce.
+"""Pallas TPU kernels of the RLC verify path's MSM: the 17-row table
+build (table17_neg), the window-major Straus MSM (msm_window_major) and
+the fold/verify epilogue (fold_verify).  ops/pallas_decompress.py holds
+the fourth shipping kernel and borrows the field ops below.
 
-Profiling on-chip showed the per-window tree reduction costs ~5x its
-pure mul time under XLA: every point_add level at shrinking widths
+Why kernels: under XLA every point_add level at shrinking widths
 dispatches ~20 separate (20, W) elementwise fusions whose fixed costs
-dominate below ~2048 lanes.  This kernel keeps the whole per-block
-pipeline — 16-way predicated select from the window table, signed-digit
-negation, and the log-depth tree of extended-coordinate point
-additions — inside one Pallas program with everything VMEM-resident.
-
-Grid: one program per BLK-lane slice of the batch; each program reduces
-its slice to OUT_PER_BLK partial points written to a disjoint lane
-range, giving a (4, 20, W // BLK * OUT_PER_BLK) partial tensor the
-caller folds into the accumulator (ops/ed25519._msm).
+dominate below ~2048 lanes.  Each kernel here keeps its whole pipeline
+— 17-way predicated select from the window table, signed-digit
+negation, the log-depth tree of extended-coordinate point additions,
+the shared doublings — inside one Pallas program with everything
+VMEM-resident.  ops/ed25519._pallas_blk decides where they run.
 
 The field arithmetic mirrors ops/fe.py (same radix-13 signed-limb
 bounds proof); shapes inside the kernel are (20, lanes) with the limb
@@ -23,7 +20,6 @@ crossings, matching the VPU layout the XLA kernels use.
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -31,12 +27,10 @@ from jax.experimental import pallas as pl
 
 from . import fe
 
-# Lanes per program.  512 was the round-4 shipping default; larger
-# blocks amortize the per-window shared doublings over more lanes
-# (doubling cost scales with OUT_PER_BLK * nblk = OUT_PER_BLK * W/BLK)
-# at the price of a bigger VMEM-resident table block (17*4*20*BLK*4 B:
-# 2.8 MB at 512, 5.6 MB at 1024).
-BLK = int(os.environ.get("COMETBFT_TPU_PALLAS_BLK", "512"))
+# Lanes per program: the VMEM-resident table block is 17*4*20*BLK*4 B,
+# 2.8 MB at 512.  A module attribute because tests shrink it
+# (monkeypatch.setattr) to run the kernels in interpret mode.
+BLK = 512
 
 
 def blk_for(w: int, cap: int | None = None):
@@ -45,15 +39,14 @@ def blk_for(w: int, cap: int | None = None):
     The 128 floor is Mosaic's lane-tile width; tests that shrink BLK
     below it keep their narrow block as the floor."""
     b = min(BLK, cap) if cap else BLK
-    if b <= 0:          # garbage env override: loud fallback, no hang
+    if b <= 0:          # no legal block: the XLA path, not a hang
         return None
     # sub-128 test blocks may be any size (the in-kernel tree never
     # halves them: out_lanes == blk).  At or above 128 the tree must
     # halve exactly onto the 128-lane output, so blocks are pow2-only
-    # — a non-pow2 override (e.g. 384, whose halving walks 384->192->96
+    # — a non-pow2 BLK (e.g. 384, whose halving walks 384->192->96
     # past the 128-lane scratch) rounds DOWN to a pow2 candidate
-    # instead of being returned verbatim or losing the path (r4
-    # advisor + r5 review)
+    # instead of being returned verbatim or losing the path
     if b < 128 and w % b == 0:
         return b
     b = 1 << (b.bit_length() - 1)
@@ -63,19 +56,20 @@ def blk_for(w: int, cap: int | None = None):
             return b
         b //= 2
     return None
-# Partials each program writes (cap).  The in-kernel pairwise tree
+
+
+# Lanes of the MSM accumulator (cap).  The in-kernel pairwise tree
 # stops at 128 lanes: every level below 128 needs sub-tile lane
-# slicing/relayouts (the prime Mosaic-ICE suspect in the r4 smoke
-# run's select_tree HTTP 500), and narrowing below one (8, 128) VPU
-# tile saves nothing — a (20, 8) accumulator pads to the same vregs
-# as (20, 128).  Stopping at 128 also shrinks the unrolled body from
-# 6 point_add levels to 2 at BLK=512.  The caller's XLA _tree_reduce
-# folds the wider partial tensor once per MSM (not per window).
+# slicing/relayouts, and narrowing below one (8, 128) VPU tile saves
+# nothing — a (20, 8) accumulator pads to the same vregs as (20, 128).
+# Stopping at 128 also shrinks the unrolled body from 6 point_add
+# levels to 2 at BLK=512.  fold_verify folds the 128 lanes once per
+# MSM (not per window).
 OUT_PER_BLK = 128
 
 
 def _out_lanes(blk: int) -> int:
-    """Lanes each program's partial occupies for a given block size."""
+    """Lanes the accumulator occupies for a given block size."""
     return min(blk, OUT_PER_BLK)
 
 
@@ -121,8 +115,6 @@ def _sq(a):
     """Dedicated squaring, Mosaic form of fe.sqr: cross terms once
     against doubled limbs plus the diagonal — 210 multiplies vs _mul's
     400 on identical column values (fe.sqr has the bounds argument)."""
-    if not fe.FAST_SQR:
-        return _mul(a, a)
     nl = fe.NLIMBS
     a2 = a + a
     cols = []
@@ -222,41 +214,6 @@ def _point_add(p, q, d2):
     return _add_cached(p, _to_cached(q, d2))
 
 
-# -- the kernel -------------------------------------------------------------
-
-def _block_contrib(tab_ref, mag, neg, d2, out_w):
-    """Shared kernel prologue: 17-row predicated select from the VMEM
-    table block, signed-digit negation (X/T arithmetic negation of the
-    redundant signed limbs), and the tile-aligned pairwise halving of
-    the block down to out_w lanes.  ONE copy of this subtle
-    select/flip/tree logic — every MSM kernel variant calls it."""
-    sel = tab_ref[0]                     # (4, 20, BLK)
-    for k in range(1, 17):
-        cond = (mag == jnp.int32(k))[None, None]
-        sel = jnp.where(cond, tab_ref[k], sel)
-    flip = (neg != 0)[None]
-    x = jnp.where(flip, -sel[0], sel[0])
-    t = jnp.where(flip, -sel[3], sel[3])
-    pts = jnp.stack([x, sel[1], sel[2], t], axis=0)
-    w = pts.shape[-1]
-    while w > out_w:
-        half = w // 2
-        pts = _point_add(pts[..., :half], pts[..., half:w], d2)
-        w = half
-    return pts
-
-
-def _select_tree_kernel(tab_ref, mag_ref, neg_ref, d2_ref, out_ref):
-    """tab (17, 4, 20, BLK) VMEM; mag/neg (1, BLK); d2 (20, 1);
-    out (1, 4, 20, OUT) — the block index rides a LEADING output dim
-    so stores stay tile-aligned (an 8-lane slice at lane offset 8*i
-    is not a legal Mosaic store; a full block at leading index i is).
-    """
-    d2 = d2_ref[:, :]                    # (20, 1)
-    out_ref[0] = _block_contrib(tab_ref, mag_ref[0, :], neg_ref[0, :],
-                                d2, out_ref.shape[-1])
-
-
 def _point_double(p, with_t: bool):
     """dbl-2008-hwcd for a=-1 on values (ops/ed25519.point_double)."""
     x, y, z = p[0], p[1], p[2]
@@ -272,118 +229,6 @@ def _point_double(p, with_t: bool):
     return jnp.stack([_mul(e, f), _mul(g, h), _mul(f, g), t], axis=0)
 
 
-def _window_loop_kernel(tab_ref, mag_ref, neg_ref, d2_ref, out_ref):
-    """One grid step = (block i, window j), j fastest: the ENTIRE
-    Straus window loop runs fused, with per-block accumulators.
-
-    Correctness of per-block doubling: the shared-doubling recurrence
-    acc <- 32*acc + contrib is linear in the contributions, so each
-    block maintaining its own accumulator (with its own 5 doublings
-    per window) and summing the block accumulators at the end equals
-    the single global accumulator — while keeping every op inside one
-    Pallas program, which is the point: profiling showed per-window
-    XLA dispatch overhead (~5x the tree's pure mul time) dominating.
-
-    tab block is revisited for every j (index map ignores j), so the
-    pipeline keeps it VMEM-resident rather than re-fetching.
-    """
-    j = pl.program_id(1)
-    d2 = d2_ref[:, :]
-    pts = _block_contrib(tab_ref, mag_ref[0, 0, :], neg_ref[0, 0, :],
-                         d2, out_ref.shape[-1])
-
-    @pl.when(j == 0)
-    def _first():
-        out_ref[0] = pts
-
-    @pl.when(j != 0)
-    def _step():
-        acc = out_ref[0]
-        acc = _point_double(acc, with_t=False)
-        acc = _point_double(acc, with_t=False)
-        acc = _point_double(acc, with_t=False)
-        acc = _point_double(acc, with_t=False)
-        acc = _point_double(acc, with_t=True)
-        out_ref[0] = _point_add(acc, pts, d2)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret", "blk"))
-def _msm_window_loop_jit(tab, mags, negs, interpret, blk):
-    w = tab.shape[-1]
-    assert w % blk == 0, (w, blk)
-    nblk = w // blk
-    nwin = mags.shape[0]
-    out_l = _out_lanes(blk)
-    out = pl.pallas_call(
-        _window_loop_kernel,
-        out_shape=jax.ShapeDtypeStruct(
-            (nblk, 4, fe.NLIMBS, out_l), jnp.int32),
-        grid=(nblk, nwin),
-        in_specs=[
-            pl.BlockSpec((17, 4, fe.NLIMBS, blk),
-                         lambda i, j: (0, 0, 0, i)),
-            # digits ride a (nwin, 1, W) layout so the BLOCK's last two
-            # dims are (1, blk) against ARRAY dims (1, W) — Mosaic
-            # requires the last two block dims divisible by (8, 128) or
-            # equal to the array's (a (1, blk) block on (nwin, W) was
-            # rejected in the r4 smoke run)
-            pl.BlockSpec((1, 1, blk), lambda i, j: (j, 0, i)),
-            pl.BlockSpec((1, 1, blk), lambda i, j: (j, 0, i)),
-            pl.BlockSpec((fe.NLIMBS, 1), lambda i, j: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 4, fe.NLIMBS, out_l),
-                               lambda i, j: (i, 0, 0, 0)),
-        interpret=interpret,
-    )(tab, mags.reshape(nwin, 1, w), negs.astype(jnp.int32).reshape(nwin, 1, w),
-      jnp.asarray(fe.D2_LIMBS).reshape(fe.NLIMBS, 1))
-    return out.transpose(1, 2, 0, 3).reshape(
-        4, fe.NLIMBS, nblk * out_l)
-
-
-def msm_window_loop(tab, mags, negs, interpret=False, blk=None):
-    """(17,4,20,W) table + (nwin,W) MSB-first signed digits ->
-    (4,20,W//blk*OUT_PER_BLK) per-block accumulators whose SUM is the
-    full MSM over all windows.  Replaces the per-window XLA scan.
-
-    blk (lanes per program) defaults to module BLK; the correctness
-    argument is width-independent, so tests run narrow blocks."""
-    return _msm_window_loop_jit(tab, mags, negs, interpret, blk or BLK)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret", "blk"))
-def _select_tree_jit(tab, mag, neg, interpret, blk):
-    w = tab.shape[-1]
-    assert w % blk == 0, (w, blk)
-    nblk = w // blk
-    grid = (nblk,)
-    out_l = _out_lanes(blk)
-    out = pl.pallas_call(
-        _select_tree_kernel,
-        out_shape=jax.ShapeDtypeStruct(
-            (nblk, 4, fe.NLIMBS, out_l), jnp.int32),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((17, 4, fe.NLIMBS, blk),
-                         lambda i: (0, 0, 0, i)),
-            pl.BlockSpec((1, blk), lambda i: (0, i)),
-            pl.BlockSpec((1, blk), lambda i: (0, i)),
-            pl.BlockSpec((fe.NLIMBS, 1), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 4, fe.NLIMBS, out_l),
-                               lambda i: (i, 0, 0, 0)),
-        interpret=interpret,
-    )(tab, mag.reshape(1, -1), neg.astype(jnp.int32).reshape(1, -1),
-      jnp.asarray(fe.D2_LIMBS).reshape(fe.NLIMBS, 1))
-    return out.transpose(1, 2, 0, 3).reshape(
-        4, fe.NLIMBS, nblk * out_l)
-
-
-def select_tree(tab, mag, neg, interpret=False, blk=None):
-    """(17,4,20,W) table + (W,) digits -> (4,20,W//blk*OUT_PER_BLK)
-    partial points, one fused Pallas program per blk lanes."""
-    return _select_tree_jit(tab, mag, neg, interpret, blk or BLK)
-
-
 # -- fused 17-row table build ----------------------------------------------
 
 def _table17_neg_kernel(pt_ref, d2_ref, out_ref):
@@ -391,8 +236,7 @@ def _table17_neg_kernel(pt_ref, d2_ref, out_ref):
     k=0..16 (the MSM consumes negated tables: ops/ed25519._msm_tables).
     Fuses the negation, the cached-form conversion, and the 15
     sequential cached adds that otherwise run as an XLA scan of ~20
-    dispatched fusions per step — the same per-op fixed-cost tax the
-    window-loop kernel removes from the scan side."""
+    dispatched fusions per step."""
     p = pt_ref[...]
     d2 = d2_ref[:, :]
     p = jnp.stack([fe.neg(p[0]), p[1], p[2], fe.neg(p[3])], axis=0)
@@ -438,19 +282,35 @@ def table17_neg(pt, interpret=False, blk=None):
 
 # -- window-major whole-MSM kernel -----------------------------------------
 #
-# The window-loop kernel (grid (nblk, nwin), window fastest) keeps each
-# table block VMEM-resident but pays the 5 shared doublings PER BLOCK
-# per window — doubling cost scales with OUT_PER_BLK * nblk lanes, the
-# largest line item of the round-4 latency decomposition (~19 ms of the
-# 58.8 ms dispatch at batch 16383 pre-fast-sqr).  This variant flips
-# the grid to (nwin, nblk), block fastest: per window, the blocks'
-# select+tree contributions accumulate into a VMEM scratch, and the
-# doubling chain runs ONCE per window on the single global accumulator
-# (the output block, whose constant index map keeps it VMEM-resident
-# across the whole grid).  The table block now changes every step and
-# is re-streamed from HBM each window (~5440 B/lane/window), but the
-# per-step fetch (2.8 MB at blk 512, ~3.4 us at v5e HBM bandwidth)
-# hides under the ~30 us of per-step compute in the pipeline.
+# Grid (nwin, nblk), block fastest: per window, the blocks' select+tree
+# contributions accumulate into a VMEM scratch, and the 5 shared
+# doublings run ONCE per window on the single global accumulator (the
+# output block, whose constant index map keeps it VMEM-resident across
+# the whole grid) — not once per block.  The table block changes every
+# step and is re-streamed from HBM each window (~5440 B/lane/window),
+# but the per-step fetch (2.8 MB at blk 512, ~3.4 us at v5e HBM
+# bandwidth) hides under the ~30 us of per-step compute in the pipeline.
+
+def _block_contrib(tab_ref, mag, neg, d2, out_w):
+    """The kernel's prologue: 17-row predicated select from the VMEM
+    table block, signed-digit negation (X/T arithmetic negation of the
+    redundant signed limbs), and the tile-aligned pairwise halving of
+    the block down to out_w lanes."""
+    sel = tab_ref[0]                     # (4, 20, BLK)
+    for k in range(1, 17):
+        cond = (mag == jnp.int32(k))[None, None]
+        sel = jnp.where(cond, tab_ref[k], sel)
+    flip = (neg != 0)[None]
+    x = jnp.where(flip, -sel[0], sel[0])
+    t = jnp.where(flip, -sel[3], sel[3])
+    pts = jnp.stack([x, sel[1], sel[2], t], axis=0)
+    w = pts.shape[-1]
+    while w > out_w:
+        half = w // 2
+        pts = _point_add(pts[..., :half], pts[..., half:w], d2)
+        w = half
+    return pts
+
 
 def _window_major_kernel(tab_ref, mag_ref, neg_ref, d2_ref, out_ref,
                          wacc_ref, *, nblk):
@@ -503,6 +363,10 @@ def _msm_window_major_jit(tab, mags, negs, interpret, blk):
         in_specs=[
             pl.BlockSpec((17, 4, fe.NLIMBS, blk),
                          lambda j, i: (0, 0, 0, i)),
+            # digits ride a (nwin, 1, W) layout so the BLOCK's last two
+            # dims are (1, blk) against ARRAY dims (1, W) — Mosaic
+            # requires the last two block dims divisible by (8, 128) or
+            # equal to the array's
             pl.BlockSpec((1, 1, blk), lambda j, i: (j, 0, i)),
             pl.BlockSpec((1, 1, blk), lambda j, i: (j, 0, i)),
             pl.BlockSpec((fe.NLIMBS, 1), lambda j, i: (0, 0)),
@@ -517,149 +381,28 @@ def _msm_window_major_jit(tab, mags, negs, interpret, blk):
     return out[0]
 
 
-def msm_window_major(tab, mags, negs, interpret=False, blk=None,
-                     group=None):
+def msm_window_major(tab, mags, negs, interpret=False, blk=None):
     """(17,4,20,W) table + (nwin,W) MSB-first signed digits ->
     (4,20,out_lanes) accumulator holding the FULL MSM (its lane-sum):
-    the exact Straus recurrence with one global accumulator — no
-    per-block doubling chains to pay for, no cross-block linearity
-    argument needed.
+    the exact Straus recurrence with one global accumulator.
 
-    group > 1 dispatches the GROUPED variant: G consecutive windows
-    share one table-block fetch (see _window_major_grouped_kernel)."""
-    g = WIN_GROUP if group is None else group
-    if g > 1:
-        return _msm_window_major_grouped_jit(tab, mags, negs,
-                                             interpret, blk or BLK, g)
+    blk (lanes per program) defaults to module BLK; the correctness
+    argument is width-independent, so tests run narrow blocks."""
     return _msm_window_major_jit(tab, mags, negs, interpret, blk or BLK)
-
-
-# -- grouped window-major kernel -------------------------------------------
-#
-# The window-major grid (nwin, nblk) re-fetches each table block from
-# HBM once PER WINDOW: 52 windows x 64 blocks x 2.8 MB = ~9.3 GB per
-# A-side dispatch at batch 32767 — ~11 ms of HBM time at v5e peak
-# against a ~65 ms dispatch, paid again (~4.6 GB) on the R side.  This
-# variant makes the group of G consecutive windows share one fetch:
-# grid (nwin/G, nblk, G) with the GROUP index outermost and the window-
-# in-group index g fastest; the tab index map ignores g, so the
-# pipeline keeps the block VMEM-resident across the G inner steps
-# (same revisiting guarantee the window-loop kernel relies on), cutting
-# table traffic by G.  Each window-in-group accumulates into its own
-# (4, 20, out_l) VMEM scratch row; when the LAST block of the LAST
-# window-in-group closes, the group folds into the global accumulator
-# with the usual 5-doublings-then-add chain per window, preserving the
-# exact Straus recurrence acc <- 32*acc + contrib_w in MSB order.
-
-WIN_GROUP = int(os.environ.get("COMETBFT_TPU_PALLAS_WIN_GROUP", "1"))
-
-
-def group_for(nwin: int, requested: int) -> int:
-    """Largest divisor of nwin that is <= requested (window counts per
-    MSM side differ — 52-window A sides admit {2, 4, 13}, 26-window R
-    sides {2, 13} — so the requested group degrades per side)."""
-    g = 1
-    for c in range(2, min(requested, nwin) + 1):
-        if nwin % c == 0:
-            g = c
-    return g
-
-
-def _window_major_grouped_kernel(tab_ref, mag_ref, neg_ref, d2_ref,
-                                 out_ref, wacc_ref, *, nblk, group):
-    jg = pl.program_id(0)
-    i = pl.program_id(1)
-    g = pl.program_id(2)
-    d2 = d2_ref[:, :]
-    pts = _block_contrib(tab_ref, mag_ref[0, 0, :], neg_ref[0, 0, :],
-                         d2, wacc_ref.shape[-1])
-
-    @pl.when(i == 0)
-    def _win_first():
-        wacc_ref[pl.ds(g, 1)] = pts[None]
-
-    @pl.when(i != 0)
-    def _win_accum():
-        cur = wacc_ref[pl.ds(g, 1)][0]
-        wacc_ref[pl.ds(g, 1)] = _point_add(cur, pts, d2)[None]
-
-    @pl.when((i == nblk - 1) & (g == group - 1))
-    def _close_group():
-        # fori_loop, NOT a python unroll: an unrolled close is 5*group
-        # point_doubles of ~5k HLO nodes each — a compile bomb at
-        # group 13 (both XLA-interpret and Mosaic); the loop body
-        # compiles once and the doubling chain math is identical
-        def body(gp, acc):
-            for _ in range(4):
-                acc = _point_double(acc, with_t=False)
-            acc = _point_double(acc, with_t=True)
-            return _point_add(acc, wacc_ref[pl.ds(gp, 1)][0], d2)
-
-        @pl.when(jg == 0)
-        def _first_group():
-            out_ref[0] = jax.lax.fori_loop(1, group, body, wacc_ref[0])
-
-        @pl.when(jg != 0)
-        def _later_group():
-            out_ref[0] = jax.lax.fori_loop(0, group, body, out_ref[0])
-
-
-@functools.partial(jax.jit, static_argnames=("interpret", "blk",
-                                             "group"))
-def _msm_window_major_grouped_jit(tab, mags, negs, interpret, blk,
-                                  group):
-    from jax.experimental.pallas import tpu as pltpu
-
-    w = tab.shape[-1]
-    assert w % blk == 0, (w, blk)
-    nblk = w // blk
-    nwin = mags.shape[0]
-    grp = group_for(nwin, group)
-    if grp == 1:
-        return _msm_window_major_jit(tab, mags, negs, interpret, blk)
-    ngrp = nwin // grp
-    out_l = _out_lanes(blk)
-    kernel = functools.partial(_window_major_grouped_kernel,
-                               nblk=nblk, group=grp)
-    out = pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct((1, 4, fe.NLIMBS, out_l),
-                                       jnp.int32),
-        # g fastest so the tab block (index map ignores g) stays
-        # resident for the whole group; i next so each block sweep
-        # completes before the group closes
-        grid=(ngrp, nblk, grp),
-        in_specs=[
-            pl.BlockSpec((17, 4, fe.NLIMBS, blk),
-                         lambda jg, i, g: (0, 0, 0, i)),
-            pl.BlockSpec((1, 1, blk),
-                         lambda jg, i, g, _grp=grp: (jg * _grp + g, 0, i)),
-            pl.BlockSpec((1, 1, blk),
-                         lambda jg, i, g, _grp=grp: (jg * _grp + g, 0, i)),
-            pl.BlockSpec((fe.NLIMBS, 1), lambda jg, i, g: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 4, fe.NLIMBS, out_l),
-                               lambda jg, i, g: (0, 0, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((grp, 4, fe.NLIMBS, out_l),
-                                   jnp.int32)],
-        interpret=interpret,
-    )(tab, mags.reshape(nwin, 1, w),
-      negs.astype(jnp.int32).reshape(nwin, 1, w),
-      jnp.asarray(fe.D2_LIMBS).reshape(fe.NLIMBS, 1))
-    return out[0]
 
 
 # -- fused fold/verify epilogue --------------------------------------------
 #
-# After the window-loop kernel, each MSM side is a (4, 20, m*128)
-# partial tensor whose lane-sum is the MSM result.  The XLA epilogue
-# (_tree_reduce to 1 lane, combine, 3 cofactor doublings, identity
-# check) runs ~12 point_add levels at shrinking widths — exactly the
-# fixed-cost-dominated regime the window-loop kernel was built to
-# avoid.  This kernel runs the whole epilogue in ONE program:
-# tile-aligned halving/chunk-sum to 128 lanes, a 7-step butterfly
-# roll-fold (every op full-width — no sub-128-lane slicing, which
-# Mosaic rejected in the r4 smoke run), cofactor, frozen identity.
+# After the window-major kernel, each MSM side is a (4, 20, m*128)
+# partial tensor whose lane-sum is the MSM result (m = 1 on one chip,
+# the mesh size under ops/msm_shard).  The XLA epilogue (_tree_reduce
+# to 1 lane, combine, 3 cofactor doublings, identity check) runs ~12
+# point_add levels at shrinking widths — exactly the
+# fixed-cost-dominated regime the kernels exist to avoid.  This kernel
+# runs the whole epilogue in ONE program: tile-aligned
+# halving/chunk-sum to 128 lanes, a 7-step butterfly roll-fold (every
+# op full-width — Mosaic takes no sub-128-lane slicing), cofactor,
+# frozen identity.
 
 # Partials wider than this are pre-folded by the caller in XLA (those
 # levels are wide enough to be efficient there) to bound kernel VMEM:

@@ -114,9 +114,7 @@ KNOBS = {
     "COMETBFT_TPU_SIGCACHE",
     "COMETBFT_TPU_SIGCACHE_CAPACITY",
     # device kernels / caches
-    "COMETBFT_TPU_MSM_ENGINE",
     "COMETBFT_TPU_SECP_MSM",
-    "COMETBFT_TPU_FAST_SQR",
     "COMETBFT_TPU_A_CACHE",
     "COMETBFT_TPU_A_CACHE_CAP",
     "COMETBFT_TPU_A_CACHE_MIN_K",
@@ -124,14 +122,6 @@ KNOBS = {
     "COMETBFT_TPU_Q_CACHE_BYTES",
     "COMETBFT_TPU_DEVICE_HASH",
     "COMETBFT_TPU_DEVICE_HASH_BLOCKS",
-    "COMETBFT_TPU_PALLAS_BLK",
-    "COMETBFT_TPU_PALLAS_TREE",
-    "COMETBFT_TPU_PALLAS_DECOMPRESS",
-    "COMETBFT_TPU_PALLAS_MSM_LOOP",
-    "COMETBFT_TPU_PALLAS_MSM_MAJOR",
-    "COMETBFT_TPU_PALLAS_TABLE",
-    "COMETBFT_TPU_PALLAS_FOLD",
-    "COMETBFT_TPU_PALLAS_WIN_GROUP",
     # mesh / blocksync
     "COMETBFT_TPU_MESH_DEVICES",
     "COMETBFT_TPU_MESH_MIN_SPLIT",

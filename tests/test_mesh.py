@@ -475,7 +475,7 @@ class TestShardedBucketMSM:
         mags = jnp.asarray(rng.integers(0, 17, (nwin, w),
                                         dtype=np.int32))
         negs = jnp.asarray(rng.integers(0, 2, (nwin, w)) != 0)
-        want = dev._msm_scan(tab, mags, negs)
+        want = dev._msm_scan_xla(tab, mags, negs)
         got = msm_shard.sharded_bucket_msm(tab, mags, negs,
                                            mesh=sharding._mesh())
         x_eq = np.asarray(fe.freeze(fe.mul(got[0], want[2]))) \

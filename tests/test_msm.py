@@ -208,36 +208,6 @@ class TestBucketMSMGoldens:
         self._run_secp256k1(4, lanes=8)
 
 
-class TestEngineChoice:
-    @pytest.mark.parametrize("forced", ["bucket", "straus"])
-    def test_env_force(self, monkeypatch, forced):
-        monkeypatch.setenv("COMETBFT_TPU_MSM_ENGINE", forced)
-        assert msm.choose_engine(64) == forced
-
-    def test_auto_returns_valid_engine(self, monkeypatch):
-        monkeypatch.delenv("COMETBFT_TPU_MSM_ENGINE", raising=False)
-        got = msm.choose_engine(256, 5)
-        assert got in ("straus", "bucket")
-
-    def test_calibrate_moves_crossover(self, monkeypatch):
-        monkeypatch.delenv("COMETBFT_TPU_MSM_ENGINE", raising=False)
-        try:
-            # measured bucket cost 1000x straus -> straus must win
-            msm.calibrate(1.0, 1000.0)
-            assert msm.choose_engine(16384, 5) == "straus"
-            # measured straus cost 1000x bucket -> bucket must win
-            msm.calibrate(1000.0, 1.0)
-            assert msm.choose_engine(16, 5) == "bucket"
-        finally:
-            msm.calibrate(1.0, 1.0)
-
-    def test_cost_models_scale_as_documented(self):
-        # bucket window cost grows with lanes*buckets, straus with
-        # lanes — the crossover honesty note in ops/msm.py
-        assert (msm.bucket_window_cost(4096, 5)
-                > msm.straus_window_cost(4096, 5))
-
-
 class TestSecpMsmKernel:
     """pack_msm_batch + QTableCache + verify_batch_msm_device vs the
     host verify oracle.  One (16, key-pad-4) shape for the file."""

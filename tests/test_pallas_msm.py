@@ -1,24 +1,28 @@
-"""Fused Pallas MSM kernels (ops/pallas_msm.py, ops/pallas_decompress.py)
-vs the XLA reference path.
+"""The four shipping Pallas kernels (ops/pallas_msm.py,
+ops/pallas_decompress.py) vs the XLA reference path, and the one
+predicate that chooses between them (ops/ed25519._pallas_blk).
 
 Two tiers, both CPU-safe:
 
 1. KERNEL tests run the real kernels in interpret mode at small widths
    (blk<=16, few windows).  The kernels' correctness argument —
-   predicated select cascade, pairwise tree, per-block linear
-   accumulators, grid/index-map slicing — is width-independent, and
-   interpret-mode COMPILE time scales with lanes x windows: the
-   round-3 file ran 512-lane/26-window programs and cost 18 min +
-   16 GB RSS, enough to OOM-segfault a full-suite run.  Small shapes
-   keep the whole file in single-digit minutes and < 4 GB.
+   predicated select cascade, pairwise tree, one global accumulator,
+   grid/index-map slicing — is width-independent, and interpret-mode
+   COMPILE time scales with lanes x windows: 512-lane/26-window
+   programs cost 18 min + 16 GB RSS, enough to OOM-segfault a
+   full-suite run.  Small shapes keep the whole file in single-digit
+   minutes and < 4 GB.
 
-2. DISPATCH tests prove the product path (rlc_verify_kernel) actually
-   routes through the kernels when the flags are on: the kernel entry
-   is replaced at trace time with a spy that records the call and
-   returns the XLA-branch value, so the end-to-end verdicts (accept +
+2. DISPATCH tests prove the product path (rlc_verify_kernel) routes
+   through the kernels wherever _pallas_blk gives a side a block —
+   _pallas_capable patched true, pallas_msm.BLK shrunk — and through
+   the XLA path on a side it gives none: each kernel entry is replaced
+   at trace time with a spy that records the call and returns the
+   XLA path's value under its own name (_msm_scan_xla,
+   _decompress_xla, _table17), so the end-to-end verdicts (accept +
    tampered-reject) are checked without paying a giant interpret
-   compile.  Full-width semantic equality on real Mosaic is
-   chip_smoke.py's job (reference verdicts on the chip).
+   compile.  Full-width semantic equality on real Mosaic is the
+   chip's job (chip_smoke.py, the benchmark's `correct`).
 """
 
 import numpy as np
@@ -62,87 +66,55 @@ def _pt_eq(a, b):
 
 # -- tier 1: the kernels themselves, interpret mode ------------------------
 
-@pytest.mark.parametrize("seed", [0, 1])
-def test_select_tree_matches_xla(seed):
+def _msm_case(w, nwin, seed):
     rng = np.random.default_rng(seed)
-    tab = dev._table17(_points(W))
-    mag = jnp.asarray(rng.integers(0, 17, (W,), dtype=np.int32))
-    neg = jnp.asarray(rng.integers(0, 2, (W,)) != 0)
-
-    sel = dev._cond_neg_point(dev._select17(tab, mag), neg)
-    want = dev._tree_reduce(sel, 1)
-    got_part = pm.select_tree(tab, mag, neg, interpret=True, blk=W)
-    got = dev._tree_reduce(jnp.asarray(got_part), 1)
-    assert _pt_eq(want, got)
-
-
-def test_select_tree_multiblock():
-    """Two 8-lane programs over a 16-wide batch: the grid/index-map
-    slicing, not just the in-block math."""
-    rng = np.random.default_rng(7)
-    tab = dev._table17(_points(W))
-    mag = jnp.asarray(rng.integers(0, 17, (W,), dtype=np.int32))
-    neg = jnp.asarray(rng.integers(0, 2, (W,)) != 0)
-
-    sel = dev._cond_neg_point(dev._select17(tab, mag), neg)
-    want = dev._tree_reduce(sel, 1)
-    got_part = pm.select_tree(tab, mag, neg, interpret=True, blk=8)
-    assert got_part.shape[-1] == 2 * pm._out_lanes(8)
-    got = dev._tree_reduce(jnp.asarray(got_part), 1)
-    assert _pt_eq(want, got)
-
-
-def test_select_tree_identity_pads():
-    """Zero digits select the identity row; an all-zero block must
-    reduce to the identity (the pad-slot case)."""
-    tab = dev._table17(_points(W))
-    mag = jnp.zeros((W,), jnp.int32)
-    neg = jnp.zeros((W,), bool)
-    got_part = pm.select_tree(tab, mag, neg, interpret=True, blk=W)
-    total = dev._tree_reduce(jnp.asarray(got_part), 1)
-    assert bool(dev.point_is_identity(total)[0])
-
-
-def test_msm_window_loop_matches_scan():
-    """The whole-window-loop kernel (per-block accumulators + fused
-    doublings) equals the XLA shared-doubling scan over the same
-    digits — the linearity argument in _window_loop_kernel, checked."""
-    w, nwin = 8, 4                # j==0 init + 3 accumulate/double steps
-    rng = np.random.default_rng(3)
     tab = dev._table17(_points(w))
     mags = jnp.asarray(rng.integers(0, 17, (nwin, w), dtype=np.int32))
     negs = jnp.asarray(rng.integers(0, 2, (nwin, w)) != 0)
-
-    want = dev._msm_scan(tab, mags, negs)          # XLA reference
-    partials = pm.msm_window_loop(tab, mags, negs, interpret=True, blk=w)
-    got = dev._tree_reduce(jnp.asarray(partials), 1)
-    assert _pt_eq(want, got)
+    return tab, mags, negs
 
 
-def test_msm_window_loop_multiblock():
-    """Per-block accumulators across TWO blocks: each block runs its
-    own doubling chain; the block sums must still equal the global
-    accumulator (the linearity argument's cross-block half)."""
-    nwin = 3
-    rng = np.random.default_rng(11)
-    tab = dev._table17(_points(W))
-    mags = jnp.asarray(rng.integers(0, 17, (nwin, W), dtype=np.int32))
-    negs = jnp.asarray(rng.integers(0, 2, (nwin, W)) != 0)
-
-    want = dev._msm_scan(tab, mags, negs)
-    partials = pm.msm_window_loop(tab, mags, negs, interpret=True, blk=8)
-    assert partials.shape[-1] == 2 * pm._out_lanes(8)
-    got = dev._tree_reduce(jnp.asarray(partials), 1)
-    assert _pt_eq(want, got)
+@pytest.fixture(scope="module")
+def scan_case():
+    """One table, one digit tensor and the XLA scan's point over them
+    for the cases below: the reference compiles once."""
+    tab, mags, negs = _msm_case(W, 3, seed=13)
+    return tab, mags, negs, dev._msm_scan_xla(tab, mags, negs)
 
 
-def _xla_epilogue_verdict(pa, pr):
+@pytest.mark.parametrize("blk, zero", [
+    (W, False),     # blk == W: one block, init and close coincide
+    (8, False),     # two blocks: the wacc accumulation across i
+    (W, True),      # pads: the first case's compile, reused
+], ids=["one_block", "two_blocks", "zero_digits"])
+def test_msm_window_major_matches_scan(scan_case, blk, zero):
+    """The window-major kernel (blocks inner, ONE global accumulator,
+    doublings once per window) equals the XLA shared-doubling scan over
+    the same digits; all-zero digits select the identity row in every
+    window and sum to the identity (the pad-slot case)."""
+    tab, mags, negs, want = scan_case
+    if zero:
+        mags, negs = jnp.zeros_like(mags), jnp.zeros_like(negs)
+    got = pm.msm_window_major(tab, mags, negs, interpret=True, blk=blk)
+    assert got.shape[-1] == pm._out_lanes(blk)
+    total = dev._tree_reduce(jnp.asarray(got), 1)
+    if zero:
+        assert bool(dev.point_is_identity(total)[0])
+    else:
+        assert _pt_eq(want, total)
+
+
+def _xla_epilogue(pa, pr):
     """The XLA reference of the fold kernel: reduce, combine, cofactor
     8, identity."""
     total = dev.point_add(dev._tree_reduce(pa, 1), dev._tree_reduce(pr, 1))
     for _ in range(3):
         total = dev.point_double(total, with_t=False)
-    return bool(dev.point_is_identity(total)[0])
+    return dev.point_is_identity(total)[0]
+
+
+def _xla_epilogue_verdict(pa, pr):
+    return bool(_xla_epilogue(pa, pr))
 
 
 @pytest.mark.slow
@@ -176,100 +148,14 @@ def test_fold_verify_chunk_sum_width():
     assert bool(pm.fold_verify(pa, pr, interpret=True, tile=4)) is True
 
 
-def test_rlc_dispatches_fold_verify(monkeypatch):
-    """With USE_PALLAS_FOLD on, the RLC verdict routes through
-    fold_verify with both sides' partial tensors, and accept/tampered-
-    reject hold around the seam."""
-    import cometbft_tpu.ops.pallas_msm as pmod
-
-    fold_calls, msm_calls = [], []
-
-    def msm_spy(tab, mags, negs, interpret=False, blk=None):
-        msm_calls.append(tab.shape)
-        monkeypatch.setattr(dev, "USE_PALLAS_MSM_LOOP", False)
-        try:
-            return dev._msm_scan(tab, mags, negs)    # (4, 20, 1) partial
-        finally:
-            monkeypatch.setattr(dev, "USE_PALLAS_MSM_LOOP", True)
-
-    def fold_spy(pa, pr, interpret=False):
-        fold_calls.append((pa.shape, pr.shape))
-        ta = dev._tree_reduce(pa, 1)
-        tr = dev._tree_reduce(pr, 1)
-        total = dev.point_add(ta, tr)
-        for _ in range(3):
-            total = dev.point_double(total, with_t=False)
-        return dev.point_is_identity(total)[0]
-
-    monkeypatch.setattr(dev, "_pallas_capable", lambda: True)
-    monkeypatch.setattr(pmod, "msm_window_loop", msm_spy)
-    monkeypatch.setattr(pmod, "fold_verify", fold_spy)
-    monkeypatch.setattr(pmod, "BLK", 8)
-    monkeypatch.setattr(dev, "USE_PALLAS_MSM_LOOP", True)
-    monkeypatch.setattr(dev, "USE_PALLAS_MSM_MAJOR", False)
-    monkeypatch.setattr(dev, "USE_PALLAS_FOLD", True)
-    monkeypatch.setattr(dev, "USE_PALLAS_TABLE", False)
-    monkeypatch.setattr(dev, "USE_PALLAS_DECOMPRESS", False)
-
-    good, bad = _rlc_verdicts(tamper_idx=3)
-    assert good and not bad
-    assert fold_calls                     # epilogue went through the seam
-    assert len(msm_calls) >= 2            # both MSM sides produced partials
-
-
-@pytest.mark.slow
-def test_msm_window_major_matches_scan():
-    """The window-major kernel (blocks inner, ONE global accumulator,
-    doublings once per window) equals the XLA shared-doubling scan —
-    single block (init/close coincide) and multiblock (the wacc
-    scratch accumulation across i)."""
-    nwin = 4
-    rng = np.random.default_rng(13)
-    tab = dev._table17(_points(W))
-    mags = jnp.asarray(rng.integers(0, 17, (nwin, W), dtype=np.int32))
-    negs = jnp.asarray(rng.integers(0, 2, (nwin, W)) != 0)
-    want = dev._msm_scan(tab, mags, negs)
-
-    got1 = pm.msm_window_major(tab, mags, negs, interpret=True, blk=W)
-    assert got1.shape[-1] == pm._out_lanes(W)
-    assert _pt_eq(want, dev._tree_reduce(jnp.asarray(got1), 1))
-
-    got2 = pm.msm_window_major(tab, mags, negs, interpret=True, blk=8)
-    assert got2.shape[-1] == pm._out_lanes(8)
-    assert _pt_eq(want, dev._tree_reduce(jnp.asarray(got2), 1))
-
-
 def test_msm_scan_dispatches_window_major(monkeypatch):
-    """USE_PALLAS_MSM_MAJOR routes _msm_scan through msm_window_major
-    and takes precedence over the window-loop kernel."""
-    import cometbft_tpu.ops.pallas_msm as pmod
-
-    calls = []
-
-    def spy(tab, mags, negs, interpret=False, blk=None):
-        calls.append((tab.shape, blk))
-        monkeypatch.setattr(dev, "USE_PALLAS_MSM_MAJOR", False)
-        monkeypatch.setattr(dev, "USE_PALLAS_MSM_LOOP", False)
-        try:
-            return dev._msm_scan(tab, mags, negs)
-        finally:
-            monkeypatch.setattr(dev, "USE_PALLAS_MSM_MAJOR", True)
-            monkeypatch.setattr(dev, "USE_PALLAS_MSM_LOOP", True)
-
-    nwin = 3
-    rng = np.random.default_rng(4)
-    tab = dev._table17(_points(W))
-    mags = jnp.asarray(rng.integers(0, 17, (nwin, W), dtype=np.int32))
-    negs = jnp.asarray(rng.integers(0, 2, (nwin, W)) != 0)
-    want = dev._msm_scan(tab, mags, negs)
-
-    monkeypatch.setattr(dev, "_pallas_capable", lambda: True)
-    monkeypatch.setattr(pmod, "msm_window_major", spy)
-    monkeypatch.setattr(pmod, "BLK", 8)
-    monkeypatch.setattr(dev, "USE_PALLAS_MSM_MAJOR", True)
-    monkeypatch.setattr(dev, "USE_PALLAS_MSM_LOOP", True)
-    got = dev._msm_scan(tab, mags, negs)
-    assert calls == [((17, 4, 20, W), 8)]
+    """Where _pallas_blk gives the width a block, one MSM side
+    (_msm_side) is msm_window_major at that block."""
+    calls = _spy_kernels(monkeypatch, blk=8)
+    tab, mags, negs = _msm_case(W, 3, seed=4)
+    want = dev._msm_scan_xla(tab, mags, negs)
+    got = dev._tree_reduce(dev._msm_side(tab, mags, negs), 1)
+    assert calls["msm"] == [((17, 4, 20, W), (3, W), 8)]
     assert _pt_eq(want, got)
 
 
@@ -322,117 +208,126 @@ def _sign_batch(n):
     return pks, msgs, sigs
 
 
-def _rlc_verdicts(tamper_idx):
-    """Pack an 8-sig batch, run rlc_verify_kernel jitted, return
-    (clean verdict, tampered verdict).  The pjit executable cache is
-    keyed on the underlying function + shapes, so an executable traced
-    by a PREVIOUS dispatch test (same 8-sig shapes, different
-    monkeypatched spies/flags) would silently win — clear it."""
-    from cometbft_tpu.crypto import ed25519 as ed
-
-    jax.clear_caches()
-    pks, msgs, sigs = _sign_batch(8)
-    fn = jax.jit(dev.rlc_verify_kernel)
-    good = bool(np.asarray(fn(*ed.pack_rlc(pks, msgs, sigs))))
-    i = tamper_idx
-    sigs[i] = sigs[i][:20] + bytes([sigs[i][20] ^ 1]) + sigs[i][21:]
-    bad = bool(np.asarray(fn(*ed.pack_rlc(pks, msgs, sigs))))
-    return good, bad
-
-
-def test_rlc_dispatches_pallas_kernels(monkeypatch):
-    """With USE_PALLAS_MSM_LOOP and USE_PALLAS_DECOMPRESS on and widths
-    divisible by BLK, BOTH MSM sides route through msm_window_loop and
-    both decompressions through the fused kernel, and the verdict
-    plumbing (accept + tampered reject) holds around the kernel seams.
-    One jitted program covers both flags: a separate test per flag
-    costs an extra ~3 min RLC compile for no additional coverage."""
+def _spy_kernels(monkeypatch, blk):
+    """The chip's choice of kernel at block `blk`, with every kernel
+    entry a spy that records its call and returns the XLA path's value.
+    Returns the call log, one list a kernel."""
     import cometbft_tpu.ops.pallas_decompress as pdmod
-    import cometbft_tpu.ops.pallas_msm as pmod
 
-    msm_calls, dec_calls = [], []
-
-    def msm_spy(tab, mags, negs, interpret=False, blk=None):
-        msm_calls.append((tab.shape, mags.shape))
-        # XLA-branch value, computed by flipping the flag for the
-        # duration of this trace-time call
-        monkeypatch.setattr(dev, "USE_PALLAS_MSM_LOOP", False)
-        try:
-            return dev._msm_scan(tab, mags, negs)    # (4, 20, 1)
-        finally:
-            monkeypatch.setattr(dev, "USE_PALLAS_MSM_LOOP", True)
+    calls = {"decompress": [], "tables": [], "msm": [], "fold": []}
 
     def dec_spy(enc_words, interpret=False, blk=None):
-        dec_calls.append(enc_words.shape)
-        monkeypatch.setattr(dev, "USE_PALLAS_DECOMPRESS", False)
-        try:
-            return dev.decompress(enc_words)
-        finally:
-            monkeypatch.setattr(dev, "USE_PALLAS_DECOMPRESS", True)
-
-    tab_calls = []
+        calls["decompress"].append(enc_words.shape)
+        return dev._decompress_xla(enc_words)
 
     def tab_spy(pt, interpret=False, blk=None):
-        tab_calls.append(pt.shape)
+        calls["tables"].append(pt.shape)
         return dev._table17(dev.point_neg(pt))
 
-    monkeypatch.setattr(dev, "_pallas_capable", lambda: True)
-    monkeypatch.setattr(pmod, "msm_window_loop", msm_spy)
-    monkeypatch.setattr(pmod, "table17_neg", tab_spy)
-    monkeypatch.setattr(pmod, "BLK", 8)
-    monkeypatch.setattr(dev, "USE_PALLAS_MSM_LOOP", True)
-    # window-major and the fold epilogue (defaults ON since r4b)
-    # supersede the scan path this test exercises; the fold has its
-    # own dispatch test below
-    monkeypatch.setattr(dev, "USE_PALLAS_MSM_MAJOR", False)
-    monkeypatch.setattr(dev, "USE_PALLAS_FOLD", False)
-    monkeypatch.setattr(dev, "USE_PALLAS_TABLE", True)
-    monkeypatch.setattr(pdmod, "decompress", dec_spy)
-    monkeypatch.setattr(pdmod, "BLK", 8)
-    monkeypatch.setattr(dev, "USE_PALLAS_DECOMPRESS", True)
+    def msm_spy(tab, mags, negs, interpret=False, blk=None):
+        calls["msm"].append((tab.shape, mags.shape, blk))
+        return dev._msm_scan_xla(tab, mags, negs)    # (4, 20, 1) partial
 
-    good, bad = _rlc_verdicts(tamper_idx=5)
+    def fold_spy(pa, pr, interpret=False):
+        calls["fold"].append((pa.shape, pr.shape))
+        return _xla_epilogue(pa, pr)
+
+    monkeypatch.setattr(dev, "_pallas_capable", lambda: True)
+    monkeypatch.setattr(pm, "BLK", blk)
+    monkeypatch.setattr(pdmod, "BLK", blk)
+    monkeypatch.setattr(pdmod, "decompress", dec_spy)
+    monkeypatch.setattr(pm, "table17_neg", tab_spy)
+    monkeypatch.setattr(pm, "msm_window_major", msm_spy)
+    monkeypatch.setattr(pm, "fold_verify", fold_spy)
+    return calls
+
+
+def _rlc_run(blk, tamper_idx):
+    """Pack an 8-sig batch at the widths the CPU packs (A 16, R 8) and
+    run rlc_verify_kernel jitted on it, clean and tampered, with the
+    chip's choice of kernel at block `blk` spied: (clean verdict,
+    tampered verdict, call log).  The pjit executable cache is keyed on
+    the underlying function + shapes, so an executable traced by a
+    PREVIOUS run (same shapes, another block) would silently win —
+    clear it."""
+    from cometbft_tpu.crypto import ed25519 as ed
+
+    pks, msgs, sigs = _sign_batch(8)
+    good_args = ed.pack_rlc(pks, msgs, sigs)
+    i = tamper_idx
+    sigs[i] = sigs[i][:20] + bytes([sigs[i][20] ^ 1]) + sigs[i][21:]
+    bad_args = ed.pack_rlc(pks, msgs, sigs)
+    with pytest.MonkeyPatch.context() as mp:
+        calls = _spy_kernels(mp, blk)
+        jax.clear_caches()
+        fn = jax.jit(dev.rlc_verify_kernel)
+        good = bool(np.asarray(fn(*good_args)))
+        bad = bool(np.asarray(fn(*bad_args)))
+    jax.clear_caches()
+    return good, bad, calls
+
+
+@pytest.fixture(scope="module")
+def all_pallas_run():
+    """One trace and compile for the two tests that read it: block 8
+    divides both widths."""
+    return _rlc_run(blk=8, tamper_idx=3)
+
+
+def test_rlc_dispatches_fold_verify(all_pallas_run):
+    """Both sides with a block: the RLC verdict routes through
+    fold_verify with both sides' accumulators, and accept/tampered-
+    reject hold around the seam."""
+    good, bad, calls = all_pallas_run
+    assert good and not bad
+    # one accumulator a side, as the msm spy handed it over
+    assert calls["fold"] == [((4, 20, 1), (4, 20, 1))]
+    assert len(calls["msm"]) == 2
+
+
+def test_rlc_dispatches_pallas_kernels(all_pallas_run):
+    """At widths a block divides, BOTH MSM sides route through
+    msm_window_major, both decompressions and both table builds through
+    their kernels, and the verdict plumbing (accept + tampered reject)
+    holds around the kernel seams."""
+    good, bad, calls = all_pallas_run
     assert good and not bad
     # A side (52 windows, width 16) and R side (26 windows, width 8)
-    assert ((17, 4, 20, 16), (52, 16)) in msm_calls
-    assert ((17, 4, 20, 8), (26, 8)) in msm_calls
-    assert (8, 16) in dec_calls and (8, 8) in dec_calls
-    assert (4, 20, 16) in tab_calls and (4, 20, 8) in tab_calls
+    assert calls["msm"] == [((17, 4, 20, 16), (52, 16), 8),
+                            ((17, 4, 20, 8), (26, 8), 8)]
+    assert calls["decompress"] == [(8, 16), (8, 8)]
+    assert calls["tables"] == [(4, 20, 16), (4, 20, 8)]
 
 
-def test_msm_scan_dispatches_select_tree(monkeypatch):
-    """USE_PALLAS_TREE routes every window's contribution through
-    select_tree with the partial-count contract intact.  Driven at the
-    _msm_scan seam (eager, no fresh RLC compile) — the RLC plumbing
-    above is flag-independent."""
-    import cometbft_tpu.ops.pallas_msm as pmod
+def test_rlc_mixed_program_takes_xla_epilogue():
+    """One side with a block, one without (block 16: the A side's 16
+    lanes have it, the R side's 8 do not): the blocked side alone runs
+    the kernels, the other the XLA code, and the verdict comes from the
+    shared epilogue's XLA branch — fold_verify is never entered — for
+    a good batch and for a tampered one."""
+    good, bad, calls = _rlc_run(blk=16, tamper_idx=5)
+    assert good and not bad
+    assert calls["msm"] == [((17, 4, 20, 16), (52, 16), 16)]
+    assert calls["decompress"] == [(8, 16)]
+    assert calls["tables"] == [(4, 20, 16)]
+    assert calls["fold"] == []
 
-    calls = []
 
-    def spy(tab, mag, neg, interpret=False, blk=None):
-        calls.append(tab.shape)
-        npart = (tab.shape[-1] // 8) * pmod._out_lanes(8)
-        contrib = dev._cond_neg_point(dev._select17(tab, mag), neg)
-        return dev._tree_reduce(contrib, npart)
-
-    nwin = 3
-    rng = np.random.default_rng(2)
-    tab = dev._table17(_points(W))
-    mags = jnp.asarray(rng.integers(0, 17, (nwin, W), dtype=np.int32))
-    negs = jnp.asarray(rng.integers(0, 2, (nwin, W)) != 0)
-    want = dev._msm_scan(tab, mags, negs)
-
+@pytest.mark.parametrize("k, n", [(64, 64), (192, 6144), (6144, 192)])
+def test_rlc_kernel_plan_blockless_side(monkeypatch, k, n):
+    """rlc_kernel_plan stage by stage on programs with a side no block
+    divides: that side reads xla in every stage, the other pallas, and
+    the fold xla."""
     monkeypatch.setattr(dev, "_pallas_capable", lambda: True)
-    monkeypatch.setattr(pmod, "select_tree", spy)
-    monkeypatch.setattr(pmod, "BLK", 8)
-    monkeypatch.setattr(dev, "USE_PALLAS_TREE", True)
-    monkeypatch.setattr(dev, "USE_PALLAS_MSM_LOOP", False)
-    monkeypatch.setattr(dev, "USE_PALLAS_MSM_MAJOR", False)
-    got = dev._msm_scan(tab, mags, negs)
-    # the window body is TRACED once inside lax.scan and reused for
-    # every window; one recorded call proves the routing
-    assert calls == [(17, 4, 20, W)]
-    assert _pt_eq(want, got)
+    plan = dev.rlc_kernel_plan(k, n)
+    for side, w in (("a", k), ("r", n)):
+        want = "pallas" if w % 128 == 0 else "xla"
+        assert plan[side]["width"] == w
+        assert (plan[side]["blk"] is None) == (want == "xla")
+        assert [plan[side][st] for st in ("decompress", "tables", "msm")] \
+            == [want] * 3, (side, plan)
+    assert plan["fold"] == "xla"
+    assert dev.rlc_kernel_name(k, n) == "xla"
 
 
 @pytest.mark.slow
@@ -455,30 +350,19 @@ def test_pallas_table17_neg_matches_xla():
 
 
 def test_msm_tables_dispatches_pallas_table(monkeypatch):
-    """USE_PALLAS_TABLE routes _msm_tables through table17_neg."""
-    import cometbft_tpu.ops.pallas_msm as pmod
-
-    calls = []
-
-    def spy(pt, interpret=False, blk=None):
-        calls.append(pt.shape)
-        return dev._table17(dev.point_neg(pt))
-
-    monkeypatch.setattr(dev, "_pallas_capable", lambda: True)
-    monkeypatch.setattr(pmod, "table17_neg", spy)
-    monkeypatch.setattr(pmod, "BLK", 8)
-    monkeypatch.setattr(dev, "USE_PALLAS_TABLE", True)
-    monkeypatch.setattr(dev, "USE_PALLAS_DECOMPRESS", False)
-
+    """Where _pallas_blk gives the width a block, _msm_tables is the
+    decompress kernel and table17_neg."""
+    calls = _spy_kernels(monkeypatch, blk=8)
     pks, _, _ = _sign_batch(8)
     words = np.stack([np.frombuffer(pk, dtype="<u4") for pk in pks],
                      axis=1)                        # (8, 8) LE words
     tab, ok = dev._msm_tables(jnp.asarray(words))
-    assert calls and calls[0] == (4, 20, 8)
+    assert calls["tables"] == [(4, 20, 8)]
+    assert calls["decompress"] == [(8, 8)]
     assert bool(ok)
 
 
-# -- r4 advisor regressions ------------------------------------------------
+# -- blocks and widths ------------------------------------------------------
 
 def test_blk_for_non_pow2_override(monkeypatch):
     """A non-pow2 BLK override (e.g. 384) must still find the pow2
@@ -511,36 +395,3 @@ def test_prefold_odd_tile_width(monkeypatch):
     got = dev._prefold(pts)
     assert got.shape[-1] == 256
     assert _pt_eq(want, dev._tree_reduce(got, 1))
-
-
-def test_group_for_divisor_degradation():
-    """Requested window groups degrade to the largest divisor of the
-    side's window count (52-window A sides vs 26-window R sides)."""
-    assert pm.group_for(6, 4) == 3
-    assert pm.group_for(52, 8) == 4
-    assert pm.group_for(52, 16) == 13
-    assert pm.group_for(26, 16) == 13
-    assert pm.group_for(26, 4) == 2
-    assert pm.group_for(7, 4) == 1      # prime: grouped == ungrouped
-
-
-@pytest.mark.slow
-def test_msm_window_major_grouped_matches_scan():
-    """The grouped window-major kernel (G windows per table fetch, per-
-    window VMEM scratch accumulators, fori_loop group-close doubling
-    chain) equals the XLA shared-doubling scan.  Slow tier: each
-    interpret compile is ~3.5 min on one core.  Combos cover multiblock wacc
-    accumulation (blk 8), divisor degradation (4 -> 3), the jg != 0
-    later-group close, single-block grids, and group == nwin."""
-    nwin = 6
-    rng = np.random.default_rng(29)
-    tab = dev._table17(_points(W))
-    mags = jnp.asarray(rng.integers(0, 17, (nwin, W), dtype=np.int32))
-    negs = jnp.asarray(rng.integers(0, 2, (nwin, W)) != 0)
-    want = dev._msm_scan(tab, mags, negs)
-    for blk, grp in ((8, 4), (W, 2), (8, 6)):
-        got = pm.msm_window_major(tab, mags, negs, interpret=True,
-                                  blk=blk, group=grp)
-        assert got.shape[-1] == pm._out_lanes(blk), (blk, grp)
-        assert _pt_eq(want, dev._tree_reduce(jnp.asarray(got), 1)), \
-            (blk, grp)

@@ -484,12 +484,21 @@ class TestPipelineQos:
         sigcache.reset()
         from cometbft_tpu.crypto import devhealth
 
-        gate = threading.Event()
+        entered, gate = threading.Event(), threading.Event()
 
         def blocked_dispatch(win):
-            gate.wait(20)
+            entered.set()
+            gate.wait(60)
             v = serial_verdicts(win.items)
             return all(v) and bool(v), v
+
+        def await_waiter(pipe, prio):
+            # submit() registers a waiter and waits under pipe._cv
+            with pipe._cv:
+                deadline = time.monotonic() + 60
+                while prio not in pipe._bo_waiters:
+                    assert time.monotonic() < deadline, pipe._bo_waiters
+                    pipe._cv.wait(timeout=0.05)
 
         health = devhealth.HealthRegistry(quarantine_after=1,
                                           probe_backoff_s=60.0)
@@ -503,11 +512,14 @@ class TestPipelineQos:
                 orig(win, label)
 
             pipe._sched.note_enqueue = spy
-            # wedge the device loop inside a dispatch, then queue one
-            # more window so the queue sits at BROWNOUT_DEPTH
+            # wedge the device loop inside a dispatch (and see it
+            # there: a window the loop reaches after the quarantine
+            # resolves on the host and the queue drains), then queue
+            # one more window so the queue sits at BROWNOUT_DEPTH
             first = pipe.submit(make_items(2, seed=1),
                                 subsystem="blocksync",
                                 device_threshold=1)
+            assert entered.wait(60)
             second = pipe.submit(make_items(2, seed=2),
                                  subsystem="blocksync",
                                  device_threshold=1)
@@ -525,18 +537,11 @@ class TestPipelineQos:
             low = threading.Thread(target=submit_lane,
                                    args=("crypto", 3), daemon=True)
             low.start()
-            deadline = time.monotonic() + 5
-            while 4 not in pipe._bo_waiters and \
-                    time.monotonic() < deadline:
-                time.sleep(0.005)
-            assert 4 in pipe._bo_waiters
+            await_waiter(pipe, 4)
             high = threading.Thread(target=submit_lane,
                                     args=("consensus", 4), daemon=True)
             high.start()
-            while 0 not in pipe._bo_waiters and \
-                    time.monotonic() < deadline:
-                time.sleep(0.005)
-            assert 0 in pipe._bo_waiters
+            await_waiter(pipe, 0)
             # free the wedged dispatch; the queue drains and admission
             # order decides who lands first
             gate.set()

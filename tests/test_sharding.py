@@ -85,10 +85,10 @@ def test_sharded_pallas_msm_interpret():
     nwin = 4
     mags = jnp.asarray(rng.integers(0, 17, (nwin, w), dtype=np.int32))
     negs = jnp.asarray(rng.integers(0, 2, (nwin, w)) != 0)
-    want = dev._msm_scan(tab, mags, negs)
+    want = dev._msm_scan_xla(tab, mags, negs)
     got = msm_shard.sharded_msm(tab, mags, negs,
                                 mesh=sharding._mesh(),
-                                interpret=True, blk=4, group=1)
+                                interpret=True, blk=4)
     x_eq = np.asarray(fe.freeze(fe.mul(got[0], want[2]))) \
         == np.asarray(fe.freeze(fe.mul(want[0], got[2])))
     y_eq = np.asarray(fe.freeze(fe.mul(got[1], want[2]))) \
