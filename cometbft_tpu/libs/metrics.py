@@ -432,6 +432,19 @@ class StateMetrics:
             "Duration of accesses to the state store, by method.",
             labels=("method",),
             buckets=BUCKET_SCHEMES["store_access"])
+        # StateStore.load_validators (state/store.py), counted by the
+        # store itself through its `metrics` attribute
+        self.validators_loads = reg.counter(
+            "state", "validators_loads_total",
+            "Validator sets loaded from the state store, by how the "
+            "answer was reached: stored (a full record), resumed (a "
+            "pointer record caught up from a height already answered) "
+            "or restarted (caught up from the full record).",
+            labels=("path",))
+        self.validators_catchup_rounds = reg.counter(
+            "state", "validators_catchup_rounds_total",
+            "Proposer-priority rounds run to catch loaded validator "
+            "sets up to the height asked for.")
 
 
 class StoreMetrics:

@@ -409,6 +409,7 @@ class Node(BaseService):
             self.state_metrics = StateMetrics(registry)
             self.block_exec.metrics = self.state_metrics
             self.pruner.metrics = self.state_metrics
+            self.state_store.metrics = self.state_metrics
             self.blocksync_reactor.metrics = BlockSyncMetrics(registry)
             self.statesync_metrics = StateSyncMetrics(registry)
             self.statesync_metrics.syncing.set(

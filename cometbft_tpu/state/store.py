@@ -15,16 +15,23 @@ Key layout (fixed-width big-endian heights, ordered for range prunes):
 
 from __future__ import annotations
 
-
+from collections import OrderedDict
 
 from ..libs import lockrank
 from ..libs import protowire as pw
+from ..libs import trace
 from ..store.kv import KVStore, be64
 from ..types.params import ConsensusParams
 from ..types.validator_set import ValidatorSet
 from .state import State
 
 VALSET_CHECKPOINT_INTERVAL = 100_000  # state/store.go valSetCheckpointInterval
+
+# load_validators keeps the decoded sets of the records it was last
+# asked for: this many full records, by recency of use, and for each this
+# many resume points (the set as it stood at a height already answered)
+_KEPT_RECORDS = 4
+_KEPT_RESUME_POINTS = 4
 
 _K_STATE = b"stateKey"
 
@@ -62,10 +69,30 @@ def _info_parse(raw: bytes) -> tuple[int, bytes | None]:
     return lhc, payload
 
 
+class _DecodedRecord:
+    """A full validators record as load_validators last decoded it:
+    `raw` the stored bytes it was decoded from (an answer is served from
+    here only while the store still holds exactly these), `vals` the set
+    as stored, `points` {height: the set caught up to that height},
+    least recently used first."""
+
+    __slots__ = ("raw", "vals", "points")
+
+    def __init__(self, raw: bytes, vals: ValidatorSet):
+        self.raw = raw
+        self.vals = vals
+        self.points: OrderedDict[int, ValidatorSet] = OrderedDict()
+
+
 class StateStore:
     def __init__(self, db: KVStore):
         self._db = db
         self._mtx = lockrank.RankedRLock("state.store")
+        # {height of a full record: _DecodedRecord}, least recently used
+        # first; under _mtx
+        self._decoded: OrderedDict[int, _DecodedRecord] = OrderedDict()
+        # StateMetrics once a node has instrumentation (node/node.py)
+        self.metrics = None
 
     # -- State -------------------------------------------------------------
 
@@ -133,25 +160,72 @@ class StateStore:
                      _info_bytes(last_height_changed, payload)))
 
     def load_validators(self, height: int) -> ValidatorSet:
-        """LoadValidators with pointer chase (store.go:822-870)."""
-        raw = self._db.get(_k_vals(height))
-        if raw is None:
-            raise KeyError(f"no validator set for height {height}")
-        lhc, payload = _info_parse(raw)
-        if payload is None:
-            raw2 = self._db.get(_k_vals(lhc))
-            if raw2 is None:
-                raise KeyError(
-                    f"validators pointer at {height} -> {lhc} dangling")
-            _, payload = _info_parse(raw2)
-            if payload is None:
-                raise KeyError(
-                    f"validator checkpoint at {lhc} is itself empty")
-            vals = ValidatorSet.from_proto(payload)
-            # catch the priorities up to `height` like the reference does
-            vals.increment_proposer_priority(height - lhc)
-            return vals
-        return ValidatorSet.from_proto(payload)
+        """LoadValidators with pointer chase (store.go:822-870).  The
+        reference catches a pointer record's priorities up from the full
+        record it points to, one round a height since the set last
+        changed, at every call; here the catch-up resumes from the
+        nearest height at or below `height` that was already answered.
+        The caller gets a set nothing else references."""
+        with trace.span("state", "load_validators") as sp:
+            raw = self._db.get(_k_vals(height))
+            if raw is None:
+                raise KeyError(f"no validator set for height {height}")
+            lhc, payload = _info_parse(raw)
+            if payload is not None:
+                lhc = height
+            else:
+                raw = self._db.get(_k_vals(lhc))
+                if raw is None:
+                    raise KeyError(
+                        f"validators pointer at {height} -> {lhc} dangling")
+                _, payload = _info_parse(raw)
+                if payload is None:
+                    raise KeyError(
+                        f"validator checkpoint at {lhc} is itself empty")
+            vals, path, rounds = self._caught_up(lhc, raw, payload, height)
+            sp.note(path=path, rounds=rounds)
+        m = self.metrics
+        if m is not None:
+            m.validators_loads.labels(path).inc()
+            if rounds:
+                m.validators_catchup_rounds.inc(rounds)
+        return vals
+
+    def _caught_up(self, lhc: int, raw: bytes, payload: bytes,
+                   height: int) -> tuple[ValidatorSet, str, int]:
+        """A private copy of the full record at `lhc` (stored bytes
+        `raw`, set `payload`) caught up to `height`, how it was reached
+        and the rounds that took.  The result is
+        from_proto(payload).increment_proposer_priority(height - lhc),
+        operation for operation: a resume point has the rescale, the
+        shift and its rounds behind it, so only the further rounds run."""
+        with self._mtx:
+            rec = self._decoded.get(lhc)
+            if rec is None or rec.raw != raw:
+                # never decoded, or the store holds another record there
+                # now (bootstrap, rollback, a prune and a regrowth)
+                rec = self._decoded[lhc] = _DecodedRecord(
+                    raw, ValidatorSet.from_proto(payload))
+                if len(self._decoded) > _KEPT_RECORDS:
+                    self._decoded.popitem(last=False)
+            self._decoded.move_to_end(lhc)
+            if height == lhc:
+                return rec.vals.copy(), "stored", 0
+            at = max((h for h in rec.points if h <= height), default=None)
+            if at is None:
+                vals = rec.vals.copy()
+                vals.increment_proposer_priority(height - lhc)
+                path, rounds = "restarted", height - lhc
+                if len(rec.points) >= _KEPT_RESUME_POINTS:
+                    rec.points.popitem(last=False)
+            else:
+                vals = rec.points.pop(at)
+                path, rounds = "resumed", height - at
+                for _ in range(rounds):
+                    vals.proposer = vals._increment_proposer_priority()
+            # the point moves up to `height`, and is the most recent
+            rec.points[height] = vals
+            return vals.copy(), path, rounds
 
     # -- consensus params --------------------------------------------------
 

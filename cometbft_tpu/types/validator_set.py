@@ -141,7 +141,18 @@ class ValidatorSet:
     def copy(self) -> "ValidatorSet":
         out = ValidatorSet()
         out.validators = [v.copy() for v in self.validators]
-        out.proposer = self.proposer
+        # the copy's proposer is the copy's own validator, so that rounds
+        # run on the original later leave it alone (a decoded set's
+        # proposer is a validator apart from the list: copied apart)
+        p = self.proposer
+        if p is not None:
+            for mine, theirs in zip(out.validators, self.validators):
+                if theirs is p:
+                    p = mine
+                    break
+            else:
+                p = p.copy()
+        out.proposer = p
         out._total_voting_power = self._total_voting_power
         out._addr_index = None
         return out

@@ -18,10 +18,12 @@ import pytest
 import yaml
 
 from cometbft_tpu.config import test_config as _tcfg
+from cometbft_tpu.libs.metrics import Registry, StateMetrics
 from cometbft_tpu.node import Node, init_files
 from cometbft_tpu.rpc.core import PRIVILEGED_ROUTES, ROUTES
 
 from tests.test_consensus import wait_for_height
+from tests.test_valset_resume import _afresh
 
 SPEC_PATH = os.path.join(os.path.dirname(__file__), os.pardir,
                          "cometbft_tpu", "rpc", "openapi.yaml")
@@ -240,3 +242,102 @@ def test_validator_rejects_drift():
             validate(bad,
                      {"$ref": "#/components/schemas/NumUnconfirmedTxsResult"},
                      spec)
+
+
+# -- /validators over a 175-validator chain: the answer and its cost ------------
+
+QA_VALS, QA_TOP = 175, 14     # blocks grown; a source serves heights below
+
+
+@pytest.fixture(scope="module")
+def qa_source():
+    """A source node holding QA_TOP blocks signed by 175 equal-power
+    validators whose set changes at no height (every validators record
+    above height 1 is a pointer to it), behind the real RPC server."""
+    from benchmark import fixture
+
+    chain = fixture.build_chain(
+        {"validators": QA_VALS, "power": 10, "chain_blocks": QA_TOP - 1,
+         "txs_per_block": 1, "tx_bytes": 32, "chain_id": "qa-contract"},
+        seed=2 ** 31 + 30)
+    chain.src.state_store.metrics = StateMetrics(Registry("t"))
+    addr = chain.src.start_rpc()
+    yield chain, addr
+    chain.src.stop()
+
+
+@pytest.mark.parametrize("height", [3, QA_TOP - 1])
+@pytest.mark.parametrize("page", [1, 2])
+def test_validators_pages_are_the_fresh_catch_up(qa_source, height, page):
+    from cometbft_tpu.rpc import serialize as ser
+
+    chain, addr = qa_source
+    # the set as load_validators built it before it kept anything
+    vals = _afresh(chain.src.state_store._db, height).validators
+    sel = vals[(page - 1) * 100:page * 100]
+    want = {"block_height": str(height),
+            "validators": [ser.validator_json(v) for v in sel],
+            "count": str(len(sel)), "total": str(QA_VALS)}
+    assert len(sel) == (100, 75)[page - 1]
+    # twice: the second answer is served from where the first one stood
+    for _ in (0, 1):
+        got = _get(addr, "validators",
+                   {"height": height, "page": page, "per_page": 100})
+        assert json.dumps(got["result"], sort_keys=True) == \
+            json.dumps(want, sort_keys=True)
+
+
+def test_a_light_sync_costs_one_round_a_height(qa_source, monkeypatch):
+    """A fresh light client over HttpProvider asks for the target first
+    and then for the heights from its trust root up, two /validators
+    pages each: the source restarts the catch-up once, for the target,
+    and runs one round a height after it (from the full record at every
+    request it would run about n squared); a second client runs no more
+    than one round a height."""
+    from cometbft_tpu.crypto import batch as cb
+    from cometbft_tpu.light.client import SEQUENTIAL, Client, TrustOptions
+    from cometbft_tpu.light.provider import HttpProvider
+    from cometbft_tpu.light.store import MemoryStore
+    from cometbft_tpu.types import validation
+
+    # the client's commits are judged on the host: no device program
+    monkeypatch.setattr(cb, "DEVICE_THRESHOLD", 10 ** 9)
+    monkeypatch.setattr(validation.DeferredSigBatch, "DEVICE_THRESHOLD",
+                        10 ** 9)
+    chain, addr = qa_source
+    m = chain.src.state_store.metrics
+    target = QA_TOP - 1
+    n = target - 1
+    root = chain.src.block_store.load_block_meta(1).header.hash()
+
+    def counts():
+        return (m.validators_catchup_rounds._values.get((), 0.0),
+                m.validators_loads._values.get(("resumed",), 0.0))
+
+    def sync():
+        store = MemoryStore()
+        Client(chain.genesis.chain_id,
+               TrustOptions(period_ns=100 * 365 * 86400 * 10 ** 9,
+                            height=1, hash=root),
+               HttpProvider(chain.genesis.chain_id, f"http://{addr}"),
+               trusted_store=store, verification_mode=SEQUENTIAL,
+               sequential_batch_size=4
+               ).verify_light_block_at_height(target)
+        assert store.light_block(target).header.hash() == \
+            chain.src.block_store.load_block_meta(target).header.hash()
+
+    # forget what the tests above left: this client finds a store that
+    # was never asked
+    chain.src.state_store._decoded.clear()
+    rounds0, resumed0 = counts()
+    sync()
+    rounds1, resumed1 = counts()
+    assert 0 < rounds1 - rounds0 <= n + (target - 1)
+    # of a height's two pages the second is always resumed, and the first
+    # too from the second pointer record on; height 2 holds a full set
+    # (two loads `stored`), so 2n - 4 and not 2n - 2
+    assert resumed1 - resumed0 >= 2 * n - 4
+    sync()
+    rounds2, resumed2 = counts()
+    assert rounds2 - rounds1 <= n
+    assert resumed2 - resumed1 >= 2 * n - 4
