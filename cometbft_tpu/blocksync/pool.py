@@ -71,13 +71,18 @@ class _Requester:
 class BlockPool(BaseService):
     def __init__(self, start_height: int, send_request,
                  on_peer_error=None, peer_timeout: float | None = None,
-                 retry_jitter: float | None = None):
+                 retry_jitter: float | None = None,
+                 seed: int | None = None):
         """send_request(height, peer_id) issues a BlockRequest;
         on_peer_error(peer_id, reason) reports misbehaving peers.
         peer_timeout/retry_jitter of None defer to the module knobs
         (PEER_TIMEOUT / RETRY_JITTER) at use time, the late binding
-        the simnet tuner and tests monkeypatch."""
+        the simnet tuner and tests monkeypatch.  The pool draws which
+        peer serves a height and how long a refetch holds off from a
+        generator of its own: with a seed, two pools asked the same
+        questions draw the same answers."""
         super().__init__("BlockPool")
+        self._rng = random.Random(seed)
         self._mtx = lockrank.RankedRLock("blocksync.pool")
         self.start_height = start_height
         self.height = start_height       # next height to sync
@@ -151,7 +156,7 @@ class BlockPool(BaseService):
                 and p.num_pending < MAX_PENDING_REQUESTS_PER_PEER]
             if not candidates:
                 return False
-            peer = random.choice(candidates)
+            peer = self._rng.choice(candidates)
             req.peer_id = peer.id
             peer.num_pending += 1
             peer.arm_timeout(self._peer_timeout())
@@ -223,7 +228,7 @@ class BlockPool(BaseService):
             jitter = self._retry_jitter()
             if jitter > 0:
                 req.not_before = time.monotonic() + \
-                    random.uniform(0, jitter)
+                    self._rng.uniform(0, jitter)
 
     def _max_peer_height(self) -> int:
         with self._mtx:
@@ -299,13 +304,16 @@ class BlockPool(BaseService):
         """First block failed verification: the peers that supplied BOTH
         blocks are suspect (the second's LastCommit drove the failed
         verify) — remove them and refetch (reactor.go:560-575).
-        Returns the offending peer ids."""
+        Returns the offending peer ids the pool still had: a supplier
+        that an earlier reject already removed is not dropped twice."""
         bad: list[str] = []
         with self._mtx:
             for h in (height, height + 1):
                 req = self._requesters.get(h)
-                if req is not None and req.peer_id:
+                if req is not None and req.peer_id \
+                        and req.peer_id not in bad:
                     bad.append(req.peer_id)
+            live = [pid for pid in bad if pid in self._peers]
         for pid in bad:
             self.remove_peer(pid)
         for h in (height, height + 1):
@@ -314,7 +322,19 @@ class BlockPool(BaseService):
             if r is not None:
                 for pid in bad:
                     self._redo_request(h, pid)
-        return bad
+        return live
+
+    def blocks_present(self, heights) -> bool:
+        """True once every one of `heights` the pool still waits to
+        apply holds a block again: what a refetch is over at."""
+        with self._mtx:
+            for h in heights:
+                if h < self.height:
+                    continue
+                r = self._requesters.get(h)
+                if r is None or r.block is None:
+                    return False
+            return True
 
     def is_caught_up(self) -> bool:
         """pool.go IsCaughtUp: within one block of the best peer."""

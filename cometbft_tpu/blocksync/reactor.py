@@ -15,6 +15,7 @@ import time
 
 from ..crypto import sigcache
 from ..libs import tracetl
+from ..libs.trace import close as trace_close
 from ..libs.trace import span as trace_span
 from ..p2p.base_reactor import Envelope, Reactor
 from ..p2p.conn.connection import ChannelDescriptor
@@ -54,7 +55,8 @@ SCHED_LANE = os.environ.get(
 
 class BlocksyncReactor(Reactor):
     def __init__(self, state, block_exec, block_store, block_sync: bool,
-                 consensus_reactor=None, peer_timeout: float | None = None):
+                 consensus_reactor=None, peer_timeout: float | None = None,
+                 seed: int | None = None):
         super().__init__("BlocksyncReactor")
         self.initial_state = state
         self.state = state
@@ -63,10 +65,11 @@ class BlocksyncReactor(Reactor):
         self.block_sync = block_sync       # actively syncing?
         self.consensus_reactor = consensus_reactor
         self.peer_timeout = peer_timeout   # None -> pool.PEER_TIMEOUT
+        self.seed = seed                   # the pool's generator
         self.pool = BlockPool(
             max(self.store.height() + 1, state.initial_height),
             self._send_block_request, self._on_peer_error,
-            peer_timeout=peer_timeout)
+            peer_timeout=peer_timeout, seed=seed)
         self._stop_sync = threading.Event()
         self.synced = not block_sync
         self.metrics = None        # BlockSyncMetrics when the node meters
@@ -74,6 +77,9 @@ class BlocksyncReactor(Reactor):
         self.pipeline_depth = PIPELINE_DEPTH
         self.mesh_devices = MESH_DEVICES
         self._pipeline = None      # crypto/dispatch.VerifyPipeline
+        # the reject that is open: from a window's false verdict until
+        # the heights it named have been verified true (_reject_window)
+        self._reject: dict | None = None
 
     def get_channels(self) -> list:
         return [ChannelDescriptor(
@@ -92,6 +98,13 @@ class BlocksyncReactor(Reactor):
     def on_stop(self) -> None:
         self._stop_sync.set()
         self.pool.stop()
+        rej, self._reject = self._reject, None
+        if rej is not None:
+            # a reject the node did not live to see through is on the
+            # record all the same, with what it named
+            trace_close("blocksync", "reject", rej["t0"],
+                        height=rej["height"], peers_dropped=rej["peers"],
+                        unfinished=True)
         if self._pipeline is not None:
             self._pipeline.stop()
             self._pipeline = None
@@ -131,7 +144,8 @@ class BlocksyncReactor(Reactor):
                                   state.initial_height),
                               self._send_block_request,
                               self._on_peer_error,
-                              peer_timeout=self.peer_timeout)
+                              peer_timeout=self.peer_timeout,
+                              seed=self.seed)
         for peer in (self.switch.peers.list() if self.switch else []):
             peer.try_send(BLOCKSYNC_CHANNEL, bm.wrap(bm.StatusRequest()))
         self.pool.start()
@@ -161,6 +175,62 @@ class BlocksyncReactor(Reactor):
         peer = self.switch.peers.get(peer_id)
         if peer is not None:
             self.switch.stop_peer_for_error(peer, reason)
+
+    # -- a block that failed verification --------------------------------
+    def _drop_suppliers(self, height: int, reason: str) -> list[str]:
+        """reactor.go:560-575: the peers that supplied `height` and the
+        block above it (whose LastCommit drove the check) are dropped
+        and both heights fetched again.  Returns who was dropped."""
+        dropped = self.pool.redo_request(height)
+        for pid in dropped:
+            self._on_peer_error(pid, reason)
+        if self.metrics is not None and dropped:
+            self.metrics.peers_dropped.labels(
+                reason.replace(" ", "_")).add(len(dropped))
+        return dropped
+
+    def _reject_window(self, heights) -> None:
+        """A verdict came out false: at `heights` a commit holds a
+        signature that does not verify (or the block fails validation
+        at apply).  Both suppliers of each pair go, the pairs are
+        fetched again, and the reject stays open - span
+        blocksync.reject - until every height named has been verified
+        true; blocksync.refetch inside it ends when the pairs are back
+        in the pool."""
+        now = time.perf_counter()
+        rej = self._reject
+        if rej is None:
+            rej = self._reject = {"t0": now, "height": min(heights),
+                                  "heights": set(), "peers": 0,
+                                  "redo": set(), "redo_t0": now}
+        if not rej["redo"]:
+            rej["redo_t0"] = now
+        for h in sorted(heights):
+            rej["peers"] += len(self._drop_suppliers(
+                h, "served invalid block"))
+            rej["heights"].add(h)
+            rej["redo"].update((h, h + 1))
+        if self.metrics is not None:
+            self.metrics.windows_rejected.inc()
+
+    def _reject_progress(self, verified=()) -> None:
+        """Close what of the open reject is over: the refetch once its
+        blocks are in the pool again, the reject once `verified` (the
+        heights a window just verified true) cover what it named."""
+        rej = self._reject
+        if rej is None:
+            return
+        if rej["redo"] and self.pool.blocks_present(rej["redo"]):
+            trace_close("blocksync", "refetch", rej["redo_t0"],
+                        blocks=len(rej["redo"]))
+            if self.metrics is not None:
+                self.metrics.blocks_refetched.add(len(rej["redo"]))
+            rej["redo"] = set()
+        rej["heights"] -= set(verified)
+        if not rej["heights"] and not rej["redo"]:
+            trace_close("blocksync", "reject", rej["t0"],
+                        height=rej["height"], peers_dropped=rej["peers"])
+            self._reject = None
 
     # -- receive -----------------------------------------------------------
     def receive(self, envelope: Envelope) -> None:
@@ -244,13 +314,12 @@ class BlocksyncReactor(Reactor):
         window N+1 collects and host-packs while window N's dispatch
         is in flight on device and window N-1 applies/stores
         (_sync_pipelined); depth 1 keeps the strictly serial loop."""
+        self._reject_progress()
         if self.pipeline_depth >= 2:
             return self._sync_pipelined()
         return self._sync_serial()
 
     def _sync_serial(self) -> bool:
-        from ..types.validation import DeferredSigBatch
-
         window, after = self.pool.peek_window(VERIFY_WINDOW)
         usable = len(window) if after is not None else len(window) - 1
         if usable < 1:
@@ -262,10 +331,8 @@ class BlocksyncReactor(Reactor):
             if ext is None and self.state.consensus_params \
                     .vote_extensions_enabled(block.header.height):
                 if i == 0:
-                    for pid in self.pool.redo_request(
-                            block.header.height):
-                        self._on_peer_error(pid,
-                                            "missing extended commit")
+                    self._drop_suppliers(block.header.height,
+                                         "missing extended commit")
                     return False
                 usable = i
                 break
@@ -279,35 +346,10 @@ class BlocksyncReactor(Reactor):
             nxt = blocks[i + 1] if i + 1 < len(window) else after
             commits.append(nxt.last_commit)
 
-        # valset per window offset: exact for +0/+1; further only while
-        # headers pin the unchanged next_validators hash
-        next_hash = self.state.next_validators.hash() \
-            if self.state.next_validators else None
-        batch = DeferredSigBatch()
-        verified = 0
-        parts_ids = []
-        collecting_h = None
         try:
             with trace_span("blocksync", "verify_dispatch"):
-                for i in range(usable):
-                    block = blocks[i]
-                    collecting_h = block.header.height
-                    if i == 0:
-                        vals = self.state.validators
-                    elif block.header.validators_hash == next_hash:
-                        vals = self.state.next_validators
-                    else:
-                        break
-                    with trace_span("blocksync", "partset",
-                                    height=collecting_h):
-                        parts = PartSet.from_data(block.to_proto())
-                        bid = BlockID(block.hash(), parts.header)
-                    parts_ids.append((parts, bid))
-                    vals.verify_commit_light(
-                        self.state.chain_id, bid, block.header.height,
-                        commits[i], defer_to=batch)
-                    verified += 1
-                collecting_h = None
+                batch, parts_ids = self._collect_pairs(blocks, commits,
+                                                       usable, head=True)
             # HOT PATH: one device dispatch for the whole window.
             # Verdicts land in the process-wide sigcache, so the
             # apply-time validate_block below (and the NEXT height's
@@ -317,17 +359,75 @@ class BlocksyncReactor(Reactor):
                 batch.verify()
         except Exception as e:
             # blame the failing height: a deferred sig failure carries
-            # it as failed_ctx; structural errors (bad commit shape,
-            # not enough power) fail while collecting that height
-            bad_h = getattr(e, "failed_ctx", None) or collecting_h or \
-                blocks[0].header.height
-            for pid in self.pool.redo_request(bad_h):
-                self._on_peer_error(pid, "served invalid block")
+            # it as failed_ctx; a structural error (bad commit shape,
+            # not enough power) raises only for the window's first pair
+            self._reject_window({getattr(e, "failed_ctx", None)
+                                 or blocks[0].header.height})
             return False
+        verified = len(parts_ids)
 
+        self._reject_progress(b.header.height for b in blocks[:verified])
         progressed, _, _ = self._apply_window(blocks, window, parts_ids,
                                               commits, verified)
         return progressed
+
+    def _collect_pairs(self, blocks, commits, usable: int, head: bool):
+        """The structure checks, power tallies and sign-bytes of
+        `usable` pairs (blocks[i] judged on commits[i], the LastCommit
+        of the block above it), their signature checks deferred into ONE
+        DeferredSigBatch.  Returns (batch, parts_ids); the blocks that
+        may be applied once the batch's verdict is true are the first
+        len(parts_ids).
+
+        Valset per pair: exact for the head window's first block;
+        further only while headers pin the unchanged next_validators
+        hash (collection stops at the first that does not).
+
+        A pair that fails on its STRUCTURE (the block is not the one
+        the commit above it is for, a malformed commit, too little
+        power) raises only where it is the window's first: reactor.go
+        judges h on (h+1).LastCommit before it looks at h+1 at all, so
+        the pairs below a bad one are judged first - a forged signature
+        in block h+1's LastCommit also changes that block's part-set
+        hash, and it is the signature, in pair h, that upstream
+        rejects.  A bad pair further up is left out and closes the run
+        of blocks that may apply; the pairs above it are collected all
+        the same: the batch keeps the window's own width (a shorter one
+        is a program the device has not compiled, and whoever forges
+        chooses where), and their verdicts are in the verdict cache
+        when the window comes round again.  The bad pair is first in
+        line then, and is blamed if it still fails."""
+        from ..types.validation import DeferredSigBatch
+
+        next_hash = self.state.next_validators.hash() \
+            if self.state.next_validators else None
+        batch = DeferredSigBatch()
+        parts_ids = []
+        unbroken = True
+        for i in range(usable):
+            block = blocks[i]
+            height = block.header.height
+            if head and i == 0:
+                vals = self.state.validators
+            elif block.header.validators_hash == next_hash:
+                vals = self.state.next_validators
+            else:
+                break
+            with trace_span("blocksync", "partset", height=height):
+                parts = PartSet.from_data(block.to_proto())
+                bid = BlockID(block.hash(), parts.header)
+            try:
+                vals.verify_commit_light(
+                    self.state.chain_id, bid, height, commits[i],
+                    defer_to=batch)
+            except Exception:
+                if i == 0:
+                    raise
+                unbroken = False
+                continue
+            if unbroken:
+                parts_ids.append((parts, bid))
+        return batch, parts_ids
 
     def _apply_window(self, blocks, window, parts_ids, commits,
                       verified) -> tuple[bool, int, bool]:
@@ -348,8 +448,8 @@ class BlocksyncReactor(Reactor):
                 # params changed mid-window (a block we just applied
                 # enabled extensions): the pre-gate used the old
                 # params — refetch, don't evict (reactor.go:540)
-                for pid in self.pool.redo_request(first.header.height):
-                    self._on_peer_error(pid, "missing extended commit")
+                self._drop_suppliers(first.header.height,
+                                     "missing extended commit")
                 return progressed, popped, False
             parts, first_id = parts_ids[i]
             try:
@@ -366,8 +466,7 @@ class BlocksyncReactor(Reactor):
             except Exception:
                 # evict BOTH suppliers (reactor.go:560): the next
                 # block's LastCommit drove the batched verify
-                for pid in self.pool.redo_request(first.header.height):
-                    self._on_peer_error(pid, "served invalid block")
+                self._reject_window({first.header.height})
                 return progressed, popped, False
             self.pool.pop_request()
             popped += 1
@@ -409,8 +508,6 @@ class BlocksyncReactor(Reactor):
         for structural failures only fires at offset 0, where the
         state is current (a lookahead failure re-collects as the head
         window next pass and blames then)."""
-        from ..types.validation import DeferredSigBatch
-
         window, after = self.pool.peek_window(VERIFY_WINDOW, offset)
         usable = len(window) if after is not None else len(window) - 1
         if usable < 1:
@@ -421,10 +518,8 @@ class BlocksyncReactor(Reactor):
                     .vote_extensions_enabled(block.header.height):
                 if i == 0:
                     if offset == 0:
-                        for pid in self.pool.redo_request(
-                                block.header.height):
-                            self._on_peer_error(
-                                pid, "missing extended commit")
+                        self._drop_suppliers(block.header.height,
+                                             "missing extended commit")
                     return None
                 usable = i
                 break
@@ -436,43 +531,19 @@ class BlocksyncReactor(Reactor):
             nxt = blocks[i + 1] if i + 1 < len(window) else after
             commits.append(nxt.last_commit)
 
-        next_hash = self.state.next_validators.hash() \
-            if self.state.next_validators else None
-        batch = DeferredSigBatch()
-        verified = 0
-        parts_ids = []
-        collecting_h = None
         try:
             with trace_span("blocksync", "verify_dispatch",
                             offset=offset), \
                     trace_span("blocksync", "collect", offset=offset), \
                     tracetl.span_for(self, "blocksync", "collect",
                                      offset=offset):
-                for i in range(usable):
-                    block = blocks[i]
-                    collecting_h = block.header.height
-                    if offset == 0 and i == 0:
-                        vals = self.state.validators
-                    elif block.header.validators_hash == next_hash:
-                        vals = self.state.next_validators
-                    else:
-                        break
-                    with trace_span("blocksync", "partset",
-                                    height=collecting_h):
-                        parts = PartSet.from_data(block.to_proto())
-                        bid = BlockID(block.hash(), parts.header)
-                    parts_ids.append((parts, bid))
-                    vals.verify_commit_light(
-                        self.state.chain_id, bid, block.header.height,
-                        commits[i], defer_to=batch)
-                    verified += 1
-        except Exception as e:
+                batch, parts_ids = self._collect_pairs(
+                    blocks, commits, usable, head=offset == 0)
+        except Exception:
             if offset == 0:
-                bad_h = getattr(e, "failed_ctx", None) \
-                    or collecting_h or blocks[0].header.height
-                for pid in self.pool.redo_request(bad_h):
-                    self._on_peer_error(pid, "served invalid block")
+                self._reject_window({blocks[0].header.height})
             return None
+        verified = len(parts_ids)
         if verified < 1:
             return None
         return {"blocks": blocks, "window": window,
@@ -485,9 +556,15 @@ class BlocksyncReactor(Reactor):
         while window N's RLC dispatch runs on device and window N-1
         applies/stores.  Verdicts resolve strictly in submission
         order, and NO block applies before its window's verdict future
-        resolved true; a reject or device fault abandons the lookahead
-        (blocks stay in the pool — no loss) and the next pass retries
-        through the normal blame path."""
+        resolved true.  A reject names EVERY height of the window whose
+        commit holds a bad signature (the per-signature kernel judged
+        them all), drops both suppliers of each pair and fetches the
+        pairs again; the lookahead is waited out, not abandoned, so
+        that its verdicts are in the verdict cache when the next pass
+        collects the same blocks again (they stay in the pool — no
+        loss): the pipeline then judges the refetched window on the
+        device as one batch, not its few new signatures in the host
+        loop (VerifyPipeline.submit)."""
         pipe = self._get_pipeline()
         inflight: list[dict] = []
         offset = 0
@@ -522,20 +599,24 @@ class BlocksyncReactor(Reactor):
                                          "device_wait"):
                     rec["verdict"].wait()
             except Exception as e:
-                # abandoned lookahead windows resolve in the
-                # background; their blocks were never popped from the
-                # pool, so nothing is lost — the next pass re-peeks
-                bad_h = getattr(e, "failed_ctx", None) \
-                    or rec["blocks"][0].header.height
-                for pid in self.pool.redo_request(bad_h):
-                    self._on_peer_error(pid, "served invalid block")
+                bad = set()
+                if getattr(e, "failed_ctx", None) is not None:
+                    bad = rec["verdict"].failed_contexts()
+                for ahead in inflight:
+                    ahead["verdict"].settle()
+                self._reject_window(
+                    bad or {rec["blocks"][0].header.height})
                 return progressed
+            self._reject_progress(
+                b.header.height for b in rec["blocks"][:rec["verified"]])
             applied, popped, clean = self._apply_window(
                 rec["blocks"], rec["window"], rec["parts_ids"],
                 rec["commits"], rec["verified"])
             progressed = progressed or applied
             offset -= rec["verified"]
             if not clean or popped != rec["verified"]:
+                for ahead in inflight:
+                    ahead["verdict"].settle()
                 return progressed
             if self._stop_sync.is_set() or not self.is_running():
                 return progressed

@@ -166,28 +166,38 @@ def _device_verify(pubkeys: list[bytes], parsed=None, packed=_NO_PACK,
         if dm is not None:
             dm.rlc_fallbacks.inc()
         flightrec.record(flightrec.EV_RLC_FALLBACK, batch=n)
-    if parsed is None:
-        # an RLC reject or a batch of one: the per-signature kernel
-        # wants every h, hashed in Python under the same span name, so
-        # the packing metrics see a seam made slow by a forged signature
-        with trace_span("verify", "host_pack", batch=n, packer="python"):
-            parsed = ed.parse_and_hash(pubkeys, msgs, sigs)
-    if device is not None:
-        import jax
+    # localisation: an RLC reject (or a batch of one) is judged signature
+    # by signature.  The span covers the whole arm; inside it the
+    # Python hash, the pack, the per-signature program's enqueue and the
+    # wait for its verdicts each have their own (trace.LOCALIZE_STAGES)
+    bucket = dev.bucket_size(n) if device is not None \
+        else sharding.auto_bucket(n)
+    with trace_span("verify", "localize", batch=n, bucket=bucket) as loc:
+        if parsed is None:
+            # the per-signature kernel wants every h, hashed in Python
+            # under the packing span's name, so the packing metrics see
+            # a seam made slow by a forged signature
+            with trace_span("verify", "host_pack", batch=n,
+                            packer="python"):
+                parsed = ed.parse_and_hash(pubkeys, msgs, sigs)
+        with trace_span("verify", "persig_pack", bucket=bucket):
+            a, r, s, h, valid = ed.pack_batch(
+                pubkeys, [b""] * n, [b""] * n, bucket, parsed=parsed)
+        with trace_span("verify", "persig_dispatch", bucket=bucket):
+            if device is not None:
+                import jax
 
-        bucket = dev.bucket_size(n)
-        a, r, s, h, valid = ed.pack_batch(pubkeys, [b""] * n, [b""] * n,
-                                          bucket, parsed=parsed)
-        a, r, s, h = (jax.device_put(x, device) for x in (a, r, s, h))
-        verdict = np.asarray(dev.verify_batch_device(a, r, s, h))
-    else:
-        bucket = sharding.auto_bucket(n)
-        a, r, s, h, valid = ed.pack_batch(pubkeys, [b""] * n, [b""] * n,
-                                          bucket, parsed=parsed)
-        verdict = np.asarray(sharding.verify_batch_sharded(a, r, s, h))
-    _count_verified("persig", n)
-    verdict = verdict & valid
-    out = verdict[:n].tolist()
+                a, r, s, h = (jax.device_put(x, device)
+                              for x in (a, r, s, h))
+                verdict = dev.verify_batch_device(a, r, s, h)
+            else:
+                verdict = sharding.verify_batch_sharded(a, r, s, h)
+        with trace_span("verify", "persig_readback", bucket=bucket):
+            verdict = np.asarray(verdict)
+        _count_verified("persig", n)
+        verdict = verdict & valid
+        out = verdict[:n].tolist()
+        loc.note(bad=out.count(False))
     return all(out) and bool(out), out
 
 

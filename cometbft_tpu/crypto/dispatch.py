@@ -555,7 +555,14 @@ class VerifyPipeline(BaseService):
         # verdict-cache partition (crypto/sigcache.py): only misses
         # stage and dispatch; cached verdicts merge back at window
         # publication.  A fully-cached window resolves RIGHT HERE —
-        # no slot, no staging, no device.
+        # no slot, no staging, no device.  A window of device size is
+        # NOT split where that would leave its misses to the host loop
+        # (fewer than the device threshold) and every cached verdict is
+        # true: it goes to the device as it was submitted.  One RLC
+        # batch costs the device the same for a few new signatures as
+        # for all of them, and a window that a reject sent round again
+        # (blocksync: the old blocks, one or two of them fetched anew)
+        # is judged where its first verdicts were.
         cached = None
         misses = items
         if sigcache.enabled():
@@ -566,7 +573,9 @@ class VerifyPipeline(BaseService):
                 handle._resolve(all(full), full, "cache")
                 self._record_cache_window(handle, len(items))
                 return handle
-            if len(miss_idx) < len(items):
+            whole = len(miss_idx) < device_threshold <= len(items) \
+                and False not in verdicts
+            if len(miss_idx) < len(items) and not whole:
                 cached = verdicts
                 misses = [items[i] for i in miss_idx]
         if self._stopping or self._staging is None \

@@ -99,6 +99,9 @@ class Gauge(_Metric):
     def inc(self, delta: float = 1.0) -> None:
         self._add((), delta)
 
+    def add(self, delta: float) -> None:
+        self._add((), delta)
+
     def dec(self, delta: float = 1.0) -> None:
         self._add((), -delta)
 
@@ -490,6 +493,20 @@ class BlockSyncMetrics:
         self.latest_block_height = reg.gauge(
             "blocksync", "latest_block_height",
             "The height of the latest block.")
+        self.windows_rejected = reg.counter(
+            "blocksync", "windows_rejected_total",
+            "Verify windows whose verdict came out false, and blocks "
+            "that failed validation at apply: each drops the suppliers "
+            "of the pairs it names and fetches the pairs again.")
+        self.peers_dropped = reg.counter(
+            "blocksync", "peers_dropped_total",
+            "Peers dropped for a block they supplied, by reason "
+            "(served_invalid_block, missing_extended_commit).",
+            labels=("reason",))
+        self.blocks_refetched = reg.counter(
+            "blocksync", "blocks_refetched_total",
+            "Blocks that came back to the pool after a reject had "
+            "them fetched again.")
 
     def record_block(self, block, size_bytes: int | None = None) -> None:
         """recordBlockMetrics (internal/blocksync/metrics.go:31).

@@ -65,6 +65,16 @@ APPLY_STAGES = ("validate", "abci_finalize", "save", "update",
 # host_pack only where the batch did not come packed from the
 # pipeline's staging thread (that one is <subsystem>.host_pack)
 VERIFY_STAGES = ("host_pack", "dispatch", "readback")
+# what an RLC reject adds (crypto/batch._device_verify): localize round
+# the whole per-signature arm, inside it host_pack (packer=python: every
+# h hashed in the interpreter, where the batch did not come parsed), the
+# pack, and the per-signature program's own enqueue and wait
+LOCALIZE_STAGES = ("localize", "persig_pack", "persig_dispatch",
+                   "persig_readback")
+# subsystem "blocksync", closed with close(): reject runs from a
+# window's false verdict to the heights it named verified true, refetch
+# inside it from the pairs' redo to their blocks back in the pool
+REJECT_STAGES = ("reject", "refetch")
 
 # interval ring size per (subsystem, stage): enough to prove overlap
 # across a bench run without unbounded growth on long-lived nodes.
@@ -248,3 +258,16 @@ def span(subsystem: str, stage: str, **fields):
     if t is None:
         return _NULL_SPAN
     return _TimedSpan(t, subsystem, stage, fields or None)
+
+
+def close(subsystem: str, stage: str, started: float, **fields) -> None:
+    """Record a span that outlived the frame it began in: `started` is
+    the time.perf_counter() of its beginning, its end is now.  For an
+    episode that a later call finishes (a blocksync reject runs from a
+    window's false verdict to the same heights verified true, several
+    passes of the pool routine later); it takes no part in nesting."""
+    t = _tracer
+    if t is not None:
+        t1 = time.perf_counter()
+        t.record(subsystem, stage, t1 - started, end=t1,
+                 fields=fields or None)

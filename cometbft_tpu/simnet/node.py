@@ -191,7 +191,7 @@ class SimNode:
             state, self.block_exec, self.block_store, block_sync,
             consensus_reactor=(self.consensus_reactor
                                if consensus_active else None),
-            peer_timeout=peer_timeout)
+            peer_timeout=peer_timeout, seed=seed)
 
         self.node_key = NodeKey(ed25519.PrivKey.generate(
             _seed_bytes(f"node-key-{name}", seed)))

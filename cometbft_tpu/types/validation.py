@@ -190,6 +190,17 @@ class _DeferredVerdict:
         raise CommitVerificationError(
             "BUG: deferred window failed with no invalid signatures")
 
+    def settle(self, timeout: float | None = None) -> None:
+        """Block until the window has resolved, whatever it resolved
+        to.  For a caller that gives a window up (blocksync's lookahead
+        at a reject) but wants its verdicts in the verdict cache, and
+        its future read, before it collects the same blocks again."""
+        if self.handle is not None:
+            try:
+                self.handle.result(timeout)
+            except Exception:       # noqa: BLE001 - given up, not judged
+                pass
+
     def failed_contexts(self, timeout: float | None = None) -> set:
         """Per-context verdicts instead of first-failure raise: the
         set of ctx values (heights, for commit collection) that had at

@@ -159,6 +159,25 @@ def test_seam_spans_in_order_and_apart(seam):
         assert a["end"] <= b["start"]
 
 
+def test_localisation_spans_lie_inside_localize_in_order(seam):
+    tracer, _, (ok, verdicts) = seam["bad"]
+    assert not ok and verdicts == [True] * 3 + [False, True]
+    got = {iv["stage"]: iv for iv in tracer.intervals("verify")
+           if iv["stage"] in libtrace.LOCALIZE_STAGES}
+    assert set(got) == set(libtrace.LOCALIZE_STAGES)
+    loc = got["localize"]
+    assert (loc["batch"], loc["bad"]) == (5, 1)
+    # the Python hash (host_pack, packer=python), then the pack, the
+    # per-signature program's enqueue and the wait for its verdicts
+    rehash = tracer.intervals("verify", "host_pack")[-1]
+    inner = [rehash] + [got[s] for s in libtrace.LOCALIZE_STAGES[1:]]
+    assert loc["start"] <= inner[0]["start"]
+    for a, b in zip(inner, inner[1:]):
+        assert a["end"] <= b["start"]
+    assert inner[-1]["end"] <= loc["end"]
+    assert got["persig_dispatch"]["bucket"] == loc["bucket"]
+
+
 @pytest.mark.parametrize("which", ["good", "bad"])
 def test_host_pack_span_names_its_packer(seam, which):
     from cometbft_tpu.crypto import rlcpack
