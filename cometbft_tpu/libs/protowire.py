@@ -38,18 +38,22 @@ BYTES = 2
 FIXED32 = 5
 
 
+# every varint below 128 is one byte: tags of fields 1 to 15, and nearly
+# every length and value a block's records hold
+_ONE_BYTE = tuple(bytes((i,)) for i in range(128))
+
+
 def encode_uvarint(v: int) -> bytes:
-    if v < 0:
-        raise ValueError("uvarint must be non-negative")
+    if v < 128:
+        if v < 0:
+            raise ValueError("uvarint must be non-negative")
+        return _ONE_BYTE[v]
     out = bytearray()
-    while True:
-        b = v & 0x7F
+    while v > 0x7F:
+        out.append(v & 0x7F | 0x80)
         v >>= 7
-        if v:
-            out.append(b | 0x80)
-        else:
-            out.append(b)
-            return bytes(out)
+    out.append(v)
+    return bytes(out)
 
 
 def decode_uvarint(buf: bytes, pos: int = 0) -> tuple[int, int]:
@@ -70,6 +74,23 @@ def decode_uvarint(buf: bytes, pos: int = 0) -> tuple[int, int]:
             raise ValueError("varint too long")
 
 
+# A field's tag by its wire type and number, kept from its first use: a
+# message's fields are a handful of constants, written millions of times.
+# Plain dicts and a `try` at each use on purpose: a dict subclass with
+# `__missing__` reads better and made the records of a block 4-5% slower
+# to write.
+_TAGS: dict[int, dict[int, bytes]] = {VARINT: {}, FIXED64: {}, BYTES: {}}
+_VARINT_TAGS = _TAGS[VARINT]
+_FIXED64_TAGS = _TAGS[FIXED64]
+_BYTES_TAGS = _TAGS[BYTES]
+
+
+def _new_tag(field: int, wire: int) -> bytes:
+    tag = encode_uvarint((field << 3) | wire)
+    _TAGS.setdefault(wire, {})[field] = tag
+    return tag
+
+
 class Writer:
     """Appends proto fields in tag order; caller keeps tags ascending."""
 
@@ -84,38 +105,77 @@ class Writer:
         return self
 
     def tag(self, field: int, wire: int) -> "Writer":
-        self._parts.append(encode_uvarint((field << 3) | wire))
+        try:
+            self._parts.append(_TAGS[wire][field])
+        except KeyError:
+            self._parts.append(_new_tag(field, wire))
         return self
 
     # -- scalars (proto3: zero omitted) ------------------------------------
     def uvarint_field(self, field: int, v: int) -> "Writer":
         if v != 0:
-            self.tag(field, VARINT).raw(encode_uvarint(v))
+            try:
+                t = _VARINT_TAGS[field]
+            except KeyError:
+                t = _new_tag(field, VARINT)
+            self._parts.append(
+                t + (_ONE_BYTE[v] if 0 < v < 128 else encode_uvarint(v)))
         return self
 
     def int_field(self, field: int, v: int) -> "Writer":
         """int32/int64/enum: negative encodes as 10-byte two's complement."""
         if v != 0:
-            self.tag(field, VARINT).raw(encode_uvarint(v & _U64))
+            try:
+                t = _VARINT_TAGS[field]
+            except KeyError:
+                t = _new_tag(field, VARINT)
+            self._parts.append(
+                t + (_ONE_BYTE[v] if 0 < v < 128
+                     else encode_uvarint(v & _U64)))
         return self
 
     def bool_field(self, field: int, v: bool) -> "Writer":
         if v:
-            self.tag(field, VARINT).raw(b"\x01")
+            try:
+                t = _VARINT_TAGS[field]
+            except KeyError:
+                t = _new_tag(field, VARINT)
+            self._parts.append(t + b"\x01")
         return self
 
     def sfixed64_field(self, field: int, v: int) -> "Writer":
         if v != 0:
-            self.tag(field, FIXED64).raw(struct.pack("<q", v))
+            try:
+                t = _FIXED64_TAGS[field]
+            except KeyError:
+                t = _new_tag(field, FIXED64)
+            self._parts.append(t + struct.pack("<q", v))
         return self
 
     def bytes_field(self, field: int, v: bytes) -> "Writer":
         if v:
-            self.tag(field, BYTES).raw(encode_uvarint(len(v))).raw(v)
+            try:
+                t = _BYTES_TAGS[field]
+            except KeyError:
+                t = _new_tag(field, BYTES)
+            n = len(v)
+            parts = self._parts
+            parts.append(t + (_ONE_BYTE[n] if n < 128 else encode_uvarint(n)))
+            parts.append(v)
         return self
 
     def string_field(self, field: int, v: str) -> "Writer":
-        return self.bytes_field(field, v.encode("utf-8"))
+        if v:
+            try:
+                t = _BYTES_TAGS[field]
+            except KeyError:
+                t = _new_tag(field, BYTES)
+            b = v.encode("utf-8")
+            n = len(b)
+            parts = self._parts
+            parts.append(t + (_ONE_BYTE[n] if n < 128 else encode_uvarint(n)))
+            parts.append(b)
+        return self
 
     def packed_uint64_field(self, field: int, vals) -> "Writer":
         payload = b"".join(encode_uvarint(v & MASK64) for v in vals)
@@ -124,7 +184,14 @@ class Writer:
     # -- messages ----------------------------------------------------------
     def message_field(self, field: int, payload: bytes) -> "Writer":
         """Embedded message, gogo nullable=false: always emitted."""
-        self.tag(field, BYTES).raw(encode_uvarint(len(payload))).raw(payload)
+        try:
+            t = _BYTES_TAGS[field]
+        except KeyError:
+            t = _new_tag(field, BYTES)
+        n = len(payload)
+        parts = self._parts
+        parts.append(t + (_ONE_BYTE[n] if n < 128 else encode_uvarint(n)))
+        parts.append(payload)
         return self
 
     def optional_message_field(self, field: int,
