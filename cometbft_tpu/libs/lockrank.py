@@ -161,6 +161,9 @@ LOCK_RANKS: dict[str, int] = {
     "metrics.series": 540,
     # pure leaves
     "service.lifecycle": 550,
+    # an RPC server's open connections; a light provider's kept ones
+    "rpc.conns": 552,
+    "light.provider": 554,
     "native_codec.lib": 560,
     "rlcpack.lib": 565,
     "bls12381.lib": 570,
@@ -187,6 +190,7 @@ MULTI_OK = frozenset({
     "flightrec.ring", "tracetl.ring", "trace.stage",
     "metrics.registry", "metrics.series", "service.lifecycle",
     "statesync.chunks", "statesync.syncer", "statesync.snapshots",
+    "rpc.conns", "light.provider",
 })
 
 

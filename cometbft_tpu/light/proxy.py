@@ -7,10 +7,10 @@ can point at an untrusted full node through this proxy.
 from __future__ import annotations
 
 import threading
-from http.server import ThreadingHTTPServer
 
 from ..rpc import serialize as ser
-from ..rpc.server import _call_target, _err, make_json_handler
+from ..rpc.server import (
+    KeepAliveHTTPServer, _call_target, _err, make_json_handler)
 
 
 class LightProxy:
@@ -30,10 +30,9 @@ class LightProxy:
                             "serves verified routes only)")
             return _call_target(getattr(self, fn_name), params, req_id)
 
-        self._httpd = ThreadingHTTPServer(
+        self._httpd = KeepAliveHTTPServer(
             (host or "127.0.0.1", int(port)),
             make_json_handler(dispatch, sorted(_ROUTES)))
-        self._httpd.daemon_threads = True
         self.bound_addr = "%s:%d" % self._httpd.server_address
         self._thread: threading.Thread | None = None
 

@@ -2,19 +2,67 @@
 
 Accepts POST / with a JSON-RPC envelope and GET /<method>?arg=...
 URI-style calls, like the reference's http_json_handler + uri handler.
-The handler factory is shared with the light proxy (which serves a
-different route table and no websocket)."""
+The handler factory and the server class are shared with the light
+proxy (which serves a different route table and no websocket).
+
+Connections are HTTP/1.1 and kept alive, as Go's net/http server keeps
+them: a light client's provider asks three questions a header on one
+connection.  A reply leaves in one go (TCP_NODELAY: the headers and the
+body are two writes, and Nagle would hold the body until the client's
+delayed ACK, 40 ms and more), and a connection that sits idle is closed
+after READ_TIMEOUT_S."""
 
 from __future__ import annotations
 
 import json
+import socket
 import threading
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from urllib.parse import parse_qsl, urlparse
 
+from ..libs import lockrank
 from .core import ROUTES, Environment, RPCError
 
 MAX_BODY_BYTES = 1_000_000
+# seconds a connection may wait for its next request (or stall one read
+# or write) before the server closes it: rpc/jsonrpc/server
+# DefaultConfig's ReadTimeout, which net/http also takes for the idle
+# wait of a kept connection
+READ_TIMEOUT_S = 10.0
+
+
+class KeepAliveHTTPServer(ThreadingHTTPServer):
+    """A thread a connection, and the connections it holds open: close
+    shuts each down, so a kept-alive one is not served on after stop()
+    and its thread ends at once (handler threads are daemons, which
+    server_close does not join)."""
+
+    daemon_threads = True
+
+    def __init__(self, addr, handler):
+        self._conns: set = set()
+        self._conns_mtx = lockrank.RankedLock("rpc.conns")
+        super().__init__(addr, handler)
+
+    def process_request(self, request, client_address) -> None:
+        with self._conns_mtx:
+            self._conns.add(request)
+        super().process_request(request, client_address)
+
+    def shutdown_request(self, request) -> None:
+        with self._conns_mtx:
+            self._conns.discard(request)
+        super().shutdown_request(request)
+
+    def server_close(self) -> None:
+        super().server_close()
+        with self._conns_mtx:
+            conns = list(self._conns)
+        for sock in conns:
+            try:
+                sock.shutdown(socket.SHUT_RDWR)
+            except OSError:
+                pass
 
 
 class RPCServer:
@@ -30,11 +78,10 @@ class RPCServer:
                 return _err(req_id, -32601, f"method {method} not found")
             return _call_target(getattr(env, attr), params, req_id)
 
-        self._httpd = ThreadingHTTPServer(
+        self._httpd = KeepAliveHTTPServer(
             (host or "127.0.0.1", int(port)),
             make_json_handler(dispatch, sorted(routes),
                               env=env if with_websocket else None))
-        self._httpd.daemon_threads = True
         self._thread: threading.Thread | None = None
         self.bound_addr = "%s:%d" % self._httpd.server_address
 
@@ -99,16 +146,22 @@ def make_json_handler(dispatch, route_names, env=None):
 
     class Handler(BaseHTTPRequestHandler):
         protocol_version = "HTTP/1.1"
+        disable_nagle_algorithm = True
+        timeout = READ_TIMEOUT_S
 
         def log_message(self, *args) -> None:
             pass  # quiet
 
         # -- helpers -------------------------------------------------------
-        def _reply(self, status: int, payload) -> None:
+        def _reply(self, status: int, payload, close: bool = False) -> None:
+            """`close` where the request's body was left unread: the
+            connection cannot be read on, and the client is told."""
             body = json.dumps(payload).encode()
             self.send_response(status)
             self.send_header("Content-Type", "application/json")
             self.send_header("Content-Length", str(len(body)))
+            if close:
+                self.send_header("Connection", "close")
             self.end_headers()
             self.wfile.write(body)
 
@@ -140,14 +193,14 @@ def make_json_handler(dispatch, route_names, env=None):
                 length = int(self.headers.get("Content-Length", "0"))
             except ValueError:
                 self._reply(400, _err(None, -32700,
-                                      "invalid Content-Length"))
+                                      "invalid Content-Length"), close=True)
                 return
             if length > MAX_BODY_BYTES:
-                self._reply(413, {"error": "body too large"})
+                self._reply(413, {"error": "body too large"}, close=True)
                 return
             if length < 0:
                 self._reply(400, _err(None, -32700,
-                                      "invalid Content-Length"))
+                                      "invalid Content-Length"), close=True)
                 return
             try:
                 req = json.loads(self.rfile.read(length) or b"{}")
@@ -177,6 +230,8 @@ def make_json_handler(dispatch, route_names, env=None):
             self.send_header("Sec-WebSocket-Accept", ws.accept_key(key))
             self.end_headers()
             self.close_connection = True
+            # a subscription waits on its events as long as it likes
+            self.connection.settimeout(None)
             session = ws.WSSession(
                 env, self.rfile, self.wfile,
                 "%s:%d" % self.client_address[:2], self._call)
