@@ -33,10 +33,20 @@ Spans nest.  A span opened inside another on the same thread carries
 the enclosing span's "subsystem.stage" as `parent` in its interval's
 fields, and the tracer keeps each span's children's seconds, so
 self_seconds() says what of a span no child names.
+
+The garbage collector's pauses are spans too, `gc.young` and `gc.full`,
+timed from gc.callbacks while a tracer is installed.  A collection stops
+every thread, and is otherwise billed to whichever span happened to be
+open.  The hook runs wherever an allocation crosses a threshold, a
+tracer's record() under its own lock among those places, so it calls no
+tracer and takes no lock: it leaves the pause in a buffer that span
+exits, close() and set_tracer() hand to the tracer.
 """
 
 from __future__ import annotations
 
+import collections
+import gc
 import threading
 import time
 
@@ -78,6 +88,11 @@ LOCALIZE_STAGES = ("localize", "persig_pack", "persig_dispatch",
 # window_cut (closed the same way) is the collection of a verify window
 # cut short at a set change (blocksync/reactor._count_cut)
 REJECT_STAGES = ("reject", "refetch")
+# subsystem "gc": one of CPython's cyclic collections, from its start to
+# its stop callback; young is generation 0 or 1, full is generation 2
+# (the whole heap).  Fields generation, collected, uncollectable, and
+# parent: the span open on the collecting thread
+GC_STAGES = ("young", "full")
 
 # interval ring size per (subsystem, stage): enough to prove overlap
 # across a bench run without unbounded growth on long-lived nodes.
@@ -85,6 +100,10 @@ REJECT_STAGES = ("reject", "refetch")
 # would otherwise push the few device/collect intervals a window
 # leaves out of a shared ring before overlap_seconds reads them
 MAX_INTERVALS = 1024
+
+# collections finished and not yet handed to the tracer: past this many
+# the oldest is dropped and counted (gc_dropped)
+GC_PENDING = 1024
 
 
 class StageTracer:
@@ -160,6 +179,14 @@ class StageTracer:
                     total += hi - lo
         return total
 
+    def add_dropped(self, n: int) -> None:
+        """Intervals lost before they reached record(): collections
+        dropped from the collector's buffer."""
+        with self._mtx:
+            self.dropped_intervals += n
+        if self.metrics is not None:
+            self.metrics.intervals_dropped.add(n)
+
     def self_seconds(self, subsystem: str, stage: str) -> float:
         """A stage's seconds less what the spans opened inside it, on
         the same thread, cover: the part of it no child names.  From
@@ -227,6 +254,8 @@ class _TimedSpan:
     def __exit__(self, *exc):
         t1 = time.perf_counter()
         _open.stack.pop()
+        if _gc_done:
+            _drain_gc(_tracer)
         self._tracer.record(self._subsystem, self._stage,
                             t1 - self._t0, end=t1, fields=self._fields)
         return False
@@ -245,8 +274,18 @@ _tracer: StageTracer | None = None
 
 
 def set_tracer(t: StageTracer | None) -> None:
+    """Install `t` (None: nothing).  The collector is hooked, once, while
+    a tracer is installed; what it left for the old tracer goes there."""
     global _tracer
+    prev = _tracer
+    _drain_gc(prev)
     _tracer = t
+    if t is not None:
+        if _gc_hook not in gc.callbacks:
+            gc.callbacks.append(_gc_hook)
+    elif _gc_hook in gc.callbacks:
+        gc.callbacks.remove(_gc_hook)
+        _drain_gc(prev)
 
 
 def tracer() -> StageTracer | None:
@@ -272,5 +311,63 @@ def close(subsystem: str, stage: str, started: float, **fields) -> None:
     t = _tracer
     if t is not None:
         t1 = time.perf_counter()
+        _drain_gc(t)
         t.record(subsystem, stage, t1 - started, end=t1,
                  fields=fields or None)
+
+
+# -- the collector's pauses ----------------------------------------------
+
+# the collection under way: (its start, the span open on its thread).
+# CPython never runs two collections at once, so one slot holds it
+_gc_started: tuple | None = None
+# finished collections, oldest first: (start, end, stage, fields, lost),
+# lost the count dropped from the buffer before this one was added
+_gc_done: collections.deque = collections.deque()
+gc_dropped = 0      # collections dropped from a full buffer, ever
+
+
+def _gc_hook(phase: str, info: dict) -> None:
+    """gc.callbacks entry.  Reads the clock and appends to a deque: it
+    calls no tracer and takes no lock, since the collection may have
+    started inside record() on a thread that holds the tracer's lock."""
+    global _gc_started, gc_dropped
+    if phase == "start":
+        stack = getattr(_open, "stack", None)
+        _gc_started = (time.perf_counter(), stack[-1] if stack else None)
+        return
+    t1 = time.perf_counter()
+    started, _gc_started = _gc_started, None
+    if started is None:             # hooked while a collection ran
+        return
+    gen = info["generation"]
+    fields = {"generation": gen, "collected": info["collected"],
+              "uncollectable": info["uncollectable"]}
+    if started[1] is not None:
+        fields["parent"] = started[1]
+    dropped = lost = 0
+    while len(_gc_done) >= GC_PENDING:
+        try:
+            lost += _gc_done.popleft()[4]
+        except IndexError:          # a drain emptied it meanwhile
+            break
+        dropped += 1
+    gc_dropped += dropped
+    _gc_done.append((started[0], t1, "full" if gen == 2 else "young",
+                     fields, lost + dropped))
+
+
+def _drain_gc(t) -> None:
+    """Hand the finished collections to `t`, outside any lock of its
+    own.  A collection is popped by one drainer only, so the threads
+    that drain at once record each exactly once."""
+    if t is None:
+        return
+    while True:
+        try:
+            t0, t1, stage, fields, lost = _gc_done.popleft()
+        except IndexError:
+            return
+        t.record("gc", stage, t1 - t0, end=t1, fields=fields)
+        if lost and isinstance(t, StageTracer):
+            t.add_dropped(lost)

@@ -6,8 +6,11 @@ parent / self-time bookkeeping of libs/trace.
 through the synchronous batch seam on the CPU backend with the real
 kernels (the 8 x 8 shape tests/test_ed25519.py compiles too), (c) the
 same calls with nothing installed, (d) self_seconds on a hand-built
-nest.
+nest, (e) the garbage collector's pauses as gc.* spans.
 """
+
+import gc
+import threading
 
 import pytest
 
@@ -99,8 +102,12 @@ def test_state_spans_lie_inside_apply_and_sum_to_within_it(applied):
     covered = sum(iv["end"] - iv["start"] for iv in inner)
     whole = outer["end"] - outer["start"]
     assert 0 < covered <= whole
+    # a collection of the garbage collector's between two state spans
+    # is a child of apply's too
+    paused = sum(iv["end"] - iv["start"] for iv in tracer.intervals("gc")
+                 if iv.get("parent") == "blocksync.apply")
     assert tracer.self_seconds("blocksync", "apply") == pytest.approx(
-        whole - covered, abs=1e-9)
+        whole - covered - paused, abs=1e-9)
 
 
 def test_a_block_validated_twice_opens_validate_once():
@@ -325,3 +332,198 @@ def test_ring_is_bounded_per_stage_not_over_all(monkeypatch):
                               "collect") == pytest.approx(0.2)
     assert [iv["end"] for iv in tr.intervals()] == sorted(
         iv["end"] for iv in tr.intervals())
+
+
+# -- (e) the collector's pauses ------------------------------------------
+
+@pytest.fixture
+def no_hook():
+    """gc.callbacks, the installed tracer and automatic collection as
+    they were, after the test; automatic collection off during it, so
+    the collections seen are the ones the test forces."""
+    callbacks, prev, was = list(gc.callbacks), libtrace.tracer(), \
+        gc.isenabled()
+    gc.disable()
+    libtrace.set_tracer(None)
+    libtrace._gc_done.clear()
+    yield
+    libtrace.set_tracer(prev)
+    gc.callbacks[:] = callbacks
+    if was:
+        gc.enable()
+
+
+@pytest.fixture
+def hooked(no_hook):
+    """A StageTracer over TraceMetrics behind the seam: (tracer,
+    registry)."""
+    reg = libmetrics.Registry()
+    tr = libtrace.StageTracer(libmetrics.TraceMetrics(reg))
+    libtrace.set_tracer(tr)
+    return tr, reg
+
+
+def test_gc_stages_name_the_two_kinds():
+    assert libtrace.GC_STAGES == ("young", "full")
+
+
+def test_a_full_collection_in_a_span_is_its_child(hooked):
+    tr, _ = hooked
+    with libtrace.span("t", "outer"):
+        gc.collect()
+    (full,) = tr.intervals("gc", "full")
+    (outer,) = tr.intervals("t", "outer")
+    assert tr.intervals("gc", "young") == []
+    assert full["parent"] == "t.outer" and full["generation"] == 2
+    assert full["uncollectable"] >= 0 and full["collected"] >= 0
+    assert outer["start"] <= full["start"] <= full["end"] <= outer["end"]
+    paused = full["end"] - full["start"]
+    assert paused > 0
+    assert tr.self_seconds("t", "outer") == pytest.approx(
+        outer["end"] - outer["start"] - paused, abs=1e-9)
+
+
+@pytest.mark.parametrize("generation,stage", [
+    (0, "young"), (1, "young"), (2, "full")])
+def test_a_generation_lands_in_its_stage(hooked, generation, stage):
+    tr, _ = hooked
+    gc.collect(generation)
+    with libtrace.span("t", "after"):       # the exit hands it over
+        pass
+    (got,) = tr.intervals("gc")
+    assert got["stage"] == stage and got["generation"] == generation
+    assert "parent" not in got                  # no span was open
+    assert got["end"] <= tr.intervals("t", "after")[0]["end"]
+
+
+def test_no_tracer_hooks_nothing_and_records_nothing(no_hook):
+    before = list(gc.callbacks)
+    with libtrace.span("t", "outer"):
+        gc.collect()
+    libtrace.close("t", "closed", 0.0)
+    assert gc.callbacks == before
+    assert libtrace._gc_hook not in gc.callbacks
+    assert not libtrace._gc_done
+
+
+def test_one_hook_however_often_a_tracer_is_set(no_hook):
+    a, b = libtrace.StageTracer(), libtrace.StageTracer()
+    libtrace.set_tracer(a)
+    libtrace.set_tracer(b)
+    libtrace.set_tracer(b)
+    assert gc.callbacks.count(libtrace._gc_hook) == 1
+    gc.collect()
+    libtrace.set_tracer(None)       # what b's collection left goes to b
+    assert gc.callbacks.count(libtrace._gc_hook) == 0
+    assert len(b.intervals("gc", "full")) == 1
+    assert a.intervals("gc") == []
+
+
+def test_close_hands_the_collections_over(hooked):
+    tr, _ = hooked
+    gc.collect(0)
+    libtrace.close("t", "episode", 0.0)
+    assert len(tr.intervals("gc", "young")) == 1
+
+
+@pytest.mark.parametrize("kind", ["span_tracer", "stage_tracer"])
+def test_a_collection_under_the_tracers_own_lock(no_hook, kind):
+    """A collection that starts while the tracer holds its lock (as one
+    may inside record()) neither deadlocks nor is lost."""
+    from benchmark.spans import SpanTracer
+
+    if kind == "span_tracer":
+        tr = SpanTracer()
+        lock = tr._lock
+    else:
+        tr = libtrace.StageTracer()
+        lock = tr._mtx
+    libtrace.set_tracer(tr)
+    done = threading.Event()
+
+    def body():
+        with lock:
+            gc.collect()
+        with libtrace.span("t", "after"):
+            pass
+        done.set()
+
+    th = threading.Thread(target=body, name="gc-under-lock", daemon=True)
+    th.start()
+    th.join(timeout=30)
+    assert done.is_set() and not th.is_alive()
+    if kind == "span_tracer":
+        names = [name for name, _, _ in tr.spans()]
+        assert names.count("gc.full") == 1 and "t.after" in names
+    else:
+        assert len(tr.intervals("gc", "full")) == 1
+
+
+def test_a_full_buffer_counts_what_it_drops(hooked, monkeypatch):
+    tr, _ = hooked
+    monkeypatch.setattr(libtrace, "GC_PENDING", 2)
+    before = libtrace.gc_dropped
+    for _ in range(5):
+        gc.collect(0)
+    assert libtrace.gc_dropped - before == 3
+    assert len(libtrace._gc_done) == 2
+    with libtrace.span("t", "after"):
+        pass
+    assert len(tr.intervals("gc", "young")) == 2
+    assert tr.dropped_intervals == 3
+    dropped = tr.metrics.intervals_dropped
+    with dropped._mtx:
+        assert dropped._values.get((), 0.0) == 3
+
+
+def test_threads_draining_at_once_record_each_collection_once(hooked,
+                                                              monkeypatch):
+    """Many threads collect and drain at once, the buffer small enough to
+    overflow: every collection is recorded once or counted dropped."""
+    import sys
+
+    tr, _ = hooked
+    monkeypatch.setattr(libtrace, "GC_PENDING", 2)
+    monkeypatch.setattr(libtrace, "MAX_INTERVALS", 10 ** 6)
+    stops = []
+    gc.callbacks.append(lambda phase, info: phase == "stop"
+                        and stops.append(info["generation"]))
+    before = libtrace.gc_dropped
+    switch = sys.getswitchinterval()
+
+    def worker(collects):
+        for i in range(100):
+            if collects:
+                gc.collect(0)
+            if not collects or i % 5 == 0:
+                with libtrace.span("t", "w"):
+                    pass
+
+    threads = [threading.Thread(target=worker, args=(i % 2 == 0,),
+                                name=f"drain-{i}", daemon=True)
+               for i in range(16)]
+    sys.setswitchinterval(1e-6)
+    try:
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(timeout=60)
+    finally:
+        sys.setswitchinterval(switch)
+    assert not any(th.is_alive() for th in threads)
+    libtrace.set_tracer(None)                   # hands over the rest
+    dropped = libtrace.gc_dropped - before
+    recorded = tr.snapshot().get("gc.young", {"count": 0})["count"]
+    # a gc.collect() that finds another thread's collection under way
+    # returns without collecting
+    assert 0 < len(stops) <= 800
+    assert recorded + dropped == len(stops)
+    assert tr.dropped_intervals == dropped      # no ring overflowed
+
+
+def test_trace_metrics_observe_the_full_collection(hooked):
+    tr, reg = hooked
+    with libtrace.span("t", "outer"):
+        gc.collect()
+    assert 'cometbft_trace_stage_duration_seconds_count{subsystem="gc",' \
+        'stage="full"} 1' in reg.expose()
